@@ -326,18 +326,12 @@ class DocumentRestructurer:
         toks, lens = zip(*(self.embedder.tokens(c) for c in chunks))
         x = np.stack(toks)                                  # [C, T, D]
         lengths = np.asarray(lens, np.int32)
-        # pad chunk count so the kernel's block shape divides
-        c = x.shape[0]
-        pad = (-c) % 8
-        if pad:
-            x = np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)])
-            lengths = np.concatenate([lengths, np.ones(pad, np.int32)])
         scores = ops.relevance_score(
             jnp.asarray(x), jnp.asarray(lengths),
             jnp.asarray(self.w, jnp.float32),
             jnp.asarray(self.b, jnp.float32),
-            impl=self.impl, block_c=8)
-        return np.asarray(scores)[:c]
+            impl=self.impl)
+        return np.asarray(scores)
 
     def reorder(self, doc: SyntheticDoc) -> SyntheticDoc:
         """Sort chunks by predicted relevance (desc); concatenate."""

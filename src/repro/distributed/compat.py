@@ -1,10 +1,7 @@
-"""JAX version compatibility shims for the distributed layer.
+"""Mesh-mapping helpers shared by the distributed layer.
 
-The codebase targets the jax>=0.5 public API (``jax.shard_map`` with
-``check_vma``, ``jax.lax.axis_size``); deployment images sometimes pin
-0.4.x where those live under ``jax.experimental.shard_map`` /
-``check_rep`` and axis sizes are read via a literal ``psum``.  Everything
-that maps over a mesh goes through these two helpers.
+Everything that maps over a mesh goes through ``shard_map`` here, so the
+replication-check setting lives in one place.
 """
 from __future__ import annotations
 
@@ -12,18 +9,11 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(axis_name: str) -> int:
     """Static size of a mesh axis from inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # a psum of the literal 1 is folded to a static int under tracing
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)

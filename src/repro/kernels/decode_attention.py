@@ -18,18 +18,25 @@ Two entry points share one online-softmax body:
     arena's scratch row (index ``n_slots`` == N_rows - 1) is an
     explicit sentinel for batch padding and may appear many times.
 
-For GQA we process one kv head per grid step and compute all ``g = Hq/Hkv``
-grouped query heads together, so the query tile is [g, Dh] (padded to the
-8-sublane minimum by Mosaic automatically).
+For GQA every kv head is folded together with its ``g = Hq/Hkv`` grouped
+query heads, so the query tile is [g, Dh] (padded to the 8-sublane minimum
+by Mosaic automatically).  The dense kernel takes one kv head per grid
+step, grid = (B, Hkv, nkv).  The paged kernel cannot: in the arena's
+[N_rows, S, Hkv, Dh] layout the head axis is second-minor, and Mosaic only
+accepts a block whose last two dims are (8, 128)-divisible or whole, so a
+one-head block is refused.  Its k/v block is therefore
+``(1, block_kv, Hkv, Dh)`` — every head, one DMA per kv block — and the
+kernel loops over the heads with static indices, grid = (B, nkv).
 
 The kv-cache length can exceed the number of valid entries (bucketed cache
 allocation); ``kv_len`` [B] masks out unwritten slots.  ``kv_len`` rides in
 scalar-prefetch SMEM so the mask costs no extra HBM traffic.
 
-Grid = (B, Hkv, nkv) with kv innermost; f32 accumulator in VMEM scratch.
-Both variants execute the identical per-block math over identical block
-contents, so paged and dense outputs agree BITWISE — the serving engine
-relies on this to keep paged results exactly equal to the gather path.
+kv is innermost in both grids; f32 accumulators live in VMEM scratch.
+Both variants run the identical per-(head, block) math over identical
+block contents, so paged and dense outputs agree BITWISE — the serving
+engine relies on this to keep paged results exactly equal to the gather
+path.
 """
 from __future__ import annotations
 
@@ -44,25 +51,57 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _decode_block(q, k, v, kv_len, k0, acc_ref, m_ref, l_ref):
+    """Fold one kv block ``k``/``v`` [bkv, dh] into the online-softmax
+    state of one head group (query ``q`` [g, dh], already scaled)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                    # [g, bkv]
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    valid = kpos < kv_len
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_ref[:, 0]
+    l_prev = l_ref[:, 0]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.where(valid, jnp.exp(s - m_cur[:, None]), 0.0)
+    l_ref[:, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
+    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[:, 0] = m_cur
+
+
+def _init_state(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _finish(acc_ref, l_ref):
+    l = jnp.maximum(l_ref[:, 0], 1e-30)
+    return acc_ref[...] / l[:, None]
+
+
 def _decode_kernel(
     kv_len_ref,                   # SMEM [B] scalar prefetch
-    q_ref, k_ref, v_ref,          # VMEM blocks
+    q_ref, k_ref, v_ref,          # VMEM blocks [1, 1, g, dh] / [1, 1, bkv, dh]
     o_ref,
     acc_ref, m_ref, l_ref,
     *,
     sm_scale: float,
     block_kv: int,
     num_kv_blocks: int,
-    paged: bool = False,
 ):
     b = pl.program_id(0)
     jk = pl.program_id(2)
 
     @pl.when(jk == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     kv_len = kv_len_ref[b]
     k0 = jk * block_kv
@@ -70,38 +109,50 @@ def _decode_kernel(
     @pl.when(k0 < kv_len)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # [g, dh]
-        if paged:
-            # arena block [1, bkv, 1, dh] (model layout, slot-addressed
-            # by the BlockSpec index map) -> [bkv, dh]
-            k = k_ref[0, :, 0, :].astype(jnp.float32)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)             # [bkv, dh]
-            v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [g, bkv]
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos < kv_len
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_cur[:, None]), 0.0)
-        l_ref[:, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, 0] = m_cur
+        k = k_ref[0, 0].astype(jnp.float32)                 # [bkv, dh]
+        v = v_ref[0, 0].astype(jnp.float32)
+        _decode_block(q, k, v, kv_len, k0, acc_ref, m_ref, l_ref)
 
     @pl.when(jk == num_kv_blocks - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    def _done():
+        o_ref[0, 0] = _finish(acc_ref, l_ref).astype(o_ref.dtype)
+
+
+def _paged_decode_kernel(
+    rows_ref, kv_len_ref,         # SMEM scalar prefetch (rows feed index maps)
+    q_ref, k_ref, v_ref,          # VMEM [1, Hkv, g, dh] / [1, bkv, Hkv, dh]
+    o_ref,
+    acc_ref, m_ref, l_ref,        # VMEM scratch, leading dim Hkv
+    *,
+    sm_scale: float,
+    block_kv: int,
+    num_kv_blocks: int,
+    num_kv_heads: int,
+):
+    b = pl.program_id(0)
+    jk = pl.program_id(1)
+
+    @pl.when(jk == 0)
+    def _init():
+        _init_state(acc_ref, m_ref, l_ref)
+
+    kv_len = kv_len_ref[b]
+    k0 = jk * block_kv
+
+    @pl.when(k0 < kv_len)
+    def _compute():
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32) * sm_scale  # [g, dh]
+            k = k_ref[0, :, h, :].astype(jnp.float32)       # [bkv, dh]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            _decode_block(q, k, v, kv_len, k0, acc_ref.at[h], m_ref.at[h],
+                          l_ref.at[h])
+
+    @pl.when(jk == num_kv_blocks - 1)
+    def _done():
+        for h in range(num_kv_heads):
+            o_ref[0, h] = _finish(acc_ref.at[h],
+                                  l_ref.at[h]).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -173,13 +224,14 @@ def paged_decode_attention_pallas(
     """True paged decode: KV blocks are DMA'd straight from the arena.
 
     ``slots`` and ``kv_len`` both ride in scalar-prefetch SMEM; the k/v
-    index maps address block ``(slots[b], j, h)`` of the UNGATHERED arena,
-    so per-launch HBM traffic is the addressed blocks only — the dense
+    index maps address block ``(slots[b], j)``, all heads, of the
+    UNGATHERED arena, so per-launch HBM traffic is the addressed blocks
+    only — the dense
     path's [B, S] gather copy (``jnp.take``) is eliminated.  The arena
     keeps the model-side [rows, S, Hkv, Dh] layout; only the tiny query
     is reshaped.  ``S`` must be a multiple of the effective kv block (the
     serving arena rounds its per-slot allocation up on Pallas runtimes);
-    callers with ragged arenas use the gather fallback in ``ops``.
+    ``ops.arena_decode_attention`` refuses ragged arenas.
 
     Slot contract: every value must lie in [0, N_rows); the last arena
     row (``n_slots`` == N_rows - 1) is the serving scratch row and is a
@@ -189,7 +241,7 @@ def paged_decode_attention_pallas(
 
     ``block_tables`` [B, S // block_kv] generalizes the indirection from
     one row per sequence to one row per CACHE BLOCK: block ``j`` of
-    sequence ``b`` is DMA'd from ``(block_tables[b, j], j, h)``.  The
+    sequence ``b`` is DMA'd from ``(block_tables[b, j], j)``.  The
     within-row block index stays ``j`` — a shared prefix row stores its
     KV at the same positions every consumer reads it at — which is what
     lets many documents' leading blocks point at one pinned prefix row
@@ -213,47 +265,42 @@ def paged_decode_attention_pallas(
     qg = q.reshape(B, Hkv, g, Dh)
 
     kernel = functools.partial(
-        _decode_kernel,
+        _paged_decode_kernel,
         sm_scale=scale,
         block_kv=block_kv,
         num_kv_blocks=nkv,
-        paged=True,
+        num_kv_heads=Hkv,
     )
 
     if block_tables is None:
-        def kv_map(b, h, j, slots_ref, kv_len_ref):
-            return (slots_ref[b], j, h, 0)
+        def kv_map(b, j, slots_ref, kv_len_ref):
+            return (slots_ref[b], j, 0, 0)
         row_ids = slots.astype(jnp.int32)
     else:
         assert block_tables.shape == (B, nkv), (block_tables.shape, B, nkv)
 
-        def kv_map(b, h, j, bt_ref, kv_len_ref):
-            return (bt_ref[b, j], j, h, 0)
+        def kv_map(b, j, bt_ref, kv_len_ref):
+            return (bt_ref[b, j], j, 0, 0)
         row_ids = block_tables.astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # (rows, kv_len) — kv_len first in
-        grid=(B, Hkv, nkv),           # kernel args is the dense kernel's
-        in_specs=[                    # order; see call below
-            pl.BlockSpec((1, 1, g, Dh), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, Dh), kv_map),
-            pl.BlockSpec((1, block_kv, 1, Dh), kv_map),
+        num_scalar_prefetch=2,        # (rows, kv_len)
+        grid=(B, nkv),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, g, Dh), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block_kv, Hkv, Dh), kv_map),
+            pl.BlockSpec((1, block_kv, Hkv, Dh), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, Dh), lambda b, h, j, *_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, g, Dh), lambda b, j, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, Dh), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((Hkv, g, Dh), jnp.float32),
+            pltpu.VMEM((Hkv, g, 128), jnp.float32),
+            pltpu.VMEM((Hkv, g, 128), jnp.float32),
         ],
     )
 
-    def paged_kernel(rows_ref, kv_len_ref, *rest):
-        # row ids are consumed by the index maps only; the body masks by
-        # kv_len exactly like the dense kernel (bitwise-equal math)
-        return kernel(kv_len_ref, *rest)
-
     out = pl.pallas_call(
-        paged_kernel,
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dh), q.dtype),
         interpret=interpret,

@@ -40,7 +40,7 @@ def _check_slots(slots, n_rows: int, where: str) -> None:
     the arena's row count; the LAST row (index ``n_slots == n_rows - 1``)
     is the serving engine's scratch row and is an explicitly legal
     sentinel that may appear any number of times (batch padding).
-    Anything outside that range is a caller bug: the gather fallback's
+    Anything outside that range is a caller bug: the gather path's
     ``jnp.take`` would silently CLIP it to the nearest edge row and the
     paged kernels would DMA an unrelated row — both produce plausible
     garbage rather than an error.  Under ``jit`` the values are traced
@@ -290,6 +290,15 @@ def decode_attention(
     raise ValueError(f"unknown decode impl {impl!r}")
 
 
+def _refuse(where: str, why: str) -> None:
+    """A Pallas impl never degrades to the gather/reference path: a shape
+    its kernel cannot take is a caller bug (arenas built for a Pallas
+    runtime always tile), so say which constraint failed."""
+    raise ValueError(
+        f"{where}: the Pallas kernel cannot take this shape ({why}); the "
+        f"gather fallback exists only for impl='xla'/'naive'")
+
+
 def arena_decode_attention(
     q: jnp.ndarray,               # [B, Hq, Dh]
     k_arena: jnp.ndarray,         # [N_rows, S, Hkv, Dh] persistent arena
@@ -309,42 +318,43 @@ def arena_decode_attention(
     arena row per sequence to one row per cache block (column ``j`` names
     the row holding positions ``[j * block, (j+1) * block)``), which is
     how many documents share a pinned operation-prefix row.  The table is
-    full-width; its granularity is inferred from its shape.  When the
-    granularity matches the kernel's effective kv block the table rides
-    in scalar-prefetch SMEM; otherwise (and on ``xla``/``naive``) the
-    blocks are gathered into dense per-sequence caches first — a pure
-    bit-move, so both planes stay bitwise-identical.
+    full-width; its granularity is inferred from its shape.  On Pallas
+    impls the table rides in scalar-prefetch SMEM and its granularity is
+    the kernel's kv block; on ``xla``/``naive`` the blocks are gathered
+    into dense per-sequence caches first — a pure bit-move, so both
+    planes stay bitwise-identical.
 
     The serving engine keeps one preallocated KV arena per length bucket
     and addresses sequences by slot id.  On Pallas runtimes the slot
     indices ride in scalar-prefetch SMEM and the kernel's k/v index maps
     DMA ``k_arena[slots[b]]`` blocks in place — no [B, S] gather copy is
     materialized, so per-launch HBM traffic no longer scales with the
-    gathered batch.  ``xla``/``naive`` keep the gather-then-reference
-    path as the correctness oracle and CPU fallback (also used when the
-    arena's cache axis is not a kv-block multiple — only possible for
-    arenas built on non-Pallas runtimes).
+    gathered batch.  The arena's cache axis must then be a kv-block
+    multiple (Pallas-runtime arenas are); any other shape raises
+    ``ValueError``.  ``xla``/``naive`` keep the gather-then-reference
+    path as the correctness oracle and CPU plane.
 
     Slot contract: values must be in ``[0, N_rows)``; the last row
     (``n_slots`` == N_rows - 1) is the scratch row, an explicitly legal
     padding sentinel that may repeat.  Out-of-range ids raise when the
     values are concrete (see ``_check_slots``); under ``jit`` the gather
-    fallback inherits ``jnp.take`` clip semantics and the paged kernel's
+    path inherits ``jnp.take`` clip semantics and the paged kernel's
     behaviour is undefined — callers own the bound.
     """
     S = k_arena.shape[1]
+    pallas = impl in ("pallas", "pallas_interpret")
     if block_tables is not None:
         _check_slots(block_tables, k_arena.shape[0],
                      "arena_decode_attention block_tables")
         sanitize.notify_rows("arena_decode_attention block_tables",
                              block_tables, k_arena.shape[0] - 1)
         tb = _block_granularity(block_tables, S, "arena_decode_attention")
-        if impl in ("pallas", "pallas_interpret") \
-                and tb == min(block_kv, S) and S % tb == 0:
+        if pallas:
+            # the kernel's kv block IS the table granularity
             return paged_decode_attention_pallas(
                 q, k_arena, v_arena, slots, kv_len,
                 block_tables=block_tables, sm_scale=sm_scale,
-                block_kv=block_kv, interpret=(impl == "pallas_interpret"))
+                block_kv=tb, interpret=(impl == "pallas_interpret"))
         k = _gather_block_rows(k_arena, block_tables, tb)
         v = _gather_block_rows(v_arena, block_tables, tb)
         return decode_attention(q, k, v, kv_len, sm_scale=sm_scale,
@@ -352,11 +362,14 @@ def arena_decode_attention(
     _check_slots(slots, k_arena.shape[0], "arena_decode_attention")
     sanitize.notify_rows("arena_decode_attention", slots,
                          k_arena.shape[0] - 1)
-    if impl in ("pallas", "pallas_interpret"):
-        if S % min(block_kv, S) == 0:
-            return paged_decode_attention_pallas(
-                q, k_arena, v_arena, slots, kv_len, sm_scale=sm_scale,
-                block_kv=block_kv, interpret=(impl == "pallas_interpret"))
+    if pallas:
+        if S % min(block_kv, S):
+            _refuse("arena_decode_attention",
+                    f"cache axis {S} is not a multiple of kv block "
+                    f"{block_kv}")
+        return paged_decode_attention_pallas(
+            q, k_arena, v_arena, slots, kv_len, sm_scale=sm_scale,
+            block_kv=block_kv, interpret=(impl == "pallas_interpret"))
     k = jnp.take(k_arena, slots, axis=0)
     v = jnp.take(v_arena, slots, axis=0)
     return decode_attention(q, k, v, kv_len, sm_scale=sm_scale, impl=impl,
@@ -384,61 +397,63 @@ def attention_paged(
 
     ``block_tables`` [B, S_alloc // block] is the per-block indirection
     of ``arena_decode_attention``: shared prefix rows appear in many
-    documents' leading columns.  The Pallas kernel consumes the first
-    ``kv_valid // block`` columns through scalar-prefetch SMEM when the
-    granularities line up; any other shape (and ``xla``/``naive``)
-    gathers blocks into dense caches — bitwise the same keys either way.
+    documents' leading columns.  The Pallas kernel consumes the columns
+    covering ``kv_valid`` through scalar-prefetch SMEM, with the table
+    granularity as its kv block; ``xla``/``naive`` gather blocks into
+    dense caches — the same keys either way.
 
     The paged twin of ``attention`` for the serving engine's extend step:
     queries are the suffix at ``q_offset`` and cached keys live in
     ``k_arena[slots[b], :kv_valid]`` (the caller scatters the new chunk's
     KV into the arena first).  Pallas runtimes resolve slots inside the
-    kernel when the block constraints hold (``Sq``/``kv_valid`` tile by
-    the effective blocks — serving launches do, since buckets and
-    fraction slices are block-aligned); ragged shapes and
-    ``xla``/``naive`` gather the addressed rows and defer to the dense
-    path, mirroring ``arena_decode_attention``'s fallback.  Slot contract
-    as in ``arena_decode_attention``.
+    kernel; a ragged ``Sq`` or ``kv_valid`` is padded up to the blocks,
+    which needs an arena whose cache axis holds the padded keys (Pallas
+    runtimes round it to a block multiple) or the call raises
+    ``ValueError``.  ``xla``/``naive`` gather the addressed rows and
+    defer to the dense path.  Slot contract as in
+    ``arena_decode_attention``.
     """
     S_alloc = k_arena.shape[1]
-    if block_tables is not None:
-        _check_slots(block_tables, k_arena.shape[0],
-                     "attention_paged block_tables")
-        sanitize.notify_rows("attention_paged block_tables", block_tables,
-                             k_arena.shape[0] - 1)
-        tb = _block_granularity(block_tables, S_alloc, "attention_paged")
-        Sq = q.shape[1]
-        if (impl in ("pallas", "pallas_interpret")
-                and Sq % min(block_q, Sq) == 0
-                and kv_valid % tb == 0 and tb == min(block_kv, kv_valid)):
-            qt = jnp.swapaxes(q, 1, 2)
-            out = paged_flash_attention_pallas(
-                qt, k_arena, v_arena, slots, kv_valid=kv_valid,
-                block_tables=block_tables[:, : kv_valid // tb],
-                causal=causal, window=window, q_offset=q_offset,
-                kv_len=kv_len, sm_scale=sm_scale, block_q=block_q,
-                block_kv=block_kv, interpret=(impl == "pallas_interpret"))
-            return jnp.swapaxes(out, 1, 2)
-        k = _gather_block_rows(k_arena, block_tables, tb)[:, :kv_valid]
-        v = _gather_block_rows(v_arena, block_tables, tb)[:, :kv_valid]
-        return attention(q, k, v, causal=causal, window=window,
-                         q_offset=q_offset, kv_len=kv_len, sm_scale=sm_scale,
-                         impl=impl, block_q=block_q, block_kv=block_kv)
-    _check_slots(slots, k_arena.shape[0], "attention_paged")
-    sanitize.notify_rows("attention_paged", slots, k_arena.shape[0] - 1)
+    rows = slots if block_tables is None else block_tables
+    where = "attention_paged" + ("" if block_tables is None
+                                 else " block_tables")
+    _check_slots(rows, k_arena.shape[0], where)
+    sanitize.notify_rows(where, rows, k_arena.shape[0] - 1)
     if impl in ("pallas", "pallas_interpret"):
         Sq = q.shape[1]
-        if (Sq % min(block_q, Sq) == 0
-                and kv_valid % min(block_kv, kv_valid) == 0):
-            qt = jnp.swapaxes(q, 1, 2)
-            out = paged_flash_attention_pallas(
-                qt, k_arena, v_arena, slots, kv_valid=kv_valid,
-                causal=causal, window=window, q_offset=q_offset,
-                kv_len=kv_len, sm_scale=sm_scale, block_q=block_q,
-                block_kv=block_kv, interpret=(impl == "pallas_interpret"))
-            return jnp.swapaxes(out, 1, 2)
-    k = jnp.take(k_arena, slots, axis=0)[:, :kv_valid]
-    v = jnp.take(v_arena, slots, axis=0)[:, :kv_valid]
+        if block_tables is None:
+            bk = min(block_kv, kv_valid)
+        else:               # the kernel's kv block IS the table granularity
+            bk = _block_granularity(block_tables, S_alloc, where)
+        bq = min(block_q, Sq)
+        # ragged extents tile by padding: extra query rows are sliced off
+        # below, and keys in [kv_valid, kv_pad) sit past every row's
+        # kv_len, so the mask hides them
+        kv_pad = -(-kv_valid // bk) * bk
+        sq_pad = -(-Sq // bq) * bq
+        if kv_pad > S_alloc:
+            _refuse(where, f"kv_valid {kv_valid} rounds up to {kv_pad} "
+                           f"keys, past the arena's {S_alloc}")
+        kv_len = (jnp.full((q.shape[0],), kv_valid, jnp.int32)
+                  if kv_len is None else jnp.minimum(kv_len, kv_valid))
+        qt = jnp.swapaxes(q, 1, 2)
+        if sq_pad > Sq:
+            qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - Sq), (0, 0)))
+        out = paged_flash_attention_pallas(
+            qt, k_arena, v_arena, slots, kv_valid=kv_pad,
+            block_tables=(None if block_tables is None
+                          else block_tables[:, : kv_pad // bk]),
+            causal=causal, window=window, q_offset=q_offset,
+            kv_len=kv_len, sm_scale=sm_scale, block_q=bq, block_kv=bk,
+            interpret=(impl == "pallas_interpret"))
+        return jnp.swapaxes(out[:, :, :Sq], 1, 2)
+    if block_tables is None:
+        k = jnp.take(k_arena, slots, axis=0)[:, :kv_valid]
+        v = jnp.take(v_arena, slots, axis=0)[:, :kv_valid]
+    else:
+        tb = _block_granularity(block_tables, S_alloc, where)
+        k = _gather_block_rows(k_arena, block_tables, tb)[:, :kv_valid]
+        v = _gather_block_rows(v_arena, block_tables, tb)[:, :kv_valid]
     return attention(q, k, v, causal=causal, window=window,
                      q_offset=q_offset, kv_len=kv_len, sm_scale=sm_scale,
                      impl=impl, block_q=block_q, block_kv=block_kv)
@@ -451,7 +466,7 @@ def relevance_score(
     b: jnp.ndarray,
     *,
     impl: str = DEFAULT_IMPL,
-    block_c: int = 128,
+    block_c: Optional[int] = None,      # None: sized to VMEM from T * D
 ) -> jnp.ndarray:
     if impl in ("naive", "xla"):
         return ref.relevance_reference(x, lengths, w, b)

@@ -9,15 +9,30 @@ immediately reduces it against the classifier weights.
 
 x: [C, T, D] chunk token embeddings, lengths: [C], w: [D], b: [1].
 Output: [C] sigmoid relevance scores (f32).
+
+The chunk tile ``block_c`` defaults to what fits VMEM: each chunk costs
+its double-buffered [T, D] input block plus the kernel's f32 copy, and
+the tile is sized to ``VMEM_BUDGET`` (half of Mosaic's default 16 MiB
+scope, leaving room for temporaries) in multiples of 8 sublanes.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+VMEM_BUDGET = 8 << 20
+
+
+def default_block_c(t: int, d: int, itemsize: int) -> int:
+    """Largest multiple of 8 chunks whose blocks fit ``VMEM_BUDGET``
+    (never below 8, the (8, 1) length/output tile's sublane minimum)."""
+    per_chunk = t * d * (2 * itemsize + 4)
+    return max(8, VMEM_BUDGET // per_chunk // 8 * 8)
 
 
 def _relevance_kernel(x_ref, len_ref, w_ref, b_ref, o_ref, *, block_c: int, t: int):
@@ -44,10 +59,12 @@ def relevance_score_pallas(
     w: jnp.ndarray,          # [D]
     b: jnp.ndarray,          # [] or [1]
     *,
-    block_c: int = 128,
+    block_c: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     C, T, D = x.shape
+    if block_c is None:
+        block_c = default_block_c(T, D, x.dtype.itemsize)
     block_c = min(block_c, C)
     # Ragged chunk counts (real corpora rarely land on a block multiple):
     # pad the chunk axis with zero-length chunks and slice them back off.

@@ -17,12 +17,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """The assigned production mesh: 16x16 per pod, 2 pods when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (used by tests with small device counts)."""
-    return jax.make_mesh(shape, axes)
+    """Mesh with *Auto* axes: the sharding rules in ``distributed`` leave
+    propagation to the compiler, which ``jax.make_mesh``'s default
+    Explicit axes refuse (e.g. the vocab-sharded embedding gather)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

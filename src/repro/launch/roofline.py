@@ -1,6 +1,7 @@
 """Three-term roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Terms per (arch x shape x mesh), all in seconds-per-step on TPU v5e:
+Terms per (arch x shape x mesh), all in seconds-per-step on TPU v5e
+(peaks from ``PEAKS``, keyed by device kind):
 
     compute    = HLO_FLOPs_per_chip   / peak_FLOPs     (197 TFLOP/s bf16)
     memory     = HLO_bytes_per_chip   / HBM_bw         (819 GB/s)
@@ -30,9 +31,30 @@ import numpy as np
 from ..config import SHAPES, resolve
 from ..configs import get_config
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator."""
+
+    flops_bf16: float        # FLOP/s
+    hbm_bw: float            # bytes/s
+    hbm_bytes: float         # device memory
+    ici_bw: float            # bytes/s per chip-to-chip link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A device missing from the table
+# has no peaks: callers report "not measured", never another chip's roof.
+_V5E = DevicePeaks(
+    flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+    ici_bw=50e9,             # 1,600 Gbit/s per chip over 4 links
+    source='Google Cloud documentation, "TPU v5e"')
+PEAKS: Dict[str, DevicePeaks] = {"TPU v5 lite": _V5E}     # v5e's kind
+
+
+def device_peaks(device_kind: str) -> Optional[DevicePeaks]:
+    """Peaks of ``device_kind``, or None when the table does not know it."""
+    return PEAKS.get(device_kind)
 
 
 def decode_launch_bytes(params_bytes: float, kv_bytes_per_step: float,
@@ -48,9 +70,9 @@ def decode_launch_bytes(params_bytes: float, kv_bytes_per_step: float,
 
 
 def bandwidth_utilization(bytes_moved: float, seconds: float,
-                          bw: float = HBM_BW) -> float:
-    """Fraction of the per-chip HBM roof a measured transfer achieved
-    (``serving/telemetry.py`` calls this per decode launch with the
+                          bw: float) -> float:
+    """Fraction of an HBM roof ``bw`` (bytes/s) a measured transfer
+    achieved (the serving engine calls this per decode launch with the
     ``block_until_ready`` device segment as ``seconds``)."""
     if seconds <= 0.0:
         return 0.0
@@ -228,12 +250,12 @@ def analyze(result: Dict) -> Optional[RooflineRow]:
     flops_pc = ex["flops"]                       # per-chip (SPMD program)
     bytes_pc = ex["bytes_accessed"]
     coll_pc = float(sum(ex.get("collective_bytes", {}).values()))
-    compute_s = flops_pc / PEAK_FLOPS
-    memory_hlo_s = bytes_pc / HBM_BW
+    compute_s = flops_pc / _V5E.flops_bf16
+    memory_hlo_s = bytes_pc / _V5E.hbm_bw
     floor_bytes = analytic_memory_floor(result["arch"], result["shape"],
                                         chips)
-    memory_s = min(max(floor_bytes / HBM_BW, 0.0), memory_hlo_s)
-    collective_s = coll_pc / ICI_BW
+    memory_s = min(max(floor_bytes / _V5E.hbm_bw, 0.0), memory_hlo_s)
+    collective_s = coll_pc / _V5E.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)

@@ -21,6 +21,7 @@ The module also exports the stream drivers used by
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -28,14 +29,16 @@ import jax
 import numpy as np
 
 from ..config import resolve
-from ..configs import get_reduced
+from ..configs import get_config, get_reduced
 from ..core.tasks import Cascade, Task, TaskConfig
 from ..data.documents import generate_corpus
 from ..data.tokenizer import HashWordTokenizer
 from ..models.model import LM
-from ..models.runtime import CPU_TEST
+from ..models.runtime import CPU_TEST, Runtime
 from ..serving.engine import (CascadeEngine, CascadeServer, EngineResult,
                               LMBackend, QueryHandle)
+from ..serving.scheduler import RESOLVED
+from .compile_cache import enable_compile_cache
 
 
 def poisson_arrivals(doc_ids, rate: float, seed: int = 0
@@ -151,21 +154,35 @@ def warm_arena(engine: CascadeEngine, cascade: Cascade,
 def build_engine(batch_size: int, slot_budget: Optional[int],
                  retire_after: int, proxy_arch: str = "llama3_2_1b",
                  oracle_arch: str = "qwen3_1_7b",
-                 byte_budget: Optional[int] = None) -> CascadeEngine:
-    """Tiny untrained proxy/oracle backends (mechanics demo, CPU-friendly).
+                 byte_budget: Optional[int] = None, *,
+                 seed: int = 0, inflight: int = 1) -> CascadeEngine:
+    """The proxy/oracle pair behind one server, with random weights from
+    ``seed`` and up to ``inflight`` launches dispatched ahead.
+
+    On a TPU both models run at their published widths in bf16 on the
+    paged Pallas plane (``Runtime(attn_impl="pallas")``).  Elsewhere they
+    are cut to 2 layers and a 512-word vocab in f32 with the reference
+    attention — the CPU mechanics demo the tests drive.  Each backend
+    tokenizes at its own model's vocab.
 
     Returns a ``CascadeEngine`` — which IS a ``CascadeServer``, so callers
     can either drive the single-query compatibility API (``run``) or
     ``register`` several queries on it.
     """
-    tokz = HashWordTokenizer(vocab_size=512)
+    on_tpu = jax.default_backend() == "tpu"
 
-    def mk(name, arch, seed, rate):
-        cfg = get_reduced(arch, dtype="float32", vocab_size=512, num_layers=2)
-        m = LM(resolve(cfg, tp=1), CPU_TEST)
+    def mk(name, arch, seed_offset, rate):
+        if on_tpu:
+            cfg, rt = get_config(arch), Runtime(attn_impl="pallas")
+        else:
+            cfg = get_reduced(arch, dtype="float32", vocab_size=512,
+                              num_layers=2)
+            rt = CPU_TEST
+        m = LM(resolve(cfg, tp=1), rt)
         return LMBackend(name=name, model=m,
-                         params=m.init(jax.random.PRNGKey(seed)),
-                         tokenizer=tokz, rate_per_token=rate,
+                         params=m.init(jax.random.PRNGKey(seed + seed_offset)),
+                         tokenizer=HashWordTokenizer(cfg.vocab_size),
+                         rate_per_token=rate,
                          slot_budget=slot_budget, byte_budget=byte_budget,
                          retire_after=retire_after)
 
@@ -175,7 +192,8 @@ def build_engine(batch_size: int, slot_budget: Optional[int],
     }
     backends = {"proxy": mk("proxy", proxy_arch, 1, 0.15e-6),
                 "oracle": mk("oracle", oracle_arch, 2, 2.50e-6)}
-    return CascadeEngine(backends, ops, n_classes=2, batch_size=batch_size)
+    return CascadeEngine(backends, ops, n_classes=2, batch_size=batch_size,
+                         inflight=inflight)
 
 
 def tenant_cascades(n: int) -> List[Cascade]:
@@ -219,15 +237,17 @@ def main():
     ap.add_argument("--byte-budget", type=int, default=None,
                     help="per-backend arena byte cap (eviction pressure)")
     ap.add_argument("--retire-after", type=int, default=64)
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the corpus, the arrivals and the weights")
     ap.add_argument("--trace-out", type=str, default=None, metavar="PATH",
                     help="write a Chrome/Perfetto trace-event JSON of the "
                          "measured serving pass (enables level='trace' "
                          "telemetry; open at https://ui.perfetto.dev)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     server = build_engine(args.batch, args.slot_budget, args.retire_after,
-                          byte_budget=args.byte_budget)
+                          byte_budget=args.byte_budget, seed=args.seed)
     if args.trace_out:
         server.telemetry.level = "trace"
     cascades = tenant_cascades(args.tenants)
@@ -286,6 +306,20 @@ def main():
         write_chrome_trace(server.telemetry, args.trace_out)
         print(f"wrote Perfetto trace to {args.trace_out} "
               f"(open at https://ui.perfetto.dev)")
+    unresolved = unresolved_docs(results)
+    if unresolved:
+        sys.exit(f"{len(unresolved)} document(s) did not resolve: "
+                 + ", ".join(f"query {q} doc {d}: {st}"
+                             for q, d, st in unresolved[:8]))
+
+
+def unresolved_docs(results: Mapping[int, EngineResult]
+                    ) -> List[Tuple[int, int, str]]:
+    """``(query, doc, status)`` of every document that ended other than
+    RESOLVED — a refused compile or device fault surfaces as FAILED after
+    its retries, so a run that reports any is not a successful run."""
+    return [(q, d, st) for q, r in sorted(results.items())
+            for d, st in sorted(r.status.items()) if st != RESOLVED]
 
 
 if __name__ == "__main__":

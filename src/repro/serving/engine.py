@@ -177,15 +177,15 @@ from .telemetry import (EV_COW_COPY, EV_ESCALATE, EV_EVICT, EV_LAUNCH,
                         EV_PREFIX_HIT, EV_QUARANTINE, EV_RETRY, EV_SUBMIT,
                         LaunchRecord, Telemetry)
 
-_bw_utilization = None     # lazy launch/roofline import (avoids a cycle)
 
-
-def _bw_util(bytes_moved: float, seconds: float) -> float:
-    global _bw_utilization
-    if _bw_utilization is None:
-        from ..launch.roofline import bandwidth_utilization
-        _bw_utilization = bandwidth_utilization
-    return _bw_utilization(bytes_moved, seconds)
+def _bw_util(bytes_moved: float, seconds: float) -> Optional[float]:
+    """HBM-roof share of a decode launch, or None on a device whose peaks
+    are unknown (``launch.roofline.PEAKS``) — never another chip's roof."""
+    from ..launch.roofline import bandwidth_utilization, device_peaks
+    peaks = device_peaks(jax.devices()[0].device_kind)
+    if peaks is None:
+        return None
+    return bandwidth_utilization(bytes_moved, seconds, peaks.hbm_bw)
 
 
 class ServerStalledError(RuntimeError):

@@ -612,8 +612,9 @@ class Telemetry:
                 "overlap_hidden_frac": overlap_hidden_fraction(
                     self.inflight_total_s, self.device_total_s),
                 "mean_launch_gap_ms": 1e3 * self.mean_launch_gap_s(),
+                # None where the device's HBM roof is unknown
                 "decode_bw_util_mean": (sum(utils) / len(utils)
-                                        if utils else 0.0),
+                                        if utils else None),
             },
         }
 

@@ -18,22 +18,13 @@ from repro.distributed.collectives import (compressed_psum,            # noqa: E
                                            ring_all_gather,
                                            ring_reduce_scatter,
                                            sp_decode_attention)
+from repro.distributed.compat import shard_map  # noqa: E402
 from repro.kernels import ref   # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 assert len(jax.devices()) == 8
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax.shard_map (>=0.5, check_vma kw) vs
-    jax.experimental.shard_map (0.4.x, check_rep kw)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+mesh = make_mesh((4, 2), ("data", "model"))
 
 
 def check_ring_all_gather():
@@ -117,7 +108,7 @@ def check_matmul_ag_overlap():
 def check_moe_ep_matches_tp_dense():
     from repro.models.moe import init_moe, moe_apply_ep_a2a, \
         moe_apply_tp_dense
-    mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh4 = make_mesh((4, 2), ("data", "model"))
     d, f, E = 16, 32, 4
     params = init_moe(jax.random.PRNGKey(6), d, f, E, jnp.float32)
     x = 0.3 * jax.random.normal(jax.random.PRNGKey(7), (8, 4, d))
@@ -180,7 +171,7 @@ def check_checkpoint_reshard():
         ck = Checkpointer(d)
         ck.save(1, {"x": xs})
         ck.wait()
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         tgt = {"x": NamedSharding(mesh2, P("model", None))}
         restored = ck.restore(1, {"x": x}, shardings=tgt)
         np.testing.assert_allclose(np.asarray(restored["x"]), np.asarray(x))
@@ -204,7 +195,7 @@ def check_elastic_remesh_training():
     from repro.train.optimizer import init_opt_state
     from repro.train.train_loop import TrainConfig, make_train_step
 
-    big = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    big = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_reduced("llama3_2_1b", dtype="float32", vocab_size=512,
                       num_layers=2, num_heads=4, num_kv_heads=2)
     rcfg = resolve(cfg, tp=2)
@@ -233,7 +224,7 @@ def check_elastic_remesh_training():
         # pod failure: 4 chips survive -> remesh plan
         rp = plan_remesh(4, old_dp=4)
         assert rp is not None and rp.chips == 4
-        small = jax.make_mesh((2, 2), ("data", "model"))
+        small = make_mesh((2, 2), ("data", "model"))
         m_small = LM(rcfg, Runtime(attn_impl="naive", remat=False,
                                    mesh=small))
         ps = tree_pspecs(m_small.param_specs(), small)

@@ -209,8 +209,9 @@ def test_paged_decode_bitwise_equals_gather(slots, kv_len):
 
 
 def test_paged_decode_ragged_arena_falls_back_to_gather():
-    """S not a kv-block multiple: the entry point silently uses the
-    gather + padded dense kernel (only non-Pallas-built arenas hit this)."""
+    """S not a kv-block multiple (an arena built for a non-Pallas
+    runtime): ``xla`` gathers the rows and runs the reference; a Pallas
+    impl refuses the shape instead of silently doing the same."""
     N, B, S, Hq, Hkv, Dh = 4, 2, 72, 4, 2, 16   # 72 % 16 != 0
     key = jax.random.PRNGKey(10)
     q = jax.random.normal(key, (B, Hq, Dh), jnp.float32)
@@ -218,12 +219,54 @@ def test_paged_decode_ragged_arena_falls_back_to_gather():
     slots = jnp.asarray([3, 1], jnp.int32)
     kv_len = jnp.asarray([40, 72], jnp.int32)
     out = ops.arena_decode_attention(q, k_arena, v_arena, slots, kv_len,
-                                     impl="pallas_interpret", block_kv=16)
+                                     impl="xla", block_kv=16)
     out_ref = ref.decode_reference(
         q, k_arena[np.asarray(slots)], v_arena[np.asarray(slots)],
         kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                atol=2e-5, rtol=1e-2)
+    with pytest.raises(ValueError, match="cannot take this shape"):
+        ops.arena_decode_attention(q, k_arena, v_arena, slots, kv_len,
+                                   impl="pallas_interpret", block_kv=16)
+
+
+@pytest.mark.parametrize("q_off,Sq,kv_valid", [
+    (0, 24, 24),       # ragged prefill: Sq and kv_valid pad up to 32
+    (16, 24, 40),      # ragged extension past one block
+])
+def test_paged_extend_pads_ragged_extents(q_off, Sq, kv_valid):
+    """A Pallas impl tiles ragged ``Sq``/``kv_valid`` by padding inside
+    the arena (extra queries dropped, extra keys masked by kv_len)
+    rather than gathering: the result matches the reference."""
+    N, B, S_alloc, Hq, Hkv, Dh = 5, 2, 64, 4, 2, 16
+    key = jax.random.PRNGKey(14)
+    q = jax.random.normal(key, (B, Sq, Hq, Dh), jnp.float32)
+    k_arena, v_arena = _mk_arena(key, N, S_alloc, Hkv, Dh)
+    slots = jnp.asarray([4, 1], jnp.int32)
+    kv_len = jnp.asarray([kv_valid, q_off + 3], jnp.int32)
+    out = ops.attention_paged(
+        q, k_arena, v_arena, slots, kv_valid=kv_valid, q_offset=q_off,
+        kv_len=kv_len, impl="pallas_interpret", block_q=16, block_kv=16)
+    kg = k_arena[np.asarray(slots)][:, :kv_valid]
+    vg = v_arena[np.asarray(slots)][:, :kv_valid]
+    out_ref = ref.mha_reference(q, kg, vg, causal=True, q_offset=q_off,
+                                kv_len=kv_len)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                               atol=3e-5, rtol=1e-3)
+
+
+def test_paged_extend_refuses_keys_past_the_arena():
+    """Padding that would read past the arena's cache axis is refused."""
+    N, B, S_alloc, Hq, Hkv, Dh = 3, 1, 40, 4, 2, 16    # 40 % 16 != 0
+    key = jax.random.PRNGKey(15)
+    q = jax.random.normal(key, (B, 8, Hq, Dh), jnp.float32)
+    k_arena, v_arena = _mk_arena(key, N, S_alloc, Hkv, Dh)
+    with pytest.raises(ValueError, match="past the arena"):
+        ops.attention_paged(q, k_arena, v_arena, jnp.asarray([0]),
+                            kv_valid=40, q_offset=32,
+                            impl="pallas_interpret", block_q=16,
+                            block_kv=16)
 
 
 @pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
@@ -394,6 +437,32 @@ def test_paged_extend_block_tables_bitwise(impl):
         q, km, vm, ident, kv_valid=kv_valid, q_offset=q_off,
         kv_len=kv_len, impl=impl, block_q=tb, block_kv=tb)
     np.testing.assert_array_equal(np.asarray(out_bt), np.asarray(out_mat))
+
+
+@pytest.mark.parametrize("kv_valid", [16, 48])
+def test_paged_extend_block_tables_coarse_granularity(kv_valid):
+    """A table whose block (32) differs from the runtime's kv block (16)
+    is read by the Pallas kernel at the table's granularity — kv_valid
+    that is not a table-block multiple pads up and masks — and matches
+    the gather reference."""
+    N, B, S_alloc, Hq, Hkv, Dh, tb = 5, 2, 64, 4, 2, 16, 32
+    key = jax.random.PRNGKey(24)
+    Sq, q_off = 16, kv_valid - 16
+    q = jax.random.normal(key, (B, Sq, Hq, Dh), jnp.float32)
+    k_arena, v_arena = _mk_arena(key, N, S_alloc, Hkv, Dh)
+    slots = jnp.asarray([1, 3], jnp.int32)
+    bt = np.repeat(np.asarray(slots)[:, None], S_alloc // tb, axis=1)
+    bt[:, 0] = 4
+    bt = jnp.asarray(bt, jnp.int32)
+    kv_len = jnp.asarray([kv_valid, q_off + 5], jnp.int32)
+    kw = dict(kv_valid=kv_valid, q_offset=q_off, kv_len=kv_len,
+              block_tables=bt, block_q=16, block_kv=16)
+    out = ops.attention_paged(q, k_arena, v_arena, slots,
+                              impl="pallas_interpret", **kw)
+    out_ref = ops.attention_paged(q, k_arena, v_arena, slots, impl="naive",
+                                  **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                               atol=3e-5, rtol=1e-3)
 
 
 def test_paged_decode_bf16_arena_tolerance():

@@ -108,3 +108,40 @@ def test_supported_shapes_follow_assignment_rules():
             assert not pure_full_attn, f"{arch} must skip long_500k"
         assert "train_4k" in cfg.supported_shapes
         assert "decode_32k" in cfg.supported_shapes   # all archs decode
+
+
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache sits
+    at the checkout's fixed .jax_cache (a moving path would never hit)."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(compile_cache.CHECKOUT, ".jax_cache")
+        assert os.path.isfile(os.path.join(compile_cache.CHECKOUT, "src",
+                                           "repro", "launch", "serve.py"))
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path     # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_unresolved_docs_lists_every_non_resolved_terminal():
+    """The serve entry point exits non-zero on any of these."""
+    from types import SimpleNamespace
+
+    from repro.launch.serve import unresolved_docs
+    results = {0: SimpleNamespace(status={3: "resolved", 1: "failed"}),
+               1: SimpleNamespace(status={0: "timed_out", 2: "resolved"})}
+    assert unresolved_docs(results) == [(0, 1, "failed"),
+                                        (1, 0, "timed_out")]
+    assert unresolved_docs({0: SimpleNamespace(status={5: "resolved"})}) \
+        == []

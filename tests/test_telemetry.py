@@ -438,9 +438,17 @@ def test_launch_record_derived_properties():
 
 
 def test_decode_launch_roofline_helpers():
-    from repro.launch.roofline import (HBM_BW, bandwidth_utilization,
-                                       decode_launch_bytes)
+    from repro.launch.roofline import (bandwidth_utilization,
+                                       decode_launch_bytes, device_peaks)
     b = decode_launch_bytes(params_bytes=1e9, kv_bytes_per_step=1e6, steps=2)
     assert b == pytest.approx(2 * (1e9 + 1e6))
-    assert bandwidth_utilization(HBM_BW, 1.0) == pytest.approx(1.0)
-    assert bandwidth_utilization(1e9, 0.0) == 0.0
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e.hbm_bw == 819e9 and v5e.flops_bf16 == 197e12
+    assert "TPU v5e" in v5e.source
+    assert bandwidth_utilization(v5e.hbm_bw, 1.0, v5e.hbm_bw) \
+        == pytest.approx(1.0)
+    assert bandwidth_utilization(1e9, 0.0, v5e.hbm_bw) == 0.0
+    # a device the table does not know has no roof, and no utilization
+    assert device_peaks("cpu") is None
+    from repro.serving.engine import _bw_util
+    assert _bw_util(1e9, 1.0) is None           # tests run on the CPU

@@ -1,0 +1,145 @@
+"""Compile the served kernels for a described TPU v5e, at real widths.
+
+Nothing runs: the TPU compiler, which is installed with libtpu, lowers
+each kernel for a chip that is described, not attached, and raises
+whatever the chip's compiler would raise (block shapes Mosaic refuses,
+scoped-VMEM overflows).  Each test asserts the Mosaic kernel is in the
+compiled program (``tpu_custom_call``).  Widths come from the two stage
+models the chip path serves: llama3.2-1b (32/8 heads, head_dim 64) and
+qwen3-1.7b (16/8 heads, head_dim 128), in bf16.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and every xdist
+worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.config import resolve
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.decode_attention import (decode_attention_pallas,
+                                            paged_decode_attention_pallas)
+from repro.kernels.flash_attention import (flash_attention_pallas,
+                                           paged_flash_attention_pallas)
+
+MODELS = ("llama3_2_1b", "qwen3_1_7b")
+
+
+def _heads(arch):
+    r = resolve(get_config(arch), tp=1)
+    return r.padded_heads, r.padded_kv_heads, r.head_dim
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (arena cache axis S, block_kv): the chip smoke's 256 bucket + 64 op
+# reserve (one block), and a 1024 row of two full 512 blocks
+@pytest.mark.parametrize("S,block_kv", [(320, 512), (1024, 512)])
+@pytest.mark.parametrize("arch", MODELS)
+def test_paged_decode_compiles(one_chip, arch, S, block_kv):
+    Hq, Hkv, Dh = _heads(arch)
+    B, N = 8, 17
+    args = (_sds(one_chip, (B, Hq, Dh)),
+            _sds(one_chip, (N, S, Hkv, Dh)), _sds(one_chip, (N, S, Hkv, Dh)),
+            _sds(one_chip, (B,), jnp.int32), _sds(one_chip, (B,), jnp.int32))
+
+    def fn(q, k, v, slots, kv_len):
+        return paged_decode_attention_pallas(q, k, v, slots, kv_len,
+                                             block_kv=block_kv)
+    assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
+@pytest.mark.parametrize("arch", MODELS)
+def test_paged_decode_block_tables_compile(one_chip, arch):
+    Hq, Hkv, Dh = _heads(arch)
+    B, N, S, blk = 4, 9, 1024, 512
+    args = (_sds(one_chip, (B, Hq, Dh)),
+            _sds(one_chip, (N, S, Hkv, Dh)), _sds(one_chip, (N, S, Hkv, Dh)),
+            _sds(one_chip, (B, S // blk), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32))
+
+    def fn(q, k, v, bt, kv_len):
+        return ops.arena_decode_attention(q, k, v, bt[:, 0], kv_len,
+                                          block_tables=bt, impl="pallas",
+                                          block_kv=blk)
+    assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
+# (arena S_alloc, Sq, kv_valid, q_offset): the served stage shapes —
+# a quarter-fraction prefill, the extension to the full 256 bucket, a
+# whole-bucket prefill, and a 512-block extension of a 1024 row
+@pytest.mark.parametrize("S,Sq,kv_valid,q_offset", [
+    (320, 64, 64, 0), (320, 192, 256, 64), (320, 256, 256, 0),
+    (1024, 512, 1024, 512)])
+@pytest.mark.parametrize("arch", MODELS)
+def test_paged_flash_compiles(one_chip, arch, S, Sq, kv_valid, q_offset):
+    Hq, Hkv, Dh = _heads(arch)
+    B, N = 8, 17
+    args = (_sds(one_chip, (B, Hq, Sq, Dh)),
+            _sds(one_chip, (N, S, Hkv, Dh)), _sds(one_chip, (N, S, Hkv, Dh)),
+            _sds(one_chip, (B,), jnp.int32), _sds(one_chip, (B,), jnp.int32))
+
+    def fn(q, k, v, slots, kv_len):
+        return paged_flash_attention_pallas(
+            q, k, v, slots, kv_valid=kv_valid, q_offset=q_offset,
+            kv_len=kv_len, block_q=512, block_kv=512)
+    assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
+@pytest.mark.parametrize("arch", MODELS)
+def test_dense_pair_compiles(one_chip, arch):
+    Hq, Hkv, Dh = _heads(arch)
+    B, S = 4, 1024
+    dec = (_sds(one_chip, (B, Hq, Dh)),
+           _sds(one_chip, (B, Hkv, S, Dh)), _sds(one_chip, (B, Hkv, S, Dh)),
+           _sds(one_chip, (B,), jnp.int32))
+    assert "tpu_custom_call" in _compile_text(
+        lambda q, k, v, n: decode_attention_pallas(q, k, v, n), *dec)
+    fl = (_sds(one_chip, (B, Hq, S, Dh)),
+          _sds(one_chip, (B, Hkv, S, Dh)), _sds(one_chip, (B, Hkv, S, Dh)))
+    assert "tpu_custom_call" in _compile_text(
+        lambda q, k, v: flash_attention_pallas(q, k, v), *fl)
+
+
+# the restructurer's chunk embeddings (64 words x 256 dims, f32) and a
+# model-width chunk (64 x 2048), both at the VMEM-derived default block_c
+@pytest.mark.parametrize("C,T,D", [(40, 64, 256), (256, 64, 2048)])
+def test_relevance_score_compiles(one_chip, C, T, D):
+    args = (_sds(one_chip, (C, T, D), jnp.float32),
+            _sds(one_chip, (C,), jnp.int32),
+            _sds(one_chip, (D,), jnp.float32),
+            _sds(one_chip, (), jnp.float32))
+
+    def fn(x, lengths, w, b):
+        return ops.relevance_score(x, lengths, w, b, impl="pallas")
+    assert "tpu_custom_call" in _compile_text(fn, *args)
