@@ -119,7 +119,7 @@ REQUIRED_TRUE = (
     # arena device state equal a level="off" run exactly); the trace
     # probe's spans must be well-formed under injected faults, nothing
     # dropped from the bounded rings at gate scale, and every launch's
-    # sched/host/dispatch/device segments must sum to its wall time
+    # sched/host/dispatch/sync segments must sum to its wall time
     "telemetry.counters_bitwise_inert",
     "telemetry.trace_probe.spans_well_formed",
     "telemetry.trace_probe.no_dropped_events",

@@ -901,7 +901,7 @@ def run_telemetry_section(models, tokz, trace_out=None):
     tree and are gated exactly) re-runs at ``level="trace"``.  Spans must
     be well-formed under injected faults (SUBMIT-opened, terminal-closed,
     monotone stamps), nothing may be dropped at the gate workload's
-    scale, and each launch's sched/host/dispatch/device segments must sum
+    scale, and each launch's sched/host/dispatch/sync segments must sum
     to its wall time within 5% (exact by construction: host is the
     clamped residual).  Structural counts (spans, events, launch records,
     metric series) are deterministic — the chaos launch schedule is a
@@ -1049,7 +1049,7 @@ def run_overlap_section(models, tokz, inflight: int):
             "overlap_hidden_frac_inflight1": tl1["overlap_hidden_frac"],
             "overlap_hidden_frac": tlk["overlap_hidden_frac"],
             "inflight_s": tlk["inflight_s"],
-            "device_s": tlk["device_s"],
+            "sync_s": tlk["sync_s"],
         },
     }
     assert section["max_inflight_ge_2"], runs[k]["snap"]["server"]

@@ -324,7 +324,7 @@ def main():
     # additionally records per-document span events — submit, every
     # launch ridden, escalations, retries, injected faults, quarantine,
     # the terminal state — into a bounded ring.  The Chrome trace-event
-    # export lays launches (with their sched/host/dispatch/device
+    # export lays launches (with their sched/host/dispatch/sync
     # segments) on per-backend tracks and doc spans on per-query tracks.
     for be in backends.values():
         be.reset()
@@ -351,8 +351,8 @@ def main():
           f"spans well-formed: {snap['spans']['ok']}")
     print(f"   wall decomposition: sched {1e3 * tl['sched_s']:.1f} ms | "
           f"host {1e3 * tl['host_s']:.1f} ms | dispatch "
-          f"{1e3 * tl['dispatch_s']:.1f} ms | device "
-          f"{1e3 * tl['device_s']:.1f} ms; mean launch gap "
+          f"{1e3 * tl['dispatch_s']:.1f} ms | sync "
+          f"{1e3 * tl['sync_s']:.1f} ms; mean launch gap "
           f"{tl['mean_launch_gap_ms']:.2f} ms")
     print(f"   wrote {trace_path} — open at https://ui.perfetto.dev "
           f"(one track per backend with launch+segment slices, one per "

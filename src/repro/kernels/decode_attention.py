@@ -299,10 +299,12 @@ def paged_decode_attention_pallas(
         ],
     )
 
+    # a stable name: the kernel's events in a profiler trace carry it
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dh), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(row_ids, kv_len.astype(jnp.int32), qg, k_arena, v_arena)
     return out.reshape(B, Hq, Dh)

@@ -372,10 +372,12 @@ def paged_flash_attention_pallas(
     params = pltpu.CompilerParams(
         vmem_limit_bytes=int(min(max(need * 5 // 4, 32 << 20), 100 << 20)))
 
+    # a stable name: the kernel's events in a profiler trace carry it
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dh), q.dtype),
         compiler_params=params,
         interpret=interpret,
+        name="paged_flash_attention",
     )(row_ids, kv_len.astype(jnp.int32), q, k_arena, v_arena)

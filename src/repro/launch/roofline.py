@@ -57,28 +57,6 @@ def device_peaks(device_kind: str) -> Optional[DevicePeaks]:
     return PEAKS.get(device_kind)
 
 
-def decode_launch_bytes(params_bytes: float, kv_bytes_per_step: float,
-                        steps: int = 1) -> float:
-    """Structural HBM-traffic estimate of a decode-only serving launch.
-
-    A decode step is memory-bound: each generated token streams the full
-    parameter set plus the batch's live KV prefix from HBM.  ``steps``
-    is the op-suffix length (one readout per suffix token).  Activations
-    and the O(B) token writes are negligible against these two terms.
-    """
-    return steps * (float(params_bytes) + float(kv_bytes_per_step))
-
-
-def bandwidth_utilization(bytes_moved: float, seconds: float,
-                          bw: float) -> float:
-    """Fraction of an HBM roof ``bw`` (bytes/s) a measured transfer
-    achieved (the serving engine calls this per decode launch with the
-    ``block_until_ready`` device segment as ``seconds``)."""
-    if seconds <= 0.0:
-        return 0.0
-    return (float(bytes_moved) / float(seconds)) / bw
-
-
 def overlap_hidden_fraction(hidden_s: float, exposed_s: float) -> float:
     """Fraction of device time hidden behind host work by ahead-of-time
     dispatch: ``hidden / (hidden + exposed)``.

@@ -297,8 +297,8 @@ def main():
     tl = server.telemetry_snapshot()["timeline"]
     print(f"timeline: sched {1e3 * tl['sched_s']:.1f} ms, host "
           f"{1e3 * tl['host_s']:.1f} ms, dispatch "
-          f"{1e3 * tl['dispatch_s']:.1f} ms, device "
-          f"{1e3 * tl['device_s']:.1f} ms, idle wait "
+          f"{1e3 * tl['dispatch_s']:.1f} ms, sync "
+          f"{1e3 * tl['sync_s']:.1f} ms, idle wait "
           f"{1e3 * tl['idle_wait_s']:.1f} ms; mean launch gap "
           f"{tl['mean_launch_gap_ms']:.2f} ms")
     if args.trace_out:

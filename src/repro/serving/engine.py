@@ -175,17 +175,7 @@ from .scheduler import (FAILED, RESOLVED, TIMED_OUT, DocRequest, LaunchSpec,
                         ServeStats, SlotAllocator, StageConfig, fraction_len)
 from .telemetry import (EV_COW_COPY, EV_ESCALATE, EV_EVICT, EV_LAUNCH,
                         EV_PREFIX_HIT, EV_QUARANTINE, EV_RETRY, EV_SUBMIT,
-                        LaunchRecord, Telemetry)
-
-
-def _bw_util(bytes_moved: float, seconds: float) -> Optional[float]:
-    """HBM-roof share of a decode launch, or None on a device whose peaks
-    are unknown (``launch.roofline.PEAKS``) — never another chip's roof."""
-    from ..launch.roofline import bandwidth_utilization, device_peaks
-    peaks = device_peaks(jax.devices()[0].device_kind)
-    if peaks is None:
-        return None
-    return bandwidth_utilization(bytes_moved, seconds, peaks.hbm_bw)
+                        LEVEL_OFF, LaunchRecord, Telemetry)
 
 
 class ServerStalledError(RuntimeError):
@@ -294,9 +284,10 @@ class GroupTicket:
     that touches the ticket's rows while it is in flight raises
     ``ArenaRaceError`` — exactly the race the brackets were built to
     audit.  Host-side billing metadata (``new_d``/``cached_d``/
-    ``op_len``) and structural traffic (``copy_bytes``/``hbm_bytes``)
-    are captured at dispatch so concurrent tickets never race on backend
-    scratch attributes."""
+    ``op_len``) and structural traffic (``copy_bytes``) are captured at
+    dispatch so concurrent tickets never race on backend scratch
+    attributes.  ``launch`` is the server's attempt index (the ``launch``
+    argument of the ticket's ``serve.dispatch``/``serve.sync`` spans)."""
 
     ids: List[int]
     bucket: int
@@ -308,11 +299,11 @@ class GroupTicket:
     op_len: int                      # billed op suffix (P on prefix plane)
     san: Any                         # ArenaSanitizer or None
     san_ticket: Any                  # open begin_launch bracket (or None)
-    timing: Dict[str, float]         # host/dispatch at dispatch; +device
+    timing: Dict[str, float]         # host/dispatch at dispatch; +sync
     ts_enqueue: float                # jit call began (dispatch segment)
     ts_dispatched: float             # dispatch_group returned control
     copy_bytes: int
-    hbm_bytes: Optional[float]
+    launch: int = -1
     ts_sync: float = 0.0             # block_until_ready entered
     ts_ready: float = 0.0            # device results host-visible
 
@@ -373,14 +364,15 @@ class LMBackend:
     pressure_retired: int = 0        # buckets freed mid-eviction (byte budget)
     # Derived view kept for compatibility: host assembly + async dispatch
     # wall-clock, exactly the pre-telemetry lumped scalar.  The per-launch
-    # decomposition (host/dispatch/device) lives in ``last_timing`` and is
+    # decomposition (host/dispatch/sync) lives in ``last_timing`` and is
     # folded into the server's launch timeline (serving/telemetry.py).
     host_overhead_s: float = 0.0
-    telemetry: Optional[Any] = field(default=None, repr=False)  # Telemetry
+    # the server installs its hub; a backend driven alone keeps an inert
+    # one, so spans time its launches without annotating a trace
+    telemetry: Telemetry = field(
+        default_factory=lambda: Telemetry(level=LEVEL_OFF), repr=False)
     last_timing: Optional[Dict[str, float]] = field(default=None, repr=False)
     last_copy_bytes: int = field(default=0, repr=False)
-    last_hbm_bytes: Optional[float] = field(default=None, repr=False)
-    _params_nbytes: Optional[int] = field(default=None, repr=False)
     # Runtime arena sanitizer (analysis.sanitizer.ArenaSanitizer): per-row
     # ownership epochs + launch read/write-set brackets that turn silent
     # slot-aliasing races into a diagnostic ``ArenaRaceError``.  None =
@@ -424,40 +416,21 @@ class LMBackend:
         self.host_overhead_s = 0.0
         self.last_timing = None
         self.last_copy_bytes = 0
-        self.last_hbm_bytes = None
         if self._sanitizer is not None:
             self._sanitizer.reset()
         # the jitted step closes over model only; its compile cache survives
         # (telemetry handle survives too — the server owns its lifecycle)
 
-    def params_nbytes(self) -> int:
-        """Device bytes of the parameter set (memoized): the fixed term
-        of the decode-launch HBM-traffic estimate."""
-        if self._params_nbytes is None:
-            self._params_nbytes = int(sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree_util.tree_leaves(self.params)))
-        return self._params_nbytes
-
-    def _note_launch_traffic(self, bucket: int, batch: int, op_len: int,
-                             n_new: int, kv_true: np.ndarray) -> None:
-        """Per-launch structural traffic for the telemetry timeline:
-        copy/undo-log bytes (same model the paged benchmark gates) and,
-        for decode-only launches, the estimated HBM bytes the step
-        streams (params once per suffix token + the batch's live KV)."""
+    def _note_launch_traffic(self, bucket: int, batch: int,
+                             op_len: int) -> None:
+        """Per-launch structural copy/undo-log bytes for the telemetry
+        timeline (same model the paged benchmark gates)."""
         if self.uses_paged_kv():
             self.last_copy_bytes = self.paged_copy_bytes_per_launch(
                 bucket, batch, op_len)
         else:
             self.last_copy_bytes = self.gather_bytes_per_launch(bucket,
                                                                 batch)
-        if n_new == 0:
-            s_alloc = self._s_alloc_for(bucket)
-            kv_bytes = (float(kv_true[:batch].sum())
-                        * self.slot_nbytes(bucket) / s_alloc)
-            self.last_hbm_bytes = op_len * (self.params_nbytes() + kv_bytes)
-        else:
-            self.last_hbm_bytes = None
 
     # ------------------------------------------------------------ slot admin
     def cached_len(self, doc_id: int) -> int:
@@ -942,7 +915,7 @@ class LMBackend:
 
     def _dispatch_group_prefix(self, ids, doc_tokens, bucket, f_len,
                                fraction, eff_c, op_tokens, n_classes,
-                               op_key):
+                               op_key, launch: int):
         """Prefix-sharing twin of the standard ``dispatch_group`` body:
         op-first layout, block-table indirection, memoized op prefill,
         one readout decode instead of a per-launch op-suffix decode loop
@@ -966,135 +939,140 @@ class LMBackend:
         assert P <= self.op_reserve, \
             f"operation longer than op_reserve ({P})"
         p_eff = self._prefix_eff_len(P)           # layout offset of the doc
-        t0 = time.perf_counter()
-        arena = self._arena(bucket)
-        row = self._ensure_prefix_row(arena, bucket, op_key, op_tokens)
-        assert arena.prefix_len[row] == P, \
-            f"op {op_key!r} re-encoded to a different length"
-        slots = [self._slot_for(bucket, d, arena) for d in ids]
-        B = len(ids)
-        Bp = _pad_width(B)
-        n_new = f_len - eff_c                     # 0 => decode-only launch
-        s_alloc = arena.s_alloc
-        tb = self._block_size(bucket)
-        nb = s_alloc // tb
-        shared_full = p_eff // tb                 # whole blocks shared
-        rem_start = shared_full * tb
-        rem = p_eff - rem_start                   # partial-block remainder
-
-        # attach documents to the shared row; the partial block (where the
-        # op remainder and the document's first tokens share a cache
-        # block) diverges immediately, so it is copied into the private
-        # row at attach time — the copy-on-write moment
-        fresh: List[int] = []
-        for i, d in enumerate(ids):
-            slot = slots[i]
-            if eff_c > 0:
-                assert arena.slot_op.get(slot) == op_key, \
-                    f"doc {d} cached under op {arena.slot_op.get(slot)!r} " \
-                    f"launched as {op_key!r} (server must invalidate)"
-            if arena.slot_prefix.get(slot) is None:
-                arena.attach_prefix(slot, op_key)
-                fresh.append(slot)
-        self.prefix_hits += len(fresh)
         tm = self.telemetry
-        if tm is not None and tm.tracing and fresh:
-            fresh_set = set(fresh)
-            fresh_docs = [d for i, d in enumerate(ids)
-                          if slots[i] in fresh_set]
-            ts = time.perf_counter()
-            for d in fresh_docs:
-                tm.event(d, EV_PREFIX_HIT, ts, {"backend": self.name})
-        san = arena.sanitizer
-        if fresh and rem > 0:
-            n = len(fresh)
-            src = jnp.full((n,), row, jnp.int32)
-            dst = jnp.asarray(fresh, jnp.int32)
-            start = jnp.full((n,), rem_start, jnp.int32)
-            cow_ticket = None
-            if san is not None:
-                with san.cow(bucket):
-                    cow_ticket = san.begin_launch(
-                        bucket, (self.name, "cow_copy", op_key, bucket),
-                        reads={row}, writes=set(fresh),
-                        scratch=arena.scratch_slot)
-            try:
-                win = self.model.take_kv_window(arena.states, src, start,
-                                                rem)
-                arena.states = self.model.put_kv_window(arena.states, dst,
-                                                        start, rem, win)
-            finally:
-                if san is not None:
-                    san.end_launch(cow_ticket)
-            self.cow_copies += n
-            if tm is not None and tm.tracing:
+        with tm.span("serve.assemble") as asm:
+            arena = self._arena(bucket)
+            row = self._ensure_prefix_row(arena, bucket, op_key, op_tokens)
+            assert arena.prefix_len[row] == P, \
+                f"op {op_key!r} re-encoded to a different length"
+            slots = [self._slot_for(bucket, d, arena) for d in ids]
+            B = len(ids)
+            Bp = _pad_width(B)
+            n_new = f_len - eff_c                 # 0 => decode-only launch
+            s_alloc = arena.s_alloc
+            tb = self._block_size(bucket)
+            nb = s_alloc // tb
+            shared_full = p_eff // tb             # whole blocks shared
+            rem_start = shared_full * tb
+            rem = p_eff - rem_start               # partial-block remainder
+
+            # attach documents to the shared row; the partial block (where
+            # the op remainder and the document's first tokens share a
+            # cache block) diverges immediately, so it is copied into the
+            # private row at attach time — the copy-on-write moment
+            fresh: List[int] = []
+            for i, d in enumerate(ids):
+                slot = slots[i]
+                if eff_c > 0:
+                    assert arena.slot_op.get(slot) == op_key, \
+                        f"doc {d} cached under op " \
+                        f"{arena.slot_op.get(slot)!r} launched as " \
+                        f"{op_key!r} (server must invalidate)"
+                if arena.slot_prefix.get(slot) is None:
+                    arena.attach_prefix(slot, op_key)
+                    fresh.append(slot)
+            self.prefix_hits += len(fresh)
+            if tm.tracing and fresh:
+                fresh_set = set(fresh)
+                fresh_docs = [d for i, d in enumerate(ids)
+                              if slots[i] in fresh_set]
                 ts = time.perf_counter()
                 for d in fresh_docs:
-                    tm.event(d, EV_COW_COPY, ts, {"backend": self.name})
+                    tm.event(d, EV_PREFIX_HIT, ts, {"backend": self.name})
+            san = arena.sanitizer
+            if fresh and rem > 0:
+                n = len(fresh)
+                src = jnp.full((n,), row, jnp.int32)
+                dst = jnp.asarray(fresh, jnp.int32)
+                start = jnp.full((n,), rem_start, jnp.int32)
+                cow_ticket = None
+                if san is not None:
+                    with san.cow(bucket):
+                        cow_ticket = san.begin_launch(
+                            bucket, (self.name, "cow_copy", op_key, bucket),
+                            reads={row}, writes=set(fresh),
+                            scratch=arena.scratch_slot)
+                try:
+                    win = self.model.take_kv_window(arena.states, src,
+                                                    start, rem)
+                    arena.states = self.model.put_kv_window(
+                        arena.states, dst, start, rem, win)
+                finally:
+                    if san is not None:
+                        san.end_launch(cow_ticket)
+                self.cow_copies += n
+                if tm.tracing:
+                    ts = time.perf_counter()
+                    for d in fresh_docs:
+                        tm.event(d, EV_COW_COPY, ts,
+                                 {"backend": self.name})
 
-        slots_arr = np.full(Bp, arena.scratch_slot, np.int32)
-        slots_arr[:B] = slots
-        # full-width table [Bp, s_alloc // tb]: column j is the arena row
-        # holding positions [j*tb, (j+1)*tb) — leading shared columns hit
-        # the pinned prefix row, the rest the document's private row
-        bt = np.repeat(slots_arr[:, None], nb, axis=1)
-        if shared_full > 0:
-            bt[:B, :shared_full] = row
-        new_tok = np.full((Bp, n_new), PAD, np.int32)
-        last_tok = np.full(Bp, PAD, np.int32)
-        kv_true = np.ones(Bp, np.int32)
-        ext_true = np.ones(Bp, np.int32)
-        new_d = np.zeros(B, np.int64)
-        cached_d = np.zeros(B, np.int64)
-        for i, d in enumerate(ids):
-            toks = doc_tokens[d]
-            slot = slots[i]
-            if n_new > 0:
-                seg = toks[min(eff_c, len(toks)): min(f_len, len(toks))]
-                new_tok[i, : len(seg)] = seg
-                new_d[i] = len(seg)
-                cached_d[i] = min(eff_c, len(toks))
-                ext_true[i] = min(eff_c, len(toks)) + len(seg)
-            else:
-                cached_d[i] = min(int(arena.true_len[slot]),
-                                  self._true_len(toks, fraction))
-            kt = self._true_len(toks, fraction)
-            kv_true[i] = kt
-            last_tok[i] = toks[kt - 1]
-        t1 = time.perf_counter()
-        self.host_overhead_s += t1 - t0
+            slots_arr = np.full(Bp, arena.scratch_slot, np.int32)
+            slots_arr[:B] = slots
+            # full-width table [Bp, s_alloc // tb]: column j is the arena
+            # row holding positions [j*tb, (j+1)*tb) — leading shared
+            # columns hit the pinned prefix row, the rest the document's
+            # private row
+            bt = np.repeat(slots_arr[:, None], nb, axis=1)
+            if shared_full > 0:
+                bt[:B, :shared_full] = row
+            new_tok = np.full((Bp, n_new), PAD, np.int32)
+            last_tok = np.full(Bp, PAD, np.int32)
+            kv_true = np.ones(Bp, np.int32)
+            ext_true = np.ones(Bp, np.int32)
+            new_d = np.zeros(B, np.int64)
+            cached_d = np.zeros(B, np.int64)
+            for i, d in enumerate(ids):
+                toks = doc_tokens[d]
+                slot = slots[i]
+                if n_new > 0:
+                    seg = toks[min(eff_c, len(toks)):
+                               min(f_len, len(toks))]
+                    new_tok[i, : len(seg)] = seg
+                    new_d[i] = len(seg)
+                    cached_d[i] = min(eff_c, len(toks))
+                    ext_true[i] = min(eff_c, len(toks)) + len(seg)
+                else:
+                    cached_d[i] = min(int(arena.true_len[slot]),
+                                      self._true_len(toks, fraction))
+                kt = self._true_len(toks, fraction)
+                kv_true[i] = kt
+                last_tok[i] = toks[kt - 1]
+        self.host_overhead_s += asm.seconds
 
         if self._prefix_step is None:
             self._prefix_step = self._build_prefix_step()
-        t2 = time.perf_counter()
         ticket = None
-        if san is not None:
-            # block-table columns resolve to slots + the pinned prefix row:
-            # writes land in the private rows, the row is the shared read
-            ticket = san.begin_launch(
-                bucket, (self.name, "prefix_step", op_key, bucket, eff_c,
-                         f_len, B),
-                reads=set(slots) | {row}, writes=set(slots),
-                scratch=arena.scratch_slot)
-        try:
-            logits, new_states = self._prefix_step(
-                self.params, arena.states, jnp.asarray(slots_arr),
-                jnp.asarray(bt), jnp.asarray(new_tok),
-                jnp.asarray(last_tok),
-                jnp.asarray(kv_true), jnp.asarray(ext_true),
-                c_len=eff_c, p_len=p_eff)
-        except BaseException:
+        with tm.span("serve.dispatch", launch=launch, model=self.name,
+                     bucket=bucket, width=Bp, new=n_new) as dsp:
             if san is not None:
-                san.end_launch(ticket)
-            raise
-        # RSA003-verified rebind: with donation on, the step consumed the
-        # old arena buffers; the arena now holds the result FUTURE, so a
-        # later launch on this arena chains through it (device-ordered)
-        arena.states = new_states
-        t3 = time.perf_counter()
-        self.host_overhead_s += t3 - t2    # async dispatch
+                # block-table columns resolve to slots + the pinned prefix
+                # row: writes land in the private rows, the row is the
+                # shared read
+                ticket = san.begin_launch(
+                    bucket, (self.name, "prefix_step", op_key, bucket,
+                             eff_c, f_len, B),
+                    reads=set(slots) | {row}, writes=set(slots),
+                    scratch=arena.scratch_slot)
+            try:
+                logits, new_states = self._prefix_step(
+                    self.params, arena.states, jnp.asarray(slots_arr),
+                    jnp.asarray(bt), jnp.asarray(new_tok),
+                    jnp.asarray(last_tok),
+                    jnp.asarray(kv_true), jnp.asarray(ext_true),
+                    c_len=eff_c, p_len=p_eff)
+            except BaseException:
+                if san is not None:
+                    san.end_launch(ticket)
+                raise
+            # RSA003-verified rebind: with donation on, the step consumed
+            # the old arena buffers; the arena now holds the result
+            # FUTURE, so a later launch on this arena chains through it
+            # (device-ordered)
+            arena.states = new_states
+        self.host_overhead_s += dsp.seconds    # async dispatch
         # undo log here is the width-1 readout window, not the op suffix
-        self._note_launch_traffic(bucket, B, 1, n_new, kv_true)
+        self._note_launch_traffic(bucket, B, 1)
         if n_new > 0:
             for i, d in enumerate(ids):
                 slot = slots[i]
@@ -1104,10 +1082,9 @@ class LMBackend:
             ids=list(ids), bucket=bucket, n_classes=n_classes,
             logits=logits, states=new_states, new_d=new_d,
             cached_d=cached_d, op_len=P, san=san, san_ticket=ticket,
-            timing={"host": t1 - t0, "dispatch": t3 - t2},
-            ts_enqueue=t2, ts_dispatched=t3,
-            copy_bytes=self.last_copy_bytes,
-            hbm_bytes=self.last_hbm_bytes)
+            timing={"host": asm.seconds, "dispatch": dsp.seconds},
+            ts_enqueue=dsp.start, ts_dispatched=dsp.end,
+            copy_bytes=self.last_copy_bytes, launch=launch)
 
     # ----------------------------------------------------- paged accounting
     def gather_bytes_per_launch(self, bucket: int, batch: int) -> int:
@@ -1200,7 +1177,8 @@ class LMBackend:
 
     def dispatch_group(self, ids, doc_tokens, bucket, f_len, fraction,
                        eff_c, op_tokens, n_classes,
-                       op_id: Optional[str] = None) -> GroupTicket:
+                       op_id: Optional[str] = None,
+                       launch: int = -1) -> GroupTicket:
         """Non-blocking half of ``run_group``: pick slots, assemble the
         launch arrays, enqueue the jitted stage step (JAX async dispatch
         — control returns while the device works), and hand back a
@@ -1212,7 +1190,9 @@ class LMBackend:
 
         ``op_id`` names the operation for the prefix-sharing memo; callers
         that don't thread one get a content-derived key (same tokens ==
-        same prefix row either way).
+        same prefix row either way).  ``launch`` is the server's attempt
+        index, carried by the ticket into its ``serve.dispatch`` and
+        ``serve.sync`` spans.
         """
         if self.prefix_sharing:
             op_key = op_id if op_id is not None else \
@@ -1220,68 +1200,70 @@ class LMBackend:
             return self._dispatch_group_prefix(ids, doc_tokens, bucket,
                                                f_len, fraction, eff_c,
                                                op_tokens, n_classes,
-                                               op_key)
+                                               op_key, launch)
         assert len(op_tokens) > 0, "operations must encode to >= 1 token"
         assert len(op_tokens) <= self.op_reserve, \
             f"operation longer than op_reserve ({len(op_tokens)})"
-        t0 = time.perf_counter()
-        arena = self._arena(bucket)
-        slots = [self._slot_for(bucket, d, arena) for d in ids]
-        B = len(ids)
-        Bp = _pad_width(B)
-        n_new = f_len - eff_c                     # 0 => decode-only launch
-        op_len = len(op_tokens)
+        tm = self.telemetry
+        with tm.span("serve.assemble") as asm:
+            arena = self._arena(bucket)
+            slots = [self._slot_for(bucket, d, arena) for d in ids]
+            B = len(ids)
+            Bp = _pad_width(B)
+            n_new = f_len - eff_c                 # 0 => decode-only launch
+            op_len = len(op_tokens)
 
-        slots_arr = np.full(Bp, arena.scratch_slot, np.int32)
-        slots_arr[:B] = slots
-        new_tok = np.full((Bp, n_new), PAD, np.int32)
-        kv_true = np.ones(Bp, np.int32)
-        ext_true = np.ones(Bp, np.int32)
-        new_d = np.zeros(B, np.int64)
-        cached_d = np.zeros(B, np.int64)
-        for i, d in enumerate(ids):
-            toks = doc_tokens[d]
-            slot = slots[i]
-            if n_new > 0:
-                seg = toks[min(eff_c, len(toks)): min(f_len, len(toks))]
-                new_tok[i, : len(seg)] = seg
-                new_d[i] = len(seg)
-                cached_d[i] = min(eff_c, len(toks))
-                ext_true[i] = min(eff_c, len(toks)) + len(seg)
-            else:
-                cached_d[i] = min(int(arena.true_len[slot]),
-                                  self._true_len(toks, fraction))
-            kv_true[i] = self._true_len(toks, fraction)
-        t1 = time.perf_counter()
-        self.host_overhead_s += t1 - t0
+            slots_arr = np.full(Bp, arena.scratch_slot, np.int32)
+            slots_arr[:B] = slots
+            new_tok = np.full((Bp, n_new), PAD, np.int32)
+            kv_true = np.ones(Bp, np.int32)
+            ext_true = np.ones(Bp, np.int32)
+            new_d = np.zeros(B, np.int64)
+            cached_d = np.zeros(B, np.int64)
+            for i, d in enumerate(ids):
+                toks = doc_tokens[d]
+                slot = slots[i]
+                if n_new > 0:
+                    seg = toks[min(eff_c, len(toks)):
+                               min(f_len, len(toks))]
+                    new_tok[i, : len(seg)] = seg
+                    new_d[i] = len(seg)
+                    cached_d[i] = min(eff_c, len(toks))
+                    ext_true[i] = min(eff_c, len(toks)) + len(seg)
+                else:
+                    cached_d[i] = min(int(arena.true_len[slot]),
+                                      self._true_len(toks, fraction))
+                kv_true[i] = self._true_len(toks, fraction)
+        self.host_overhead_s += asm.seconds
 
         if self._step is None:
             self._step = self._build_step()
-        t2 = time.perf_counter()
         san = arena.sanitizer
         ticket = None
-        if san is not None:
-            ticket = san.begin_launch(
-                bucket, (self.name, "step", bucket, eff_c, f_len, B),
-                reads=set(slots), writes=set(slots),
-                scratch=arena.scratch_slot)
-        try:
-            logits, new_states = self._step(
-                self.params, arena.states, jnp.asarray(slots_arr),
-                jnp.asarray(new_tok), jnp.asarray(op_tokens, jnp.int32),
-                jnp.asarray(kv_true), jnp.asarray(ext_true),
-                c_len=eff_c, op_len=op_len)
-        except BaseException:
+        with tm.span("serve.dispatch", launch=launch, model=self.name,
+                     bucket=bucket, width=Bp, new=n_new) as dsp:
             if san is not None:
-                san.end_launch(ticket)
-            raise
-        # RSA003-verified rebind: with donation on, the step consumed the
-        # old arena buffers; the arena now holds the result FUTURE, so a
-        # later launch on this arena chains through it (device-ordered)
-        arena.states = new_states
-        t3 = time.perf_counter()
-        self.host_overhead_s += t3 - t2    # async dispatch
-        self._note_launch_traffic(bucket, B, op_len, n_new, kv_true)
+                ticket = san.begin_launch(
+                    bucket, (self.name, "step", bucket, eff_c, f_len, B),
+                    reads=set(slots), writes=set(slots),
+                    scratch=arena.scratch_slot)
+            try:
+                logits, new_states = self._step(
+                    self.params, arena.states, jnp.asarray(slots_arr),
+                    jnp.asarray(new_tok), jnp.asarray(op_tokens, jnp.int32),
+                    jnp.asarray(kv_true), jnp.asarray(ext_true),
+                    c_len=eff_c, op_len=op_len)
+            except BaseException:
+                if san is not None:
+                    san.end_launch(ticket)
+                raise
+            # RSA003-verified rebind: with donation on, the step consumed
+            # the old arena buffers; the arena now holds the result
+            # FUTURE, so a later launch on this arena chains through it
+            # (device-ordered)
+            arena.states = new_states
+        self.host_overhead_s += dsp.seconds    # async dispatch
+        self._note_launch_traffic(bucket, B, op_len)
         if n_new > 0:
             for i, d in enumerate(ids):
                 slot = slots[i]
@@ -1291,10 +1273,9 @@ class LMBackend:
             ids=list(ids), bucket=bucket, n_classes=n_classes,
             logits=logits, states=new_states, new_d=new_d,
             cached_d=cached_d, op_len=op_len, san=san, san_ticket=ticket,
-            timing={"host": t1 - t0, "dispatch": t3 - t2},
-            ts_enqueue=t2, ts_dispatched=t3,
-            copy_bytes=self.last_copy_bytes,
-            hbm_bytes=self.last_hbm_bytes)
+            timing={"host": asm.seconds, "dispatch": dsp.seconds},
+            ts_enqueue=dsp.start, ts_dispatched=dsp.end,
+            copy_bytes=self.last_copy_bytes, launch=launch)
 
     def complete_group(self, ticket: GroupTicket):
         """Blocking half of ``run_group``: wait out the ticket's device
@@ -1308,23 +1289,25 @@ class LMBackend:
         implies the whole step (arena writes included) retired.  The
         bracket closes in ``finally`` so a device-side error surfacing
         at sync still releases the ticket's rows."""
-        t0 = time.perf_counter()
-        ticket.ts_sync = t0
-        try:
-            # device segment: wait out the step here (host-side sync only
-            # — the np.asarray readout below then costs nothing extra) so
-            # the timeline can split dispatch/in-flight from device wait
-            jax.block_until_ready(ticket.logits)
-        finally:
-            if ticket.san is not None:
-                ticket.san.end_launch(ticket.san_ticket)
-        t1 = time.perf_counter()
-        ticket.ts_ready = t1
-        ticket.timing["device"] = t1 - t0
+        tm = self.telemetry
+        with tm.span("serve.sync", launch=ticket.launch) as sync:
+            ticket.ts_sync = sync.start
+            try:
+                # sync segment: wait out the step here (host-side only —
+                # the np.asarray readout below then costs nothing extra)
+                # so the timeline can split dispatch/in-flight from the
+                # wait
+                jax.block_until_ready(ticket.logits)
+            finally:
+                if ticket.san is not None:
+                    ticket.san.end_launch(ticket.san_ticket)
+        ticket.ts_ready = sync.end
+        ticket.timing["sync"] = sync.seconds
         self.last_timing = dict(ticket.timing)
         B = len(ticket.ids)
-        pred, conf = self.class_confidences(
-            np.asarray(ticket.logits)[:B], ticket.n_classes)
+        with tm.span("serve.readout"):
+            pred, conf = self.class_confidences(
+                np.asarray(ticket.logits)[:B], ticket.n_classes)
         return pred, conf, ticket.new_d + ticket.op_len, ticket.cached_d
 
     @staticmethod
@@ -1514,6 +1497,7 @@ class _Flight:
     attempt: int
     t_begin: float
     t_sched: float
+    queue_wait_s: Tuple[float, ...] = ()
 
 
 @dataclass
@@ -1877,46 +1861,58 @@ class CascadeServer:
         failure model.
 
         Telemetry: each launch's wall time decomposes into
-        scheduler-pick / host / dispatch / device segments (host is the
+        scheduler-pick / host / dispatch / sync segments (host is the
         residual, so the four sum to the record's wall clock exactly);
         overlapped launches additionally stamp their in-flight window
-        (``inflight_s``) — see ``serving/telemetry.py``.
+        (``inflight_s``).  Each segment but host is a ``serve.*`` span on
+        the profiler's clock — see ``serving/telemetry.py``.
         """
-        t_begin = now = time.perf_counter()
+        tm = self.telemetry
         terminal: List[Tuple[int, int]] = []
-        for req in self._queue.pop_expired(now):    # deadline beats backoff
-            self._finish(req, TIMED_OUT, now, error="deadline exceeded")
-            terminal.append((req.query_id, req.ext_id))
-        self._reroute_sick()
         k = max(int(self.inflight), 1)
         dispatched = False
         while len(self._flights) < k:
-            # the first pick reuses the step-entry stamp (inflight=1 parity:
-            # sched_s measures queue grouping, not work done meanwhile)
-            t_pick = time.perf_counter() if dispatched else t_begin
-            launch = self._queue.next_launch(
-                self._stage_of, self.batch_size, policy=self.policy,
-                now=t_pick,
-                blocked=self._inflight_blocked if self._flights else None)
-            t_sched = time.perf_counter()
+            with tm.span("serve.sched") as sched:
+                if not dispatched:
+                    # the first pick of a step also sweeps deadlines and
+                    # reroutes around open breakers, inside its sched
+                    # segment (inflight=1 parity)
+                    now = sched.start
+                    for req in self._queue.pop_expired(now):  # beats backoff
+                        self._finish(req, TIMED_OUT, now,
+                                     error="deadline exceeded")
+                        terminal.append((req.query_id, req.ext_id))
+                    self._reroute_sick()
+                launch = self._queue.next_launch(
+                    self._stage_of, self.batch_size, policy=self.policy,
+                    now=sched.start,
+                    blocked=self._inflight_blocked if self._flights
+                    else None)
             if launch is None:
                 break
             be = self.backends[launch.model]
-            if self._flights and self._room_needed(be, launch):
-                # eviction releases rows open tickets may still read or
-                # write: drain every in-flight launch before making room
-                self._complete_flights(terminal)
-            launch = self._make_room(be, launch)
+            with tm.span("serve.make_room"):
+                if self._flights and self._room_needed(be, launch):
+                    # eviction releases rows open tickets may still read
+                    # or write: drain every in-flight launch before making
+                    # room
+                    self._complete_flights(terminal)
+                launch = self._make_room(be, launch)
             self._attempts += 1
             fl = _Flight(launch=launch, be=be, group=None,
-                         attempt=self._attempts - 1, t_begin=t_pick,
-                         t_sched=t_sched)
+                         attempt=self._attempts - 1, t_begin=sched.start,
+                         t_sched=sched.end)
+            ready = []
+            for rid in launch.doc_ids:      # leaves the queue for a launch
+                req = self._requests[rid]
+                ready.append(req.ready_ts)
+                req.ready_ts = None
             try:
                 fl.group = be.dispatch_group(
                     list(launch.doc_ids), self._tok[launch.model],
                     launch.bucket, launch.f_len, launch.fraction,
                     launch.cached_len, self._op_tokens(be, launch.op_id),
-                    self.n_classes, op_id=launch.op_id)
+                    self.n_classes, op_id=launch.op_id, launch=fl.attempt)
             except Exception as exc:    # noqa: BLE001 — isolate the launch
                 # fresh stamp: retry/terminal events must postdate any
                 # fault events the injector recorded DURING the failed
@@ -1926,6 +1922,10 @@ class CascadeServer:
                 self._record_flight(fl, ok=False, error=str(exc))
                 self._note_progress(True)
                 return terminal
+            t_enqueue = fl.group.ts_enqueue    # 0.0: nothing was enqueued
+            if t_enqueue > 0.0:
+                fl.queue_wait_s = tuple(max(t_enqueue - r, 0.0)
+                                        for r in ready)
             self._flights.append(fl)
             dispatched = True
             self._max_inflight_seen = max(self._max_inflight_seen,
@@ -2000,64 +2000,68 @@ class CascadeServer:
                                     terminal)
             self._record_flight(fl, ok=False, error=str(exc))
             return
-        health = self._health.get(launch.model)
-        if health is not None:
-            health.record_success()
-        now = time.perf_counter()
-        if tm.tracing:
-            sig = (launch.model, launch.op_id, launch.bucket,
-                   launch.cached_len, launch.f_len)
+        with tm.span("serve.route"):
+            health = self._health.get(launch.model)
+            if health is not None:
+                health.record_success()
+            now = time.perf_counter()
+            if tm.tracing:
+                sig = (launch.model, launch.op_id, launch.bucket,
+                       launch.cached_len, launch.f_len)
+                for i, rid in enumerate(ids):
+                    tm.event(rid, EV_LAUNCH, now,
+                             {"sig": sig, "batch": len(ids),
+                              "stage": self._requests[rid].stage,
+                              "launch": self._launches})
+            touched: Dict[int, None] = {}       # queries in this launch
             for i, rid in enumerate(ids):
-                tm.event(rid, EV_LAUNCH, now,
-                         {"sig": sig, "batch": len(ids),
-                          "stage": self._requests[rid].stage,
-                          "launch": self._launches})
-        touched: Dict[int, None] = {}           # queries in this launch
-        for i, rid in enumerate(ids):
-            req = self._requests[rid]
-            qid = req.query_id
-            touched[qid] = None
-            stats = self._query_stats[qid]
-            thr = self._handles[qid].stages[req.stage][3]
-            cost_d = (new_d[i] * be.rate_per_token
-                      + cached_d[i] * be.rate_per_token * be.cached_discount)
-            stats.record(req.stage, 1, int(new_d[i]), int(cached_d[i]),
-                         cost_d)
-            self._query_cost[qid] += cost_d
-            req.cost += cost_d
-            self._ledger.append((self._launches, qid, rid, float(cost_d)))
-            req.cached[be.name] = be.cached_len(rid)
-            if not np.isfinite(c[i]):
-                self._quarantine(req, stats, now, terminal)
-                continue
-            if thr is None or c[i] >= thr[p[i]]:
-                self._finish(req, RESOLVED, now, pred=int(p[i]),
-                             conf=float(c[i]), exit_stage=req.stage)
-                terminal.append((qid, req.ext_id))
-            else:
-                req.stage += 1
-                req.solo = False        # rejoin cohort launches
-                if tm.tracing:
-                    tm.event(rid, EV_ESCALATE, now,
-                             {"to": req.stage, "reason": "threshold"})
-                self._sync_cached_for_stage(req)
-                self._queue.push(req)
-        self._launches += 1
-        if tm.enabled:
-            tm.count("serve_tokens_total", int(new_d.sum()),
-                     backend=launch.model, kind="new")
-            tm.count("serve_tokens_total", int(cached_d.sum()),
-                     backend=launch.model, kind="cached")
-        self._sync_shared_counters()
-        for qid in touched:       # a query's ``batches`` = launches it rode
-            self._query_stats[qid].batches += 1
-        # retirement ticks on EVERY backend: one that stops receiving
-        # launches must still free arenas its drifted length mix pinned
-        # (safe under open tickets: their live docs keep buckets unretired)
-        retired = sum(b.note_launch() for b in self.backends.values()
-                      if hasattr(b, "note_launch"))
-        if retired:
-            self._note_retired(retired)
+                req = self._requests[rid]
+                qid = req.query_id
+                touched[qid] = None
+                stats = self._query_stats[qid]
+                thr = self._handles[qid].stages[req.stage][3]
+                cost_d = (new_d[i] * be.rate_per_token
+                          + cached_d[i] * be.rate_per_token
+                          * be.cached_discount)
+                stats.record(req.stage, 1, int(new_d[i]), int(cached_d[i]),
+                             cost_d)
+                self._query_cost[qid] += cost_d
+                req.cost += cost_d
+                self._ledger.append((self._launches, qid, rid,
+                                     float(cost_d)))
+                req.cached[be.name] = be.cached_len(rid)
+                if not np.isfinite(c[i]):
+                    self._quarantine(req, stats, now, terminal)
+                    continue
+                if thr is None or c[i] >= thr[p[i]]:
+                    self._finish(req, RESOLVED, now, pred=int(p[i]),
+                                 conf=float(c[i]), exit_stage=req.stage)
+                    terminal.append((qid, req.ext_id))
+                else:
+                    req.stage += 1
+                    req.solo = False        # rejoin cohort launches
+                    if tm.tracing:
+                        tm.event(rid, EV_ESCALATE, now,
+                                 {"to": req.stage, "reason": "threshold"})
+                    self._sync_cached_for_stage(req)
+                    self._queue.push(req)
+            self._launches += 1
+            if tm.enabled:
+                tm.count("serve_tokens_total", int(new_d.sum()),
+                         backend=launch.model, kind="new")
+                tm.count("serve_tokens_total", int(cached_d.sum()),
+                         backend=launch.model, kind="cached")
+            self._sync_shared_counters()
+            for qid in touched:   # a query's ``batches`` = launches it rode
+                self._query_stats[qid].batches += 1
+            # retirement ticks on EVERY backend: one that stops receiving
+            # launches must still free arenas its drifted length mix
+            # pinned (safe under open tickets: their live docs keep
+            # buckets unretired)
+            retired = sum(b.note_launch() for b in self.backends.values()
+                          if hasattr(b, "note_launch"))
+            if retired:
+                self._note_retired(retired)
         self._record_flight(fl, ok=True)
         if self.faults is not None:     # planned arena-loss events, if any
             losses = self.faults.poll_arena_loss(self._launches,
@@ -2086,29 +2090,25 @@ class CascadeServer:
         g = fl.group
         timing = (g.timing if g is not None else None) or {}
         dispatch = timing.get("dispatch", 0.0)
-        device = timing.get("device", 0.0)
+        sync = timing.get("sync", 0.0)
         launch = fl.launch
         batch = len(launch.doc_ids)
         wall = t_end - fl.t_begin
         sched = fl.t_sched - fl.t_begin
-        host = max(wall - sched - dispatch - device, 0.0)
+        host = max(wall - sched - dispatch - sync, 0.0)
         rec = LaunchRecord(
             index=fl.attempt, ts_start=fl.t_begin, model=launch.model,
             op_id=launch.op_id, bucket=launch.bucket,
             cached_len=launch.cached_len, f_len=launch.f_len, batch=batch,
             width=_pad_width(batch), sched_s=sched, host_s=host,
-            dispatch_s=dispatch, device_s=device, wall_s=wall,
+            dispatch_s=dispatch, sync_s=sync, wall_s=wall,
             copy_bytes=g.copy_bytes if (ok and g is not None) else 0,
             ok=ok, error=error,
             ts_enqueue=g.ts_enqueue if g is not None else 0.0,
             ts_ready=g.ts_ready if g is not None else 0.0,
             inflight_s=(max(g.ts_sync - g.ts_dispatched, 0.0)
-                        if g is not None and g.ts_sync > 0.0 else 0.0))
-        if ok and rec.decode_only:
-            hbm = g.hbm_bytes if g is not None else None
-            if hbm and device > 0.0:
-                rec.hbm_bytes = hbm
-                rec.bw_util = _bw_util(hbm, device)
+                        if g is not None and g.ts_sync > 0.0 else 0.0),
+            queue_wait_s=fl.queue_wait_s)
         tm.record_launch(rec)
         tm.set_gauge("serve_queue_depth", len(self._queue))
 
@@ -2373,12 +2373,12 @@ class CascadeServer:
         wakeups; now it costs at most ``ceil(0.5 / cap)``.  The measured
         sleep time accumulates into the launch timeline
         (``telemetry.idle_wait_s``) so drain-side idle waits are visible
-        next to sched/host/dispatch/device in ``telemetry_snapshot()``."""
+        next to sched/host/dispatch/sync in ``telemetry_snapshot()``."""
         wait = self._queue.next_eligible_in()
         if wait is not None and wait > 0 and math.isfinite(wait):
-            t0 = time.perf_counter()
-            time.sleep(min(wait, self.idle_wait_cap))
-            self.telemetry.add_idle_wait(time.perf_counter() - t0)
+            with self.telemetry.span("serve.idle_wait") as idle:
+                time.sleep(min(wait, self.idle_wait_cap))
+            self.telemetry.add_idle_wait(idle.seconds)
 
     def ledger(self) -> List[Tuple[int, int, int, float]]:
         """Per-document billing ledger: ``(launch, query_id, request_id,

@@ -174,8 +174,7 @@ class _InjectedTicket:
 
     _POISONED_DEFAULTS = {"timing": None, "ts_enqueue": 0.0,
                           "ts_dispatched": 0.0, "ts_sync": 0.0,
-                          "ts_ready": 0.0, "copy_bytes": 0,
-                          "hbm_bytes": None}
+                          "ts_ready": 0.0, "copy_bytes": 0}
 
     def __init__(self, inner: Any, fail_exc: Optional[Exception],
                  corrupt: bool, spike_s: float, ids: List[int]):

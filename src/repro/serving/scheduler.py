@@ -202,6 +202,9 @@ class DocRequest:
     quarantines: int = 0              # non-finite confidence events
     not_before: float = 0.0           # backoff gate (perf_counter instant)
     deadline: Optional[float] = None  # absolute timeout (perf_counter)
+    # when the request last became ready to launch (None while it rides
+    # a launch): the start of its next queue wait
+    ready_ts: Optional[float] = None
     solo: bool = False                # launch alone (failure isolation)
     error: Optional[str] = None       # last failure diagnostic
 
@@ -288,7 +291,18 @@ class RequestQueue:
         return doc_id in self._ready
 
     def push(self, req: DocRequest) -> None:
-        """Admit a request (also how deferred/surviving requests return)."""
+        """Admit a request (also how deferred/surviving requests return).
+
+        Stamps ``ready_ts``, the instant the request became ready to
+        launch: the push itself (admission, or the return from a launch
+        on escalation or quarantine), or ``not_before`` when that is later
+        (a retry's backoff is not waiting).  The queue wait starts here,
+        not at ``arrival_ts``: a client's lag in submitting is not time
+        in this queue.  A request put back before its launch dispatched
+        (a launch trimmed to fit memory) keeps its stamp; the server
+        clears it at dispatch."""
+        if req.ready_ts is None:
+            req.ready_ts = max(time.perf_counter(), req.not_before)
         self._ready[req.doc_id] = req
 
     def clear(self) -> None:

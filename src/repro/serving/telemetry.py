@@ -1,8 +1,8 @@
 """Bounded-memory tracing + metrics for the cascade serving plane.
 
-Zero external dependencies (numpy only), zero device work: every probe is
-a host-side ``time.perf_counter()`` read or a dict update around the
-jitted stage steps, so the fault-free data plane stays bitwise identical
+No device work: every probe is a host-side ``time.perf_counter()`` read,
+a profiler annotation or a dict update around the jitted stage steps, so
+the fault-free data plane stays bitwise identical
 whether telemetry is off, at ``"counters"`` (the default), or at
 ``"trace"``.  All storage is fixed-capacity — ring buffers for events and
 launch records, a capped label-set registry for metrics — so memory stays
@@ -55,17 +55,47 @@ Launch timeline (``level="counters"`` and up)
 disjoint segments that sum to the record's wall clock:
 
 ``sched_s``     scheduler pick: deadline sweep, breaker rerouting,
-                ``RequestQueue.next_launch``
-``host_s``      host bookkeeping: eviction, batch assembly, billing,
-                threshold routing, queue pushes (the residual of the
-                other three — everything that is not dispatch/device)
-``dispatch_s``  the jitted stage-step call returning (async dispatch)
-``device_s``    the completion-side ``jax.block_until_ready`` wait
+                ``RequestQueue.next_launch`` (span ``serve.sched``)
+``host_s``      host bookkeeping: eviction, batch assembly, readout,
+                billing, threshold routing, queue pushes (the residual of
+                the other three — everything that is not sched/dispatch/
+                sync)
+``dispatch_s``  the jitted stage-step call returning (async dispatch;
+                span ``serve.dispatch``)
+``sync_s``      the completion-side ``jax.block_until_ready`` wait (span
+                ``serve.sync``): the host blocked until the device
+                finished, not the device's own time
+
+Spans on the profiler's clock
+-----------------------------
+``Telemetry.span(name, **args)`` is the one interval primitive: it stamps
+``perf_counter`` at entry and exit for the timeline and, at every level
+but ``off``, wraps the interval in a ``jax.profiler.TraceAnnotation`` of
+the same name, so a profiler trace holds the serving plane's own host
+timeline next to the device's programs (its keyword arguments arrive as
+the event's stats).  The spans, outermost first:
+
+=====================  ==================================================
+``serve.sched``        deadline sweep, breaker rerouting, ``next_launch``
+``serve.make_room``    ``_make_room`` and any drain of open tickets it
+                       forces (nested ``serve.sync``/``readout``/``route``)
+``serve.assemble``     host launch arrays in ``dispatch_group``
+``serve.dispatch``     the jitted step call (``launch``, ``model``,
+                       ``bucket``, ``width``, ``new`` tokens per document)
+``serve.sync``         ``block_until_ready`` at completion (``launch``)
+``serve.readout``      logits to host and class confidences
+``serve.route``        billing, thresholds and queue pushes
+``serve.idle_wait``    sleeping out a retry backoff
+=====================  ==================================================
+
+Spans never enter jitted code.  With no profiler running an annotation
+costs a check of whether one is; the data plane is identical at every
+level.
 
 SEGMENT SEMANTICS UNDER OVERLAPPED DISPATCH (``CascadeServer.inflight``
 > 1): timing is PER-TICKET and never forces synchronization — the
 dispatch segment stamps around the non-blocking ``dispatch_group``
-enqueue, the device segment stamps around ``complete_group``'s sync,
+enqueue, the sync segment stamps around ``complete_group``'s wait,
 and the window in between (dispatch returned, sync not yet entered:
 the launch computing on-device while the host schedules/dispatches
 OTHER launches) is recorded separately as the record's ``inflight_s``.
@@ -74,22 +104,22 @@ wall spans dispatch of younger launches at K>1, so walls of
 consecutive records overlap and ``host_s`` — still the residual —
 absorbs the in-flight window (the four segments still sum to ``wall_s``
 exactly).  The hidden window is the overlap win:
-``timeline["overlap_hidden_frac"] = inflight / (inflight + device)``
+``timeline["overlap_hidden_frac"] = inflight / (inflight + sync)``
 (≈0 at ``inflight=1``, → 1 when sched+host work fully hides device
 waits), and ``timeline["mean_launch_gap_ms"]`` measures
 ``max(enqueue(next) - ready(prev), 0)`` over consecutive ok records —
 the device idle window between launches, which ahead-of-time dispatch
 drives toward zero.  At ``inflight=1`` every stamp reduces to the
-pre-overlap decomposition (``device_s`` measured immediately after
+pre-overlap decomposition (``sync_s`` measured immediately after
 dispatch; ``inflight_s`` ~ 0).
 
 The old ``LMBackend.host_overhead_s`` scalar survives as a derived view:
 it accumulates ``host assembly + dispatch`` exactly as before, and
 ``snapshot()["timeline"]["host_overhead_s"]`` derives the same quantity
 from the segment totals.  Each ``LaunchRecord`` also carries batch
-occupancy, structural copy/undo-log bytes, and — for decode-only
-launches — a ``launch/roofline.py``-derived HBM bandwidth-utilization
-estimate.
+occupancy, structural copy/undo-log bytes and each document's queue
+wait (ready to ``serve.dispatch`` entry; histogram
+``serve_queue_wait_seconds``).
 
 Exporters
 ---------
@@ -107,8 +137,11 @@ from __future__ import annotations
 
 import json
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+import jax
 
 LEVEL_OFF = "off"
 LEVEL_COUNTERS = "counters"
@@ -349,12 +382,42 @@ class MetricRegistry:
         return "\n".join(lines) + "\n"
 
 
+# ------------------------------------------------------------------ spans
+class Span:
+    """One host interval: ``perf_counter`` at entry (``start``) and exit
+    (``end``), inside a profiler annotation of the same name when one is
+    given.  The annotation opens before the first stamp and closes after
+    the second, so the profiler's span holds the timeline's interval."""
+
+    __slots__ = ("start", "end", "_ann")
+
+    def __init__(self, annotation: Any = None):
+        self._ann = annotation
+        self.start = self.end = 0.0
+
+    def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
 # -------------------------------------------------------- launch timeline
 @dataclass
 class LaunchRecord:
-    """One dispatched launch: signature, occupancy, copy traffic, and the
-    scheduler/host/dispatch/device wall-time decomposition (the four
-    segments are disjoint and sum to ``wall_s`` by construction)."""
+    """One dispatched launch: signature, occupancy, copy traffic, its
+    documents' queue waits, and the scheduler/host/dispatch/sync
+    wall-time decomposition (the four segments are disjoint and sum to
+    ``wall_s`` by construction)."""
 
     index: int                     # server launch index (attempt order)
     ts_start: float                # perf_counter at step entry
@@ -368,11 +431,9 @@ class LaunchRecord:
     sched_s: float = 0.0
     host_s: float = 0.0
     dispatch_s: float = 0.0
-    device_s: float = 0.0
+    sync_s: float = 0.0
     wall_s: float = 0.0
     copy_bytes: int = 0            # gather copy / paged undo-log bytes
-    hbm_bytes: Optional[float] = None   # est. device bytes moved (decode)
-    bw_util: Optional[float] = None     # fraction of the HBM roof achieved
     ok: bool = True
     error: Optional[str] = None
     # per-ticket overlap stamps (0.0 when the launch never dispatched)
@@ -381,6 +442,7 @@ class LaunchRecord:
     inflight_s: float = 0.0        # dispatched->sync window hidden behind
     #                                other launches' sched/host work; NOT
     #                                a wall-clock segment (see docstring)
+    queue_wait_s: Tuple[float, ...] = ()   # ready -> serve.dispatch entry
 
     @property
     def occupancy(self) -> float:
@@ -392,7 +454,7 @@ class LaunchRecord:
 
     def segments(self) -> Dict[str, float]:
         return {"sched": self.sched_s, "host": self.host_s,
-                "dispatch": self.dispatch_s, "device": self.device_s}
+                "dispatch": self.dispatch_s, "sync": self.sync_s}
 
 
 # --------------------------------------------------------------- telemetry
@@ -424,7 +486,7 @@ class Telemetry:
         self.sched_total_s = 0.0
         self.host_total_s = 0.0
         self.dispatch_total_s = 0.0
-        self.device_total_s = 0.0
+        self.sync_total_s = 0.0
         self.wall_total_s = 0.0
         self.inflight_total_s = 0.0
         self._prev_ready = 0.0      # last ok record's ts_ready (gap histo)
@@ -438,6 +500,16 @@ class Telemetry:
     @property
     def tracing(self) -> bool:
         return self.level == LEVEL_TRACE
+
+    def span(self, name: str, **args: Any) -> Span:
+        """A host interval for the launch timeline, annotated in the
+        profiler's trace under ``name`` (with ``args`` as its stats) at
+        every level but ``off``.  Use as ``with tm.span(...) as sp``;
+        ``sp.start``/``sp.end``/``sp.seconds`` are the timeline's
+        stamps."""
+        if self.level == LEVEL_OFF:
+            return Span()
+        return Span(jax.profiler.TraceAnnotation(name, **args))
 
     # -- span events -----------------------------------------------------
     def register_doc(self, rid: int, query_id: int, ext_id: int) -> None:
@@ -526,7 +598,7 @@ class Telemetry:
         self.sched_total_s += rec.sched_s
         self.host_total_s += rec.host_s
         self.dispatch_total_s += rec.dispatch_s
-        self.device_total_s += rec.device_s
+        self.sync_total_s += rec.sync_s
         self.wall_total_s += rec.wall_s
         self.inflight_total_s += rec.inflight_s
         if rec.ok and rec.ts_enqueue > 0.0:
@@ -542,9 +614,8 @@ class Telemetry:
         self.observe("serve_launch_wall_seconds", rec.wall_s, backend=be)
         for seg, v in rec.segments().items():
             self.observe("serve_launch_segment_seconds", v, segment=seg)
-        if rec.bw_util is not None:
-            self.observe("serve_decode_bw_utilization", rec.bw_util,
-                         backend=be)
+        for w in rec.queue_wait_s:
+            self.observe("serve_queue_wait_seconds", w, backend=be)
 
     def mean_launch_gap_s(self) -> float:
         """Mean device idle window between consecutive surviving launch
@@ -573,7 +644,7 @@ class Telemetry:
         for r in self.launches.items():
             if not r.ok:
                 continue
-            s = r.sched_s + r.host_s + r.dispatch_s + r.device_s
+            s = r.sched_s + r.host_s + r.dispatch_s + r.sync_s
             if abs(s - r.wall_s) > rel_tol * max(r.wall_s, 1e-9):
                 return False
         return True
@@ -584,8 +655,6 @@ class Telemetry:
         # local import: roofline depends only on stdlib, but serving
         # modules must stay importable without the launch package cycle
         from ..launch.roofline import overlap_hidden_fraction
-        utils = [r.bw_util for r in self.launches.items()
-                 if r.bw_util is not None]
         return {
             "level": self.level,
             "counters": {
@@ -603,18 +672,15 @@ class Telemetry:
                 "sched_s": self.sched_total_s,
                 "host_s": self.host_total_s,
                 "dispatch_s": self.dispatch_total_s,
-                "device_s": self.device_total_s,
+                "sync_s": self.sync_total_s,
                 "wall_s": self.wall_total_s,
                 # derived view of the pre-telemetry lumped scalar
                 "host_overhead_s": self.host_total_s + self.dispatch_total_s,
                 "idle_wait_s": self.idle_wait_s,
                 "inflight_s": self.inflight_total_s,
                 "overlap_hidden_frac": overlap_hidden_fraction(
-                    self.inflight_total_s, self.device_total_s),
+                    self.inflight_total_s, self.sync_total_s),
                 "mean_launch_gap_ms": 1e3 * self.mean_launch_gap_s(),
-                # None where the device's HBM roof is unknown
-                "decode_bw_util_mean": (sum(utils) / len(utils)
-                                        if utils else None),
             },
         }
 
@@ -629,7 +695,7 @@ class Telemetry:
         self.sched_total_s = 0.0
         self.host_total_s = 0.0
         self.dispatch_total_s = 0.0
-        self.device_total_s = 0.0
+        self.sync_total_s = 0.0
         self.wall_total_s = 0.0
         self.inflight_total_s = 0.0
         self._prev_ready = 0.0
@@ -675,8 +741,6 @@ def chrome_trace(tm: Telemetry) -> Dict[str, Any]:
                 "batch": r.batch, "width": r.width,
                 "occupancy": round(r.occupancy, 4),
                 "copy_bytes": r.copy_bytes, "ok": r.ok}
-        if r.bw_util is not None:
-            args["bw_util"] = round(r.bw_util, 6)
         if r.error:
             args["error"] = r.error
         events.append({"ph": "X", "pid": pid, "tid": 0,

@@ -256,7 +256,7 @@ def test_faults_surface_at_completion(tokz, docs):
 # --------------------------------------------------- per-ticket timing
 def test_per_ticket_timing_without_sync(tokz, docs):
     """``dispatch_group`` must not block: the ticket carries only the
-    host/dispatch segments until completion measures the device wait;
+    host/dispatch segments until completion measures the sync wait;
     ``last_timing`` updates at completion (per-ticket, no forced
     sync inside the step)."""
     be = _mk_backend("proxy", 1, tokz)
@@ -271,7 +271,7 @@ def test_per_ticket_timing_without_sync(tokz, docs):
     assert be.last_timing is None           # nothing synced yet
     assert ticket.ts_dispatched >= ticket.ts_enqueue > 0.0
     pred, conf, new_d, cached_d = be.complete_group(ticket)
-    assert set(ticket.timing) == {"host", "dispatch", "device"}
+    assert set(ticket.timing) == {"host", "dispatch", "sync"}
     assert be.last_timing == ticket.timing
     assert ticket.ts_ready >= ticket.ts_sync >= ticket.ts_dispatched
     assert len(pred) == len(conf) == 1

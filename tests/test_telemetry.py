@@ -291,7 +291,7 @@ def test_launch_segments_sum_to_wall(models):
     recs = [r for r in tm.launches.items() if r.ok]
     assert recs, "chaos drain recorded no launches"
     for r in recs:
-        total = r.sched_s + r.host_s + r.dispatch_s + r.device_s
+        total = r.sched_s + r.host_s + r.dispatch_s + r.sync_s
         assert total == pytest.approx(r.wall_s, rel=0.05), r
         assert r.width >= r.batch > 0
         assert 0.0 < r.occupancy <= 1.0
@@ -302,7 +302,7 @@ def test_launch_segments_sum_to_wall(models):
     assert snap["server"]["launches"] == srv._launches
     tl = snap["timeline"]
     assert tl["wall_s"] == pytest.approx(
-        tl["sched_s"] + tl["host_s"] + tl["dispatch_s"] + tl["device_s"],
+        tl["sched_s"] + tl["host_s"] + tl["dispatch_s"] + tl["sync_s"],
         rel=0.05)
     assert tl["host_overhead_s"] == tl["host_s"] + tl["dispatch_s"]
 
@@ -392,6 +392,68 @@ def test_idle_wait_cap_bounds_single_sleep(models):
     assert f.done
 
 
+# ------------------------------------------------------------ queue wait
+
+def test_queue_wait_from_ready_to_dispatch(models, docs):
+    """``LaunchRecord.queue_wait_s`` runs from the instant a document
+    became ready to its launch's ``serve.dispatch`` entry: the first hop
+    from its push at submit (a client's lag behind ``arrival_ts`` is not
+    queue wait), an escalation hop from the push after the previous
+    stage, a retry hop from the end of its backoff (the backoff is not
+    waiting)."""
+    import time
+    inf = math.inf
+    never = {0: inf, 1: inf}
+    backoff = 0.05
+    srv = mk_server(models, retry=RetryPolicy(max_retries=2,
+                                              backoff_base=backoff))
+    h = srv.register(Cascade([
+        Task(TaskConfig("proxy", "sur_1", 0.25), never),
+        Task(TaskConfig("proxy", "o_orig", 1.0), never)]))
+    oracle = srv.backends["oracle"]
+    real, failed_at = oracle.dispatch_group, []
+
+    def fail_once(*args, **kwargs):
+        if not failed_at:
+            failed_at.append(time.perf_counter())
+            raise RuntimeError("injected dispatch failure")
+        return real(*args, **kwargs)
+
+    oracle.dispatch_group = fail_once
+    d = sorted(docs)[0]
+    lag = 0.2
+    arrival = time.perf_counter() - lag
+    t_submit = time.perf_counter()
+    h.submit(d, docs[d], arrival_ts=arrival)
+    t_pushed = time.perf_counter()
+    srv.drain()
+    req = srv._requests[srv._ids[(h.query_id, d)]]
+    assert req.status == "resolved" and req.exit_stage == 2
+    first, second, failed, retried = srv.telemetry.launches.items()
+    assert [r.model for r in (first, second, failed, retried)] == \
+        ["proxy", "proxy", "oracle", "oracle"]
+    assert (first.ok, second.ok, failed.ok, retried.ok) == \
+        (True, True, False, True)
+    for r in (first, second, retried):
+        assert r.batch == 1 and len(r.queue_wait_s) == 1
+    # first hop: from the push inside submit, the client's lag left out
+    assert first.ts_enqueue - t_pushed <= first.queue_wait_s[0] <= \
+        first.ts_enqueue - t_submit
+    assert first.queue_wait_s[0] <= first.ts_enqueue - arrival - lag
+    # escalation hop: from the push that followed the previous stage
+    assert 0.0 <= second.queue_wait_s[0] <= \
+        second.ts_enqueue - first.ts_ready
+    # a dispatch that failed before enqueueing records no wait
+    assert failed.queue_wait_s == ()
+    # retry hop: from the end of the backoff, which is not waiting
+    assert req.not_before >= failed_at[0] + backoff
+    assert retried.queue_wait_s[0] == pytest.approx(
+        retried.ts_enqueue - req.not_before, abs=1e-9)
+    hist = srv.telemetry.registry.snapshot()
+    assert hist["serve_queue_wait_seconds{backend=proxy}"]["count"] == 2
+    assert hist["serve_queue_wait_seconds{backend=oracle}"]["count"] == 1
+
+
 # -------------------------------------------------------------- exporters
 
 def test_chrome_trace_layout(models, tmp_path):
@@ -417,7 +479,7 @@ def test_chrome_trace_layout(models, tmp_path):
         assert e["ts"] >= 0 and e["dur"] >= 0
     # per-launch segment slices tile the launch slice
     seg_names = {e["name"] for e in segs}
-    assert seg_names == {"sched", "host", "dispatch", "device"}
+    assert seg_names == {"sched", "host", "dispatch", "sync"}
     insts = [e for e in evs if e["ph"] == "i"]
     assert any(e["name"] == "submit" for e in insts)
     assert any(e["name"] in TERMINAL_EVENTS for e in insts)
@@ -438,17 +500,9 @@ def test_launch_record_derived_properties():
 
 
 def test_decode_launch_roofline_helpers():
-    from repro.launch.roofline import (bandwidth_utilization,
-                                       decode_launch_bytes, device_peaks)
-    b = decode_launch_bytes(params_bytes=1e9, kv_bytes_per_step=1e6, steps=2)
-    assert b == pytest.approx(2 * (1e9 + 1e6))
+    from repro.launch.roofline import device_peaks
     v5e = device_peaks("TPU v5 lite")
     assert v5e.hbm_bw == 819e9 and v5e.flops_bf16 == 197e12
     assert "TPU v5e" in v5e.source
-    assert bandwidth_utilization(v5e.hbm_bw, 1.0, v5e.hbm_bw) \
-        == pytest.approx(1.0)
-    assert bandwidth_utilization(1e9, 0.0, v5e.hbm_bw) == 0.0
-    # a device the table does not know has no roof, and no utilization
+    # a device the table does not know has no roof
     assert device_peaks("cpu") is None
-    from repro.serving.engine import _bw_util
-    assert _bw_util(1e9, 1.0) is None           # tests run on the CPU
