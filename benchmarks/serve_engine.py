@@ -441,7 +441,7 @@ def paged_parity_check():
                  remat=False)
     tokz = HashWordTokenizer(vocab_size=512)
     # 50 words: ceil(50 * 0.25) = 13 < fraction_len(64, 0.25) = 16, so the
-    # op suffix decodes over live document KV — the undo log's hard case
+    # op suffix runs over live document KV — the undo log's hard case
     docs = {0: " ".join(f"a{j}" for j in range(20)),
             1: " ".join(f"b{j}" for j in range(50))}
     thr = {0: 2.0, 1: 2.0}

@@ -8,6 +8,11 @@ Supports:
     ``q_offset`` tokens already live in the KV operand.  This is the task-
     cascade primitive: extending a document from fraction f_j to f_i > f_j
     re-uses the cached prefix KV and only computes attention for new queries.
+  * ``q_start`` [B] — a per-row first query position in place of the static
+    ``q_offset`` (ragged-start extend: the serving engine's operation chunk
+    begins at each document's own true length).  It rides in scalar-prefetch
+    SMEM, so one compiled kernel serves every start; the static offset is
+    the case ``q_start == q_offset`` for every row.
   * ``kv_len`` [B] — per-row valid KV length (bucket-padded serving batches:
     keys at positions >= kv_len[b] are PAD and masked for every query).
     Rides in scalar-prefetch SMEM like the decode kernel's length mask.
@@ -95,7 +100,7 @@ def _block_runs(q0, k0, kv_len, *, causal, window, block_q, block_kv):
 
 
 def _flash_kernel(
-    kv_len_ref,                   # SMEM [B] scalar prefetch
+    kv_len_ref, q_start_ref,      # SMEM [B] scalar prefetch
     q_ref, k_ref, v_ref,          # VMEM blocks
     o_ref,                        # output block
     acc_ref, m_ref, l_ref,        # VMEM scratch (persist across kv steps)
@@ -103,7 +108,6 @@ def _flash_kernel(
     sm_scale: float,
     causal: bool,
     window: Optional[int],
-    q_offset: int,
     block_q: int,
     block_kv: int,
     num_kv_blocks: int,
@@ -117,7 +121,7 @@ def _flash_kernel(
         _init_state(acc_ref, m_ref, l_ref)
 
     # absolute positions of this block's first query / key
-    q0 = q_offset + iq * block_q
+    q0 = q_start_ref[b] + iq * block_q
     k0 = ik * block_kv
     kv_len = kv_len_ref[b]
     blk = dict(causal=causal, window=window, block_q=block_q,
@@ -136,7 +140,8 @@ def _flash_kernel(
 
 
 def _paged_flash_kernel(
-    rows_ref, kv_len_ref,         # SMEM scalar prefetch (rows feed index maps)
+    rows_ref, kv_len_ref, q_start_ref,   # SMEM scalar prefetch (rows feed
+    #                                      the k/v index maps)
     q_ref, k_ref, v_ref,          # VMEM [1, Hq, bq, dh] / [1, bkv, Hkv, dh]
     o_ref,                        # [1, Hq, bq, dh]
     acc_ref, m_ref, l_ref,        # VMEM scratch, leading dim Hq
@@ -144,7 +149,6 @@ def _paged_flash_kernel(
     sm_scale: float,
     causal: bool,
     window: Optional[int],
-    q_offset: int,
     block_q: int,
     block_kv: int,
     num_kv_blocks: int,
@@ -159,7 +163,7 @@ def _paged_flash_kernel(
     def _init():
         _init_state(acc_ref, m_ref, l_ref)
 
-    q0 = q_offset + iq * block_q
+    q0 = q_start_ref[b] + iq * block_q
     k0 = ik * block_kv
     kv_len = kv_len_ref[b]
     blk = dict(causal=causal, window=window, block_q=block_q,
@@ -207,11 +211,13 @@ def flash_attention_pallas(
     window: Optional[int] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,   # [B] valid kv length (pad mask)
+    q_start: Optional[jnp.ndarray] = None,  # [B] first query position
     sm_scale: Optional[float] = None,
     block_q: int = 512,
     block_kv: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """``q_start`` [B], when given, replaces ``q_offset`` row by row."""
     B, Hq, Sq, Dh = q.shape
     _, Hkv, Skv, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
@@ -227,20 +233,21 @@ def flash_attention_pallas(
 
     if kv_len is None:
         kv_len = jnp.full((B,), Skv, jnp.int32)   # every key valid
+    if q_start is None:
+        q_start = jnp.full((B,), q_offset, jnp.int32)
 
     kernel = functools.partial(
         _flash_kernel,
         sm_scale=scale,
         causal=causal,
         window=window,
-        q_offset=q_offset,
         block_q=block_q,
         block_kv=block_kv,
         num_kv_blocks=nkv,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,        # (kv_len, q_start)
         grid=(B, Hq, nq, nkv),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, Dh),
@@ -264,7 +271,7 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dh), q.dtype),
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), q, k, v)
+    )(kv_len.astype(jnp.int32), q_start.astype(jnp.int32), q, k, v)
 
 
 def paged_flash_attention_pallas(
@@ -279,6 +286,7 @@ def paged_flash_attention_pallas(
     window: Optional[int] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,   # [B] valid kv length (pad mask)
+    q_start: Optional[jnp.ndarray] = None,  # [B] first query position
     sm_scale: Optional[float] = None,
     block_q: int = 512,
     block_kv: int = 512,
@@ -287,9 +295,12 @@ def paged_flash_attention_pallas(
     """Prefix-extend attention reading K/V straight from a slot arena.
 
     The queries are the suffix [q_offset, q_offset + Sq) of each
-    sequence; cached keys live in ``k_arena[slots[b], :kv_valid]``
-    (chunk included — the caller scatters the new chunk's KV into the
-    arena BEFORE attending, mirroring the dense extend path).  Only the
+    sequence, or [q_start[b], q_start[b] + Sq) of row ``b`` when
+    ``q_start`` is given (the block pruning and causal mask then follow
+    each row; ``kv_valid`` must cover every row's last query); cached
+    keys live in ``k_arena[slots[b], :kv_valid]`` (chunk included — the
+    caller scatters the new chunk's KV into the arena BEFORE attending,
+    mirroring the dense extend path).  Only the
     kv blocks covering ``kv_valid`` are visited, so the arena's op-suffix
     reserve past the bucket costs nothing.  Slot contract as in
     ``paged_decode_attention_pallas``: any row in [0, N_rows) is legal,
@@ -322,13 +333,14 @@ def paged_flash_attention_pallas(
 
     if kv_len is None:
         kv_len = jnp.full((B,), kv_valid, jnp.int32)
+    if q_start is None:
+        q_start = jnp.full((B,), q_offset, jnp.int32)
 
     kernel = functools.partial(
         _paged_flash_kernel,
         sm_scale=scale,
         causal=causal,
         window=window,
-        q_offset=q_offset,
         block_q=block_q,
         block_kv=block_kv,
         num_kv_blocks=nkv,
@@ -337,18 +349,18 @@ def paged_flash_attention_pallas(
     )
 
     if block_tables is None:
-        def kv_map(b, i, j, slots_ref, kv_len_ref):
+        def kv_map(b, i, j, slots_ref, *_):
             return (slots_ref[b], j, 0, 0)
         row_ids = slots.astype(jnp.int32)
     else:
         assert block_tables.shape == (B, nkv), (block_tables.shape, B, nkv)
 
-        def kv_map(b, i, j, bt_ref, kv_len_ref):
+        def kv_map(b, i, j, bt_ref, *_):
             return (bt_ref[b, j], j, 0, 0)
         row_ids = block_tables.astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # (rows, kv_len)
+        num_scalar_prefetch=3,        # (rows, kv_len, q_start)
         grid=(B, nq, nkv),
         in_specs=[
             pl.BlockSpec((1, Hq, block_q, Dh),
@@ -380,4 +392,5 @@ def paged_flash_attention_pallas(
         compiler_params=params,
         interpret=interpret,
         name="paged_flash_attention",
-    )(row_ids, kv_len.astype(jnp.int32), q, k_arena, v_arena)
+    )(row_ids, kv_len.astype(jnp.int32), q_start.astype(jnp.int32), q,
+      k_arena, v_arena)

@@ -111,6 +111,22 @@ def _block_pairs(
     return tuple(qi), tuple(ki)
 
 
+def _round_up(n: int, block: int) -> int:
+    """``n`` rounded up to a whole number of ``block``s."""
+    return -(-n // block) * block
+
+
+def _pad_axis(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
+    """Zero-pad ``x`` along ``axis`` up to ``size`` (no-op if it holds it):
+    ragged extents tile by padding, and the callers slice padded query
+    rows off and mask padded keys by ``kv_len``."""
+    if x.shape[axis] >= size:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - x.shape[axis])
+    return jnp.pad(x, pad)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -122,6 +138,7 @@ def xla_flash_attention(
     k: jnp.ndarray,               # [B, Skv, Hkv, Dh]
     v: jnp.ndarray,
     kv_len: Optional[jnp.ndarray] = None,   # [B] valid kv length (pad mask)
+    q_start: Optional[jnp.ndarray] = None,  # [B] per-row first query pos
     *,
     causal: bool = True,
     window: Optional[int] = None,
@@ -141,7 +158,13 @@ def xla_flash_attention(
     assert Sq % bq == 0 and Skv % bk == 0, (Sq, bq, Skv, bk)
     nq, nk = Sq // bq, Skv // bk
 
-    qi, ki = _block_pairs(nq, nk, bq, bk, causal, window, q_offset)
+    if q_start is None:
+        qi, ki = _block_pairs(nq, nk, bq, bk, causal, window, q_offset)
+        start = q_offset
+    else:
+        # traced row starts: no block pair is masked for every row
+        qi, ki = _block_pairs(nq, nk, bq, bk, False, None, 0)
+        start = q_start.astype(jnp.int32)[:, None, None]
     pair_arr = jnp.stack(
         [jnp.asarray(qi, jnp.int32), jnp.asarray(ki, jnp.int32)], axis=1
     )
@@ -162,17 +185,16 @@ def xla_flash_attention(
         vb = jnp.repeat(vb.astype(jnp.float32), g, axis=2)
         s = jnp.einsum("bqhd,bkhd->bqhk", qb, kb)                   # [B,bq,Hq,bk]
 
-        qpos = q_offset + i * bq + jnp.arange(bq)[:, None]
-        kpos = j * bk + jnp.arange(bk)[None, :]
-        mask = jnp.ones((bq, bk), bool)
+        qpos = start + i * bq + jnp.arange(bq)[None, :, None]   # [1|B,bq,1]
+        kpos = j * bk + jnp.arange(bk)[None, None, :]           # [1, 1, bk]
+        mask = jnp.ones((B, bq, bk), bool)
         if causal:
             mask &= kpos <= qpos
         if window is not None and window > 0:
             mask &= kpos > qpos - window
-        mask = jnp.broadcast_to(mask[None], (B, bq, bk))
         if kv_len is not None:
             # per-row valid kv length: keys past kv_len[b] are padding
-            mask = mask & (kpos[None] < kv_len[:, None, None])
+            mask &= kpos < kv_len[:, None, None]
         s = jnp.where(mask[:, :, None, :], s, -jnp.inf)
 
         mb = jax.lax.dynamic_slice_in_dim(m, i * bq, bq, axis=1)
@@ -211,6 +233,7 @@ def attention(
     window: Optional[int] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,   # [B] valid kv length (pad mask)
+    q_start: Optional[jnp.ndarray] = None,  # [B] per-row first query pos
     sm_scale: Optional[float] = None,
     impl: str = DEFAULT_IMPL,
     block_q: int = 512,
@@ -222,6 +245,13 @@ def attention(
     padded, so a document shorter than its bucket carries PAD keys past its
     true length — with ``kv_len`` those keys are invisible to every query
     (the prefill twin of the decode kernel's length mask).
+
+    ``q_start`` [B] places row ``b``'s queries at ``[q_start[b],
+    q_start[b] + Sq)`` in place of the static ``q_offset`` (ragged-start
+    extend); it is traced, so every start shares one program.  Ragged
+    ``Sq``/``Skv`` are padded up to whole blocks on the blocked impls, as
+    in ``attention_paged``: extra queries are dropped and extra keys are
+    masked.
     """
     if impl == "stub":
         # near-zero-cost stand-in used by the dry-run to ATTRIBUTE HLO
@@ -233,24 +263,34 @@ def attention(
     if impl == "naive":
         return ref.mha_reference(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
-            kv_len=kv_len, sm_scale=sm_scale,
+            kv_len=kv_len, q_start=q_start, sm_scale=sm_scale,
         )
+    if impl not in ("xla", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    B, Sq = q.shape[:2]
+    Skv = k.shape[1]
+    bq = min(block_q, Sq)
+    bk = min(block_kv, Skv)
+    kv_pad = _round_up(Skv, bk)
+    q = _pad_axis(q, 1, _round_up(Sq, bq))
+    if kv_pad > Skv:
+        k = _pad_axis(k, 1, kv_pad)
+        v = _pad_axis(v, 1, kv_pad)
+        if kv_len is None:
+            kv_len = jnp.full((B,), Skv, jnp.int32)
     if impl == "xla":
-        return xla_flash_attention(
-            q, k, v, kv_len, causal=causal, window=window, q_offset=q_offset,
-            sm_scale=sm_scale, block_q=block_q, block_kv=block_kv,
+        out = xla_flash_attention(
+            q, k, v, kv_len, q_start, causal=causal, window=window,
+            q_offset=q_offset, sm_scale=sm_scale, block_q=bq, block_kv=bk,
         )
-    if impl in ("pallas", "pallas_interpret"):
-        qt = jnp.swapaxes(q, 1, 2)
-        kt = jnp.swapaxes(k, 1, 2)
-        vt = jnp.swapaxes(v, 1, 2)
-        out = flash_attention_pallas(
-            qt, kt, vt, causal=causal, window=window, q_offset=q_offset,
-            kv_len=kv_len, sm_scale=sm_scale, block_q=block_q,
-            block_kv=block_kv, interpret=(impl == "pallas_interpret"),
-        )
-        return jnp.swapaxes(out, 1, 2)
-    raise ValueError(f"unknown attention impl {impl!r}")
+        return out[:, :Sq]
+    out = flash_attention_pallas(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+        causal=causal, window=window, q_offset=q_offset, kv_len=kv_len,
+        q_start=q_start, sm_scale=sm_scale, block_q=bq, block_kv=bk,
+        interpret=(impl == "pallas_interpret"),
+    )
+    return jnp.swapaxes(out, 1, 2)[:, :Sq]
 
 
 def decode_attention(
@@ -388,6 +428,7 @@ def attention_paged(
     window: Optional[int] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,
+    q_start: Optional[jnp.ndarray] = None,  # [B] per-row first query pos
     sm_scale: Optional[float] = None,
     impl: str = DEFAULT_IMPL,
     block_q: int = 512,
@@ -412,6 +453,10 @@ def attention_paged(
     ``ValueError``.  ``xla``/``naive`` gather the addressed rows and
     defer to the dense path.  Slot contract as in
     ``arena_decode_attention``.
+
+    ``q_start`` [B] (traced) starts row ``b``'s queries at ``q_start[b]``
+    instead of ``q_offset``; ``kv_valid`` must cover ``q_start[b] + Sq``
+    for every row.
     """
     S_alloc = k_arena.shape[1]
     rows = slots if block_tables is None else block_tables
@@ -426,26 +471,22 @@ def attention_paged(
         else:               # the kernel's kv block IS the table granularity
             bk = _block_granularity(block_tables, S_alloc, where)
         bq = min(block_q, Sq)
-        # ragged extents tile by padding: extra query rows are sliced off
-        # below, and keys in [kv_valid, kv_pad) sit past every row's
-        # kv_len, so the mask hides them
-        kv_pad = -(-kv_valid // bk) * bk
-        sq_pad = -(-Sq // bq) * bq
+        # keys in [kv_valid, kv_pad) sit past every row's kv_len, so the
+        # mask hides them
+        kv_pad = _round_up(kv_valid, bk)
         if kv_pad > S_alloc:
             _refuse(where, f"kv_valid {kv_valid} rounds up to {kv_pad} "
                            f"keys, past the arena's {S_alloc}")
         kv_len = (jnp.full((q.shape[0],), kv_valid, jnp.int32)
                   if kv_len is None else jnp.minimum(kv_len, kv_valid))
-        qt = jnp.swapaxes(q, 1, 2)
-        if sq_pad > Sq:
-            qt = jnp.pad(qt, ((0, 0), (0, 0), (0, sq_pad - Sq), (0, 0)))
+        qt = _pad_axis(jnp.swapaxes(q, 1, 2), 2, _round_up(Sq, bq))
         out = paged_flash_attention_pallas(
             qt, k_arena, v_arena, slots, kv_valid=kv_pad,
             block_tables=(None if block_tables is None
                           else block_tables[:, : kv_pad // bk]),
             causal=causal, window=window, q_offset=q_offset,
-            kv_len=kv_len, sm_scale=sm_scale, block_q=bq, block_kv=bk,
-            interpret=(impl == "pallas_interpret"))
+            kv_len=kv_len, q_start=q_start, sm_scale=sm_scale, block_q=bq,
+            block_kv=bk, interpret=(impl == "pallas_interpret"))
         return jnp.swapaxes(out[:, :, :Sq], 1, 2)
     if block_tables is None:
         k = jnp.take(k_arena, slots, axis=0)[:, :kv_valid]
@@ -455,8 +496,9 @@ def attention_paged(
         k = _gather_block_rows(k_arena, block_tables, tb)[:, :kv_valid]
         v = _gather_block_rows(v_arena, block_tables, tb)[:, :kv_valid]
     return attention(q, k, v, causal=causal, window=window,
-                     q_offset=q_offset, kv_len=kv_len, sm_scale=sm_scale,
-                     impl=impl, block_q=block_q, block_kv=block_kv)
+                     q_offset=q_offset, kv_len=kv_len, q_start=q_start,
+                     sm_scale=sm_scale, impl=impl, block_q=block_q,
+                     block_kv=block_kv)
 
 
 def relevance_score(

@@ -21,11 +21,13 @@ def mha_reference(
     window: Optional[int] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,   # [B] valid kv length (padding mask)
+    q_start: Optional[jnp.ndarray] = None,  # [B] per-row first query position
     sm_scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Grouped-query attention reference with prefix-extend semantics.
 
-    Query position i (0-based within q) has absolute position q_offset + i.
+    Query position i (0-based within q) has absolute position q_offset + i,
+    or q_start[b] + i in row b when ``q_start`` is given.
     ``causal`` masks kv positions > absolute q position; ``window`` further
     restricts to kv positions > abs_q - window.
     """
@@ -44,17 +46,18 @@ def mha_reference(
 
     scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kf)    # [B, Hq, Sq, Skv]
 
-    qpos = q_offset + jnp.arange(Sq)[:, None]          # [Sq, 1]
-    kpos = jnp.arange(Skv)[None, :]                    # [1, Skv]
-    mask = jnp.ones((Sq, Skv), dtype=bool)
+    start = (jnp.full((B,), q_offset, jnp.int32) if q_start is None
+             else q_start.astype(jnp.int32))
+    qpos = start[:, None, None] + jnp.arange(Sq)[None, :, None]  # [B, Sq, 1]
+    kpos = jnp.arange(Skv)[None, None, :]              # [1, 1, Skv]
+    mask = jnp.ones((B, Sq, Skv), dtype=bool)
     if causal:
         mask &= kpos <= qpos
     if window is not None and window > 0:
         mask &= kpos > qpos - window
-    mask_b = jnp.broadcast_to(mask[None, None], scores.shape)
     if kv_len is not None:
-        valid = kpos < kv_len[:, None, None, None]     # [B,1,1,Skv]
-        mask_b = mask_b & valid
+        mask &= kpos < kv_len[:, None, None]
+    mask_b = jnp.broadcast_to(mask[:, None], scores.shape)
     scores = jnp.where(mask_b, scores, -jnp.inf)
     # rows that are fully masked produce zeros, not NaN
     probs = jax.nn.softmax(scores, axis=-1)

@@ -6,7 +6,10 @@ Modes
              optionally emits a KV cache ("prefill").
 ``extend``   chunked prefill: queries are a suffix at static ``q_offset``;
              cached KV for ``[0, q_offset)`` is reused (the cascade
-             fraction-extension primitive).
+             fraction-extension primitive).  With ``q_start`` [B] (full
+             attention only) row ``b``'s chunk starts at its own traced
+             position instead, and ``q_offset`` bounds every start from
+             above (the serving engine's one-pass operation suffix).
 ``decode``   one new token per sequence against the cache.
 
 Caches are dicts ``{"k": [B, S_alloc, KV, Dh], "v": ...}``; keys are stored
@@ -105,6 +108,14 @@ def _project_qkv(p, x, positions, *, theta, qk_norm, mrope_sections=None,
     return q, k, v
 
 
+def chunk_positions(start: jnp.ndarray, length: int) -> jnp.ndarray:
+    """[B, length] cache positions of a chunk whose row ``b`` starts at
+    ``start[b]``: a ragged-start extend's RoPE positions and KV writes,
+    and the serving undo log's window."""
+    return (start.astype(jnp.int32)[:, None]
+            + jnp.arange(length, dtype=jnp.int32)[None])
+
+
 def attention_apply(
     p: Dict[str, Any],
     x: jnp.ndarray,                  # [B, S, D]
@@ -121,6 +132,8 @@ def attention_apply(
     q_offset: int = 0,               # static, mode=extend
     kv_len: Optional[jnp.ndarray] = None,      # [B] true (unpadded) length
                                                # incl. this chunk, mode=extend
+    q_start: Optional[jnp.ndarray] = None,     # [B] per-row chunk start
+                                               # (<= q_offset), mode=extend
     slots: Optional[jnp.ndarray] = None,       # [B] arena rows (paged serving)
     block_tables: Optional[jnp.ndarray] = None,  # [B, S_alloc // block] rows
                                                # per cache block (prefix
@@ -185,6 +198,8 @@ def attention_apply(
                 new_cache = {"k": k, "v": v}
     elif mode == "extend":
         assert cache is not None
+        assert q_start is None or window in (None, 0), \
+            "ragged-start extend (q_start) supports full attention only"
         if slots is not None:
             # paged extend: ``cache`` is the slot arena [N_rows, S, KV, Dh];
             # scatter the chunk's KV into the addressed rows, then attend
@@ -194,15 +209,18 @@ def attention_apply(
             kv_valid = min(q_offset + S, cache["k"].shape[1])
             # the arena may store KV compressed (bf16 for f32 models):
             # quantize on the scatter; the kernels upcast to f32 at read
-            ck = cache["k"].at[slots, q_offset:q_offset + S].set(
-                k.astype(cache["k"].dtype))
-            cv = cache["v"].at[slots, q_offset:q_offset + S].set(
-                v.astype(cache["v"].dtype))
+            if q_start is None:
+                rows, cols = slots, slice(q_offset, q_offset + S)
+            else:
+                rows, cols = slots[:, None], chunk_positions(q_start, S)
+            ck = cache["k"].at[rows, cols].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[rows, cols].set(v.astype(cache["v"].dtype))
             out = ops.attention_paged(
                 q, ck, cv, slots, kv_valid=kv_valid,
                 block_tables=block_tables, causal=causal,
-                q_offset=q_offset, kv_len=kv_len, impl=rt.attn_impl,
-                sm_scale=sm_scale, block_q=rt.block_q, block_kv=rt.block_kv,
+                q_offset=q_offset, kv_len=kv_len, q_start=q_start,
+                impl=rt.attn_impl, sm_scale=sm_scale, block_q=rt.block_q,
+                block_kv=rt.block_kv,
             )
             if want_cache:
                 new_cache = {"k": ck, "v": cv}
@@ -263,17 +281,24 @@ def attention_apply(
                 new_cache = {"k": ck, "v": cv}
         else:
             # full-attention extend: write new kv at [q_offset, q_offset+S)
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), q_offset, 1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), q_offset, 1)
+            # (or at [q_start[b], q_start[b]+S) of row b)
+            if q_start is None:
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), q_offset, 1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), q_offset, 1)
+            else:
+                rows = jnp.arange(B)[:, None]
+                cols = chunk_positions(q_start, S)
+                ck = cache["k"].at[rows, cols].set(k.astype(cache["k"].dtype))
+                cv = cache["v"].at[rows, cols].set(v.astype(cache["v"].dtype))
             kv_valid = q_offset + S
             out = ops.attention(
                 q, ck[:, :kv_valid] if kv_valid < ck.shape[1] else ck,
                 cv[:, :kv_valid] if kv_valid < cv.shape[1] else cv,
                 causal=causal, q_offset=q_offset, kv_len=kv_len,
-                impl=rt.attn_impl, sm_scale=sm_scale, block_q=rt.block_q,
-                block_kv=rt.block_kv,
+                q_start=q_start, impl=rt.attn_impl, sm_scale=sm_scale,
+                block_q=rt.block_q, block_kv=rt.block_kv,
             )
             if want_cache:
                 new_cache = {"k": ck, "v": cv}

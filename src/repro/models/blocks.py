@@ -180,6 +180,7 @@ def block_apply(
     cache_len: Optional[jnp.ndarray] = None,
     q_offset: int = 0,
     kv_len: Optional[jnp.ndarray] = None,      # [B] true length, mode=extend
+    q_start: Optional[jnp.ndarray] = None,     # [B] chunk starts, mode=extend
     slots: Optional[jnp.ndarray] = None,       # [B] arena rows (paged serving)
     block_tables: Optional[jnp.ndarray] = None,  # [B, nblocks] rows per cache
                                                # block (prefix sharing)
@@ -211,6 +212,7 @@ def block_apply(
             cache_len=cache_len,
             q_offset=q_offset,
             kv_len=kv_len,
+            q_start=q_start,
             slots=slots,
             block_tables=block_tables,
             want_cache=(mode != "train"),
@@ -219,20 +221,23 @@ def block_apply(
             norm_eps=b.norm_eps,
         )
     elif kind == MLSTM:
-        assert slots is None, \
-            "paged serving (slots) supports attention-state models only"
+        assert slots is None and q_start is None, \
+            "paged serving (slots) and ragged-start extend (q_start) " \
+            "support attention-state models only"
         mix, new_state = ssm.mlstm_apply(
             p["mlstm"], h, state=state,
             mode=("step" if mode == "decode" else "full"),
             heads=b.num_heads)
     elif kind == SLSTM:
-        assert slots is None, \
-            "paged serving (slots) supports attention-state models only"
+        assert slots is None and q_start is None, \
+            "paged serving (slots) and ragged-start extend (q_start) " \
+            "support attention-state models only"
         mix, new_state = ssm.slstm_apply(
             p["slstm"], h, state=state, heads=b.num_heads)
     elif kind == RGLRU:
-        assert slots is None, \
-            "paged serving (slots) supports attention-state models only"
+        assert slots is None and q_start is None, \
+            "paged serving (slots) and ragged-start extend (q_start) " \
+            "support attention-state models only"
         mix, new_state = ssm.rglru_apply(
             p["rglru"], h, state=state,
             mode=("step" if mode == "decode" else "full"))

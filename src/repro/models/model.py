@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from ..config import ATTN_FULL, ATTN_LOCAL, ResolvedConfig
 from ..distributed.sharding import batch_pspec, constrain
 from . import blocks
+from .attention import chunk_positions
 from .layers import embed_apply, init_embed, init_rmsnorm, lm_head_apply, \
     rmsnorm_apply, spec_embed, spec_rmsnorm
 from .runtime import Runtime
@@ -180,8 +181,7 @@ class LM:
 
     def _kv_window_idx(self, slots: jnp.ndarray, start: jnp.ndarray,
                        length: int):
-        win = start[:, None] + jnp.arange(length, dtype=jnp.int32)[None]
-        return slots[:, None], win                       # [B, 1], [B, L]
+        return slots[:, None], chunk_positions(start, length)  # [B,1],[B,L]
 
     def take_kv_window(self, states, slots: jnp.ndarray,
                        start: jnp.ndarray, length: int):
@@ -189,7 +189,7 @@ class LM:
         at arena rows ``slots`` -> a tiny [B, length, KV, Dh]-per-leaf
         pytree.  With ``put_kv_window`` this is the paged op-suffix UNDO
         LOG: the serving engine snapshots the ``length`` cache positions
-        an operation suffix will dirty, decodes in place, then restores —
+        an operation suffix will dirty, runs it in place, then restores —
         O(B * op_len) bytes instead of the full [B, S] row copy.  Only
         valid for ``supports_paged_kv`` models (every leaf is a KV cache
         whose sequence axis follows the batch axis)."""
@@ -265,8 +265,8 @@ class LM:
 
     # ------------------------------------------------------------------ core
     def _run_blocks(self, params, x, *, mode, states=None, cache_len=None,
-                    q_offset=0, kv_len=None, slots=None, block_tables=None,
-                    positions=None, positions3=None):
+                    q_offset=0, kv_len=None, q_start=None, slots=None,
+                    block_tables=None, positions=None, positions3=None):
         rcfg, rt = self.rcfg, self.rt
         dp_spec = self._dp_spec()
         pattern = self.pattern
@@ -281,8 +281,8 @@ class LM:
                 x, ns, a = blocks.block_apply(
                     stage_params[pi], x, kind=kind, rcfg=rcfg, rt=rt,
                     mode=mode, state=st, cache_len=cache_len,
-                    q_offset=q_offset, kv_len=kv_len, slots=slots,
-                    block_tables=block_tables,
+                    q_offset=q_offset, kv_len=kv_len, q_start=q_start,
+                    slots=slots, block_tables=block_tables,
                     positions=positions, positions3=positions3,
                     dp_spec=dp_spec)
                 x = self._constrain_act(x)
@@ -332,7 +332,8 @@ class LM:
             x, ns, a = blocks.block_apply(
                 params["tail"][ti], x, kind=kind, rcfg=rcfg, rt=rt,
                 mode=mode, state=st, cache_len=cache_len, q_offset=q_offset,
-                kv_len=kv_len, slots=slots, block_tables=block_tables,
+                kv_len=kv_len, q_start=q_start, slots=slots,
+                block_tables=block_tables,
                 positions=positions, positions3=positions3, dp_spec=dp_spec)
             x = self._constrain_act(x)
             new_tail.append(ns)
@@ -398,7 +399,8 @@ class LM:
     def extend(self, params, batch: Dict[str, jnp.ndarray], states,
                q_offset: int, kv_len: Optional[jnp.ndarray] = None,
                slots: Optional[jnp.ndarray] = None,
-               block_tables: Optional[jnp.ndarray] = None):
+               block_tables: Optional[jnp.ndarray] = None,
+               q_start: Optional[jnp.ndarray] = None):
         """Cascade fraction-extension: new tokens at [q_offset, q_offset+S).
 
         ``kv_len`` [B] is the TRUE (unpadded) sequence length including this
@@ -417,14 +419,29 @@ class LM:
         cache block ``j`` of sequence ``b`` is fetched from arena row
         ``block_tables[b, j]`` instead of ``slots[b]`` — the prefix-sharing
         indirection.  Writes still land in row ``slots[b]``.
+
+        ``q_start`` [B] (traced; models whose layers are all full
+        attention) gives each row its own start: row ``b``'s chunk takes
+        positions [q_start[b], q_start[b]+S), its KV is written there and
+        ``kv_len`` defaults to ``q_start + S``.  ``q_offset`` then bounds
+        every start from above and fixes the keys attended, [0, q_offset +
+        S).  An unpadded chunk's last position is then every row's true
+        last token, so the returned logits are each row's own — the
+        serving engine runs a whole operation suffix this way in one pass.
         """
         x = self.embed_inputs(params, batch)
         B, S, _ = x.shape
         positions = q_offset + jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        if q_start is not None:
+            assert self.supports_paged_kv, \
+                "ragged-start extend needs every layer to be full attention"
+            positions = chunk_positions(q_start, S)
+            if kv_len is None:
+                kv_len = q_start + S
         x, new_states, _ = self._run_blocks(
             params, x, mode="extend", states=states, q_offset=q_offset,
-            kv_len=kv_len, slots=slots, block_tables=block_tables,
-            positions=positions,
+            kv_len=kv_len, q_start=q_start, slots=slots,
+            block_tables=block_tables, positions=positions,
             positions3=batch.get("positions3"),
             cache_len=jnp.full((B,), q_offset, jnp.int32))
         x = rmsnorm_apply(params["final_norm"], x[:, -1:],
@@ -441,7 +458,7 @@ class LM:
         ``slots`` [B] switches to PAGED mode: ``states`` is the slot arena
         and the step reads/writes row ``slots[b]`` in place (the token's
         KV lands at position ``pos[b]`` of that row; callers that must not
-        dirty the row — the serving op suffix — bracket the steps with
+        dirty the row — the serving readout — bracket the steps with
         ``take_kv_window``/``put_kv_window``).  ``block_tables``
         [B, nblocks] redirects cache READS per block (prefix sharing);
         the written token still lands in ``slots[b]``."""

@@ -12,7 +12,7 @@ operations in the token stream, so
     ONLY the operation tokens against the cached document KV;
   * the engine never merges operation tokens into the cached document
     state, exactly mirroring the doc-before-op prompt layout: on the
-    paged data plane op suffixes decode over the arena in place behind a
+    paged data plane op suffixes run over the arena in place behind a
     tiny KV-window undo log, on the gather plane against a row copy that
     is dropped — either way the cached document prefix survives bitwise
     untouched.
@@ -76,17 +76,20 @@ interleaved stages AND interleaved queries share compiled steps.
 Prefill-into-arena is the ``cached_len == 0`` case of extend, fraction
 extension writes the suffix at a static offset with per-row true lengths
 masking bucket PAD out of the chunk (``kernels/flash_attention.py``
-scalar-prefetch ``kv_len``), and the operation suffix runs as masked
-decode steps whose per-document ``kv_len`` rides through
-``kernels/decode_attention.py``.
+scalar-prefetch ``kv_len``), and the operation suffix runs as ONE causal
+extend of its ``op_len`` tokens starting at each document's own true
+length (the same kernel's scalar-prefetch ``q_start``): one model pass
+per launch, not one per op token.  Models with sliding-window or
+recurrent layers (no per-row positional write) keep a loop of masked
+decode steps, one per op token (``LMBackend.one_pass_op_suffix``).
 
 Paged data plane (default on Pallas runtimes, for models whose
 serve-state is all full-attention KV caches): the stage step never
 copies arena rows.  Per-sequence slot ids ride in scalar-prefetch SMEM
 beside ``kv_len`` and the paged kernels
 (``ops.arena_decode_attention`` / ``ops.attention_paged``) DMA
-``k_arena[slot]`` blocks directly, so extend scatters only the chunk's
-KV and decode reads the arena in place — per-launch copy traffic drops
+``k_arena[slot]`` blocks directly, so an extend scatters only its
+chunk's KV and reads the arena in place — per-launch copy traffic drops
 from O(batch * s_alloc) (the gather/scatter of whole rows) to the
 O(batch * op_len) op-suffix undo log (see ``LMBackend.paged_step``'s
 comments; ``gather_bytes_per_launch`` vs ``paged_copy_bytes_per_launch``
@@ -732,8 +735,36 @@ class LMBackend:
                 "full-attention KV caches (LM.supports_paged_kv)"
         return self.paged
 
+    @property
+    def one_pass_op_suffix(self) -> bool:
+        """Whether a standard-layout launch runs its operation suffix as
+        ONE ragged-start extend: when every layer is full attention
+        (``LM.supports_paged_kv``), each row's op KV can be written at its
+        own true length.  Otherwise the suffix is one decode step per op
+        token."""
+        return bool(getattr(self.model, "supports_paged_kv", False))
+
     def _build_step(self):
         model = self.model
+        one_pass = self.one_pass_op_suffix
+
+        def op_suffix(params, states, slots, op_tok, pos, *, ext: int,
+                      op_len: int):
+            # the operation's tokens at [pos[b], pos[b] + op_len) of each
+            # row (pos = per-doc TRUE prefix length, so bucket-PAD KV is
+            # invisible); ``ext`` = the launch's padded prefix extent,
+            # which bounds every pos
+            B = pos.shape[0]
+            if one_pass:
+                tok = jnp.broadcast_to(op_tok[None], (B, op_len))
+                return model.extend(params, {"tokens": tok}, states,
+                                    q_offset=ext, q_start=pos, slots=slots)
+            logits = None
+            for t in range(op_len):
+                tok = jnp.broadcast_to(op_tok[t], (B,))
+                logits, states = model.decode_step(params, tok, states,
+                                                   pos + t, slots=slots)
+            return logits, states
 
         def gather_step(params, arena_states, slots, new_tok, op_tok,
                         kv_true, ext_true, *, c_len: int, op_len: int):
@@ -745,15 +776,11 @@ class LMBackend:
                 _, st = model.extend(params, {"tokens": new_tok}, st,
                                      q_offset=c_len, kv_len=ext_true)
                 arena_states = model.put_states(arena_states, slots, st)
-            # operation suffix: masked decode steps over the gathered COPY
-            # (kv_true = per-doc TRUE prefix length -> pad KV is invisible;
-            # the doc snapshot in the arena survives untouched)
-            logits = None
-            pos = kv_true.astype(jnp.int32)
-            B = slots.shape[0]
-            for t in range(op_len):
-                tok = jnp.broadcast_to(op_tok[t], (B,))
-                logits, st = model.decode_step(params, tok, st, pos + t)
+            # operation suffix over the gathered COPY: the doc snapshot in
+            # the arena survives untouched
+            logits, _ = op_suffix(params, st, None, op_tok,
+                                  kv_true.astype(jnp.int32),
+                                  ext=c_len + new_tok.shape[1], op_len=op_len)
             return logits, arena_states
 
         def paged_step(params, arena_states, slots, new_tok, op_tok,
@@ -767,22 +794,18 @@ class LMBackend:
                 _, arena_states = model.extend(
                     params, {"tokens": new_tok}, arena_states,
                     q_offset=c_len, kv_len=ext_true, slots=slots)
-            # operation suffix: masked decode steps run IN PLACE over the
-            # arena.  The op tokens' KV lands at [kv_true, kv_true+op_len)
-            # of each row — positions that may hold live document KV (the
-            # true fraction can undershoot the padded cache) — so the
-            # window is snapshotted first and restored after: an O(B *
-            # op_len) undo log instead of an O(B * s_alloc) row copy, and
-            # the arena leaves the step bitwise identical to the gather
-            # path's.
-            logits = None
+            # operation suffix IN PLACE over the arena.  The op tokens' KV
+            # lands at [kv_true, kv_true+op_len) of each row — positions
+            # that may hold live document KV (the true fraction can
+            # undershoot the padded cache) — so the window is snapshotted
+            # first and restored after: an O(B * op_len) undo log instead
+            # of an O(B * s_alloc) row copy, and the arena leaves the step
+            # bitwise identical to the gather path's.
             pos = kv_true.astype(jnp.int32)
-            B = slots.shape[0]
             saved = model.take_kv_window(arena_states, slots, pos, op_len)
-            for t in range(op_len):
-                tok = jnp.broadcast_to(op_tok[t], (B,))
-                logits, arena_states = model.decode_step(
-                    params, tok, arena_states, pos + t, slots=slots)
+            logits, arena_states = op_suffix(
+                params, arena_states, slots, op_tok, pos,
+                ext=c_len + new_tok.shape[1], op_len=op_len)
             arena_states = model.put_kv_window(arena_states, slots, pos,
                                                op_len, saved)
             return logits, arena_states

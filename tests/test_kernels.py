@@ -482,3 +482,78 @@ def test_paged_decode_bf16_arena_tolerance():
         slots, kv_len, impl="pallas_interpret", block_kv=16)
     np.testing.assert_allclose(np.asarray(out16, np.float32),
                                np.asarray(out32), atol=3e-2, rtol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# Ragged-start extend: per-row query starts in scalar-prefetch SMEM
+# ---------------------------------------------------------------------------
+
+# (per-row starts, padded extent): each row's queries start at its own true
+# length, at most the launch's padded extent; a start below the extent is
+# the undershoot case (true fraction under the cached padded one), where
+# the chunk's keys overwrite cached positions
+_RAGGED_STARTS = [
+    ([40, 40, 40], 40),       # every row at the padded extent
+    ([40, 13, 0], 40),        # undershoot, and a row starting at 0
+    ([7, 31, 22], 40),        # every row below the extent
+]
+
+
+def _ragged_case(starts, ext, Sq):
+    N, B, S_alloc, Hq, Hkv, Dh = 6, 3, 80, 4, 2, 16
+    key = jax.random.PRNGKey(31)
+    q = jax.random.normal(key, (B, Sq, Hq, Dh), jnp.float32)
+    k_arena, v_arena = _mk_arena(key, N, S_alloc, Hkv, Dh)
+    slots = jnp.asarray([5, 0, 3], jnp.int32)   # scratch row 5 included
+    q_start = jnp.asarray(starts, jnp.int32)
+    kv_valid = ext + Sq
+    kg = k_arena[np.asarray(slots)][:, :kv_valid]
+    vg = v_arena[np.asarray(slots)][:, :kv_valid]
+    return q, k_arena, v_arena, slots, q_start, kv_valid, kg, vg
+
+
+@pytest.mark.parametrize("starts,ext", _RAGGED_STARTS)
+@pytest.mark.parametrize("Sq", [7, 24])   # within one q block / across two
+def test_flash_q_start_vs_ref(starts, ext, Sq):
+    """Paged and dense flash with a per-row ``q_start`` (interpret mode),
+    and the xla fallback's masked path, match the naive reference."""
+    q, k_arena, v_arena, slots, q_start, kv_valid, kg, vg = _ragged_case(
+        starts, ext, Sq)
+    kv_len = q_start + Sq
+    out_ref = ref.mha_reference(q, kg, vg, causal=True, kv_len=kv_len,
+                                q_start=q_start)
+    outs = {"paged": ops.attention_paged(
+        q, k_arena, v_arena, slots, kv_valid=kv_valid, kv_len=kv_len,
+        q_start=q_start, impl="pallas_interpret", block_q=16, block_kv=16)}
+    for impl in ("pallas_interpret", "xla"):
+        outs[impl] = ops.attention(q, kg, vg, causal=True, kv_len=kv_len,
+                                   q_start=q_start, impl=impl, block_q=16,
+                                   block_kv=16)
+    for name, out in outs.items():
+        assert out.shape == q.shape, name
+        np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                                   atol=3e-5, rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("starts,ext", _RAGGED_STARTS)
+def test_paged_flash_q_start_bitwise_equals_dense(starts, ext):
+    """With ``q_start``, paged flash over the arena == dense flash over the
+    gathered rows, bitwise; one start for every row == the static
+    ``q_offset`` path, bitwise."""
+    Sq = 24
+    q, k_arena, v_arena, slots, q_start, kv_valid, kg, vg = _ragged_case(
+        starts, ext, Sq)
+    kv_len = q_start + Sq
+    kw = dict(kv_len=kv_len, impl="pallas_interpret", block_q=16,
+              block_kv=16)
+    out_paged = ops.attention_paged(q, k_arena, v_arena, slots,
+                                    kv_valid=kv_valid, q_start=q_start, **kw)
+    out_dense = ops.attention(q, kg, vg, causal=True, q_start=q_start, **kw)
+    np.testing.assert_array_equal(np.asarray(out_paged),
+                                  np.asarray(out_dense))
+    if len(set(starts)) == 1:
+        out_static = ops.attention_paged(q, k_arena, v_arena, slots,
+                                         kv_valid=kv_valid,
+                                         q_offset=starts[0], **kw)
+        np.testing.assert_array_equal(np.asarray(out_paged),
+                                      np.asarray(out_static))

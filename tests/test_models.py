@@ -14,7 +14,7 @@ import pytest
 from repro.config import resolve
 from repro.configs import ARCHS, get_reduced
 from repro.models.model import LM
-from repro.models.runtime import CPU_TEST, Runtime
+from repro.models.runtime import CPU_KERNEL_TEST, CPU_TEST, Runtime
 from repro.models.whisper import WhisperModel
 
 
@@ -173,3 +173,37 @@ def test_rglru_scan_matches_step_by_step():
                                atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(st["h"]), np.asarray(st_full["h"]),
                                atol=1e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_one_pass_op_extend_matches_decode_loop(paged):
+    """The serving engine's one-pass operation suffix: one ragged-start
+    extend of the op tokens at each row's true length gives the logits of
+    the per-token decode loop it replaces.  Row 1 starts below the padded
+    extent (its op KV overwrites cached document positions), row 2 at it;
+    on the paged plane the rows live in a slot arena, scratch row
+    included."""
+    cfg = get_reduced("llama3_2_1b", dtype="float32", num_layers=2)
+    model = LM(resolve(cfg, tp=1), CPU_KERNEL_TEST)
+    params = model.init(jax.random.PRNGKey(8))
+    B, S, P, s_alloc = 3, 24, 7, 48
+    toks = jax.random.randint(jax.random.PRNGKey(9), (B, S), 9,
+                              cfg.vocab_size)
+    op = jax.random.randint(jax.random.PRNGKey(10), (P,), 9, cfg.vocab_size)
+    kv_true = jnp.asarray([5, 13, S], jnp.int32)
+    if paged:
+        slots = jnp.asarray([4, 0, 2], jnp.int32)
+        _, st = model.extend(params, {"tokens": toks},
+                             model.init_states(5, s_alloc), q_offset=0,
+                             slots=slots)
+    else:
+        slots = None
+        _, st = model.prefill(params, {"tokens": toks}, s_alloc=s_alloc)
+    one, _ = model.extend(params, {"tokens": jnp.broadcast_to(op, (B, P))},
+                          st, q_offset=S, q_start=kv_true, slots=slots)
+    loop = None
+    for t in range(P):
+        loop, st = model.decode_step(params, jnp.broadcast_to(op[t], (B,)),
+                                     st, kv_true + t, slots=slots)
+    np.testing.assert_allclose(np.asarray(one), np.asarray(loop),
+                               atol=2e-5, rtol=1e-4)

@@ -707,6 +707,50 @@ def test_paged_op_suffix_leaves_arena_bitwise_pristine():
     np.testing.assert_array_equal(c1, c2)
 
 
+@pytest.mark.parametrize("arch,over,one_pass,doc_ids", [
+    ("llama3_2_1b", {"num_layers": 2}, True, (0, 1, 3)),   # full attention
+    ("gemma3_27b", {"num_layers": 6, "sliding_window": 8}, False, (3,)),
+])
+def test_one_pass_op_suffix_serves_as_the_loop(monkeypatch, arch, over,
+                                               one_pass, doc_ids):
+    """The one-pass operation suffix engages exactly for models whose
+    layers are all full attention, and serves the answers of the
+    per-token decode loop: same predictions and $, confidences to
+    rounding.  A windowed model keeps the loop and still serves."""
+    thr = {0: 2.0, 1: 2.0}       # impossible: every doc walks every stage
+    ladder = Cascade([
+        Task(TaskConfig("proxy", "sur_1", 0.25), thr),
+        Task(TaskConfig("proxy", "o_orig", 0.25), thr),   # decode-only
+        Task(TaskConfig("oracle", "o_orig", 1.0), thr),
+    ])
+    cfg = get_reduced(arch, dtype="float32", vocab_size=512, **over)
+    m = LM(resolve(cfg, tp=1), CPU_TEST)
+    tokz = HashWordTokenizer(vocab_size=512)
+
+    def engine():
+        return CascadeEngine({name: LMBackend(
+            name=name, model=m, params=m.init(jax.random.PRNGKey(seed)),
+            tokenizer=tokz, rate_per_token=1.0 if name == "oracle" else 0.06,
+            s_alloc=512) for name, seed in (("proxy", 1), ("oracle", 2))},
+            OPS, n_classes=2, batch_size=4)
+
+    docs = {d: _PAGED_DOCS[d] for d in doc_ids}
+    eng = engine()
+    assert eng.backends["proxy"].one_pass_op_suffix == one_pass
+    served = eng.run(ladder, docs)
+    assert sorted(served.pred) == sorted(docs)
+    if not one_pass:
+        return                   # served through the loop already
+    monkeypatch.setattr(LMBackend, "one_pass_op_suffix",
+                        property(lambda self: False))
+    loop = engine().run(ladder, docs)
+    assert served.pred == loop.pred
+    assert served.doc_cost == loop.doc_cost
+    for d in docs:
+        np.testing.assert_allclose(served.conf[d], loop.conf[d],
+                                   atol=1e-5, rtol=1e-5)
+
+
 def test_paged_gather_bytes_accounting():
     """The copy-traffic model behind the benchmark's paged section: the
     gather step moves whole [B, s_alloc] rows per launch, the paged step

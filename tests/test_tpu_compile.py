@@ -4,9 +4,10 @@ Nothing runs: the TPU compiler, which is installed with libtpu, lowers
 each kernel for a chip that is described, not attached, and raises
 whatever the chip's compiler would raise (block shapes Mosaic refuses,
 scoped-VMEM overflows).  Each test asserts the Mosaic kernel is in the
-compiled program (``tpu_custom_call``).  Widths come from the two stage
-models the chip path serves: llama3.2-1b (32/8 heads, head_dim 64) and
-qwen3-1.7b (16/8 heads, head_dim 128), in bf16.
+compiled program (``tpu_custom_call``).  Widths come from the stage
+models the chip path serves: llama3.2-1b (32/8 heads, head_dim 64),
+qwen3-1.7b (16/8 heads, head_dim 128), and for the one-pass operation
+suffix the Qwen3-4B and Qwen3-0.6B widths, in bf16.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every xdist
@@ -20,12 +21,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.config import resolve
-from repro.configs import get_config
+from repro.configs import get_config, get_reduced
+from repro.data.tokenizer import HashWordTokenizer
 from repro.kernels import ops
 from repro.kernels.decode_attention import (decode_attention_pallas,
                                             paged_decode_attention_pallas)
 from repro.kernels.flash_attention import (flash_attention_pallas,
                                            paged_flash_attention_pallas)
+from repro.models.model import LM
+from repro.models.runtime import Runtime
+from repro.serving.engine import LMBackend
 
 MODELS = ("llama3_2_1b", "qwen3_1_7b")
 
@@ -116,6 +121,27 @@ def test_paged_flash_compiles(one_chip, arch, S, Sq, kv_valid, q_offset):
     assert "tpu_custom_call" in _compile_text(fn, *args)
 
 
+# the one-pass operation suffix of the Qwen3 benchmark pairs: 60 op tokens
+# at ragged per-row starts, over the 4B oracle's 2048-bucket arena row
+# (2048 + 64 op positions rounded to the 512 block; keys attended up to
+# 2048 + 60, padded to 2560) and the 0.6B proxy's 64-bucket row (64 + 64)
+@pytest.mark.parametrize("Hq,B,N,S,kv_valid", [
+    (32, 2, 3, 2560, 2108),       # Qwen3-4B: 32/8 heads, launches of 2
+    (16, 4, 18, 128, 124),        # Qwen3-0.6B: 16/8 heads, launches of 4
+])
+def test_paged_flash_q_start_compiles(one_chip, Hq, B, N, S, kv_valid):
+    Hkv, Dh, Sq = 8, 128, 60
+    args = (_sds(one_chip, (B, Sq, Hq, Dh)),
+            _sds(one_chip, (N, S, Hkv, Dh)), _sds(one_chip, (N, S, Hkv, Dh)),
+            _sds(one_chip, (B,), jnp.int32), _sds(one_chip, (B,), jnp.int32))
+
+    def fn(q, k, v, slots, q_start):
+        return ops.attention_paged(
+            q, k, v, slots, kv_valid=kv_valid, q_start=q_start,
+            kv_len=q_start + Sq, impl="pallas", block_q=512, block_kv=512)
+    assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
 @pytest.mark.parametrize("arch", MODELS)
 def test_dense_pair_compiles(one_chip, arch):
     Hq, Hkv, Dh = _heads(arch)
@@ -143,3 +169,30 @@ def test_relevance_score_compiles(one_chip, C, T, D):
     def fn(x, lengths, w, b):
         return ops.relevance_score(x, lengths, w, b, impl="pallas")
     assert "tpu_custom_call" in _compile_text(fn, *args)
+
+
+# a prefill launch (64 new tokens) and a decode-only one (64 cached)
+@pytest.mark.parametrize("c_len,n_new", [(0, 64), (64, 0)])
+def test_paged_step_runs_op_suffix_as_one_flash_pass(one_chip, c_len, n_new):
+    """The served paged step of a full-attention model, compiled for the
+    chip: the operation suffix is one ragged-start flash pass, so the
+    program holds the paged flash kernel and no paged decode kernel."""
+    cfg = get_reduced("qwen3_1_7b", num_layers=2, vocab_size=512)
+    model = LM(resolve(cfg, tp=1), Runtime(attn_impl="pallas", remat=False))
+    be = LMBackend(name="proxy", model=model, params=None,
+                   tokenizer=HashWordTokenizer(512), paged=True)
+    B, N, op_len = 4, 5, 7
+    s_alloc = be._s_alloc_for(64)
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    arena = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         model.state_shapes(N, s_alloc))
+    i32 = jnp.int32
+    step = be._build_step()
+    text = step.lower(
+        params, arena, _sds(one_chip, (B,), i32),
+        _sds(one_chip, (B, n_new), i32), _sds(one_chip, (op_len,), i32),
+        _sds(one_chip, (B,), i32), _sds(one_chip, (B,), i32),
+        c_len=c_len, op_len=op_len).compile().as_text()
+    assert "paged_flash_attention" in text
+    assert "paged_decode_attention" not in text
