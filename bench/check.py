@@ -1,11 +1,12 @@
 """The comparison that decides ``correct``.
 
-For each sampled document the reference (``reference.py``, float32) reads
-the class logits after the prompt its exit stage served — the stage's
-fraction of the document followed by the stage's operation — through
-that stage's model, with the same weights.  The served answer is the
-class ``pred`` and its confidence ``conf`` (softmax over the class
-logits).  The number compared is the widest, over the sample, of
+For each sampled document the reference (``class_logits`` of the stage
+model's ``arch/<model_type>.py``, float32) reads the class logits after
+the prompt its exit stage served — the stage's fraction of the document
+followed by the stage's operation — through that stage's model, with
+the same weights.  The served answer is the class ``pred`` and its
+confidence ``conf`` (softmax over the class logits).  The number
+compared is the widest, over the sample, of
 
     max( lp[best] - lp[pred],  |log conf - lp[pred]| )
 
@@ -64,8 +65,8 @@ def compare(cell, params: Mapping[str, Any], resolved: Sequence[Any],
         vocab = models[stage_role(mix, s.doc.exit_stage)]["vocab_size"]
         role, toks = stage_prompt(mix, ops, vocab, s.doc.text,
                                   s.doc.exit_stage)
-        logits = REF.class_logits(params[role], models[role], toks,
-                                  n_classes)
+        logits = cell.arch[role].class_logits(params[role], models[role],
+                                              toks, n_classes)
         gap = answer_gap(logits, s.pred, s.conf)
         worst = max(worst, gap)
         log(f"  doc {s.doc.index} ({s.doc.n_tokens} tokens) stage "

@@ -30,24 +30,22 @@ def control_run(cell, seed: int, seconds: float,
 
     import check as CK
     import harness
-    import reference as REF
     import traffic as TR
-    import weights as W
     mix, models = cell.mix, cell.config["models"]
     n_classes = int(mix["classes"])
     traffic = TR.Traffic(mix, seed)
     docs = [traffic.doc(k) for k in range(traffic.window_docs(seconds))]
     sample = traffic.sample(docs)
     ops = traffic.operations()
-    params = {role: W.make_params(models[role], seed, i)
+    params = {role: cell.arch[role].make_params(models[role], seed, i)
               for i, role in enumerate(("proxy", "oracle"))}
     answered = []
     for d in sample:
         role = CK.stage_role(mix, d.exit_stage)
         _, toks = CK.stage_prompt(mix, ops, models[role]["vocab_size"],
                                   d.text, d.exit_stage)
-        z = REF.class_logits(params[role], models[role], toks, n_classes,
-                             control=True)
+        z = cell.arch[role].class_logits(params[role], models[role], toks,
+                                         n_classes, control=True)
         p = np.exp(z - z.max())
         p /= p.sum()
         answered.append(harness.Served(

@@ -8,6 +8,10 @@ file of its own, found by the name ``BENCHMARK.json`` gives it:
   configs/<config>.json   the two models' published sizes and the serving
                           settings (launch width, launches in flight,
                           arena slots)
+  arch/<model_type>.py    one architecture, named by each model entry's
+                          published ``model_type``: ``model_config``,
+                          ``make_params``, ``class_logits``,
+                          ``stage_work``, ``paged_attention_layers``
   traffic/<traffic>.json  the mix, read by ``traffic.Traffic``
   metrics/<metric>.py     ``read(run) -> float | None`` for each metric
 """
@@ -35,7 +39,7 @@ import trace_reduce as TRR
 import traffic as TR
 import weights as W
 import work as WK
-from repro.config import ATTN_FULL, DENSE, ModelConfig, resolve
+from repro.config import resolve
 from repro.core.tasks import Cascade, Task, TaskConfig
 from repro.data.tokenizer import HashWordTokenizer
 from repro.models.model import LM
@@ -68,6 +72,7 @@ class Cell:
     chips: int
     config_name: str
     config: Dict[str, Any]
+    arch: Dict[str, Any]               # role -> arch/<model_type>.py
     traffic_name: str
     mix: Dict[str, Any]
     e2e: List[Dict[str, Any]]          # this cell's end-to-end metrics
@@ -89,8 +94,11 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     moved = {m["name"] for m in e2e}
     per_layer = [m for m in bm["per_layer"]
                  if _applies(m, name) and m["moves"] in moved]
+    config = load_json(os.path.join(root, cfg["file"]))
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=load_json(os.path.join(root, cfg["file"])),
+                config=config,
+                arch={role: load_arch(root, m.get("model_type"))
+                      for role, m in config["models"].items()},
                 traffic_name=w["traffic"],
                 mix=TR.load_mix(os.path.join(root, "bench", "traffic",
                                              f"{w['traffic']}.json")),
@@ -106,11 +114,35 @@ def load_reader(root: str, metric: str) -> Callable:
     if not os.path.exists(path) and "." in metric:
         path = os.path.join(root, "bench", "metrics",
                             f"{metric.rsplit('.', 1)[0]}.py")
+    return _load_module(path, "bench_metric_" + metric).read
+
+
+_ARCHS: Dict[str, Any] = {}     # path -> module, loaded once a process
+
+
+def load_arch(root: str, model_type: Optional[str]):
+    """``arch/<model_type>.py``: the architecture's builder, weights,
+    reference and work counts (``arch/qwen3.py`` shows the five
+    functions).  A model entry with no ``model_type``, or one that names
+    no module, stops the run; there is no default architecture.  Each
+    file is loaded once, so the models of one architecture share its
+    module and the reference programs it has compiled."""
+    path = os.path.join(root, "bench", "arch",
+                        f"{model_type or '<model_type>'}.py")
+    if not model_type or not os.path.isfile(path):
+        raise ValueError(f"model_type {model_type!r} names no architecture "
+                         f"module: looked for {path}")
+    if path not in _ARCHS:
+        _ARCHS[path] = _load_module(path, "bench_arch_" + model_type)
+    return _ARCHS[path]
+
+
+def _load_module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 # ------------------------------------------------------------- the device
@@ -158,21 +190,6 @@ class CompileCounter:
 
 
 # -------------------------------------------------------- the served pair
-def model_config(m: Mapping[str, Any], dtype: str):
-    if m["hidden_act"] != "silu" or not m["tie_word_embeddings"]:
-        raise ValueError(f"{m['name']}: the Qwen3 stack takes SwiGLU and "
-                         "tied embeddings")
-    return ModelConfig(
-        name=m["name"], family=DENSE, num_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-        block_pattern=(ATTN_FULL,), qk_norm=True,
-        rope_theta=float(m["rope_theta"]), tie_embeddings=True,
-        norm_eps=float(m["rms_norm_eps"]), act="silu",
-        max_seq_len=int(m["max_position_embeddings"]), dtype=dtype)
-
-
 def build_server(cell: Cell, seed: int, buckets: List[int],
                  operations: Dict[str, str]):
     """The pair behind one ``CascadeServer``, weights made on the device
@@ -185,9 +202,9 @@ def build_server(cell: Cell, seed: int, buckets: List[int],
                  block_kv=int(sv["block_kv"]), remat=False)
     backends, params = {}, {}
     for role_i, role in enumerate(("proxy", "oracle")):
-        m = cfg["models"][role]
-        lm = LM(resolve(model_config(m, cfg["dtype"]), tp=1), rt)
-        p = W.make_params(m, seed, role_i)
+        m, arch = cfg["models"][role], cell.arch[role]
+        lm = LM(resolve(arch.model_config(m, cfg["dtype"]), tp=1), rt)
+        p = arch.make_params(m, seed, role_i)
         W.check_layout(p, jax.eval_shape(lm.init, jax.random.PRNGKey(0)))
         slots = int(sv["arena_slots"][role])
         be = LMBackend(name=role, model=lm, params=p,
@@ -264,7 +281,7 @@ class LaunchLog:
         self.launches: List[Launch] = []
         self._seen: Dict[Tuple[str, int], int] = {}
         self._server, self._sample = server, sample_arena
-        self._models = cell.config["models"]
+        self._models, self._arch = cell.config["models"], cell.arch
         self._classes = int(cell.mix["classes"])
         for be in server.backends.values():
             be.dispatch_group = self._wrap(be, be.dispatch_group)
@@ -288,8 +305,9 @@ class LaunchLog:
         m, req = self._models[model], {}
         for d, prefix, cached in zip(ticket.ids, prefixes, ticket.cached_d):
             done = self._seen.get((model, d), 0)
-            w = WK.stage_work(m, min(max(int(cached), done), prefix), prefix,
-                              int(ticket.op_len), self._classes)
+            w = self._arch[model].stage_work(
+                m, min(max(int(cached), done), prefix), prefix,
+                int(ticket.op_len), self._classes)
             self._seen[(model, d)] = max(done, prefix)
             for k, v in w.items():
                 req[k] = req.get(k, 0) + v

@@ -1,15 +1,18 @@
 """Share of the step programs' device time spent in the op suffix: from
-the start of each ``paged_step`` program's first paged-decode kernel to
-its end (the operation's token-by-token decode, the KV-window restore and
-the class head), over the programs that ran whole inside the trace, each
-matched to its launch (model step, device trace).
+the start of each ``paged_step`` program's first kernel of the operation
+chunk to its end (the operation's extend through the paged flash kernel,
+the undo log's restore and the class head), over the programs that ran
+whole inside the trace, each matched to its launch (model step, device
+trace).
 
 A program is matched to its launch by the server's own ``serve.dispatch``
 and ``serve.sync`` spans (``serve_spans.match_programs``), as the launch
 timeline holds them (``LaunchRecord.ts_enqueue``/``ts_ready``), carried
-onto the trace's clock at the ``bench.window`` span's start.  The first
-decode kernel is found by position: the (L + 1)-th Pallas kernel of a
-launch with new tokens, the first of a decode-only one.
+onto the trace's clock at the ``bench.window`` span's start.  The op
+chunk's first kernel is found by position: the (L + 1)-th Pallas kernel
+of a launch with new tokens, the first of a decode-only one, where L is
+the model's paged flash kernels in one pass (``paged_attention_layers``
+of its ``arch/<model_type>.py``).
 
 The trace's first step program is matched but left out of the share:
 the device can start recording operations after that program began (a
@@ -33,11 +36,14 @@ def read(run):
     recs = sorted(run.launches, key=lambda r: r.index)
     anchors = [SS.LaunchAnchor(i, r.ts_enqueue - t0, r.ts_ready - t0)
                for i, r in enumerate(recs)]
-    models = run.cell.config["models"]
+    models, arch = run.cell.config["models"], run.cell.arch
     pairs = SS.match_programs(programs, anchors)
-    counted = [(pos, models[recs[a.index].model]["num_hidden_layers"],
-                not recs[a.index].decode_only)
-               for a, pos in pairs if pos > 0]
+    counted = []
+    for a, pos in pairs:
+        r = recs[a.index]
+        if pos > 0:
+            layers = arch[r.model].paged_attention_layers(models[r.model])
+            counted.append((pos, layers, not r.decode_only))
     suffix, total, skipped = SS.op_suffix_seconds(
         programs, run.trace.kernel_events, counted)
     unmatched = len(programs) - len(pairs)

@@ -19,12 +19,12 @@ opened, a launch whose program left the trace, a dispatch that raised
 and two launches in flight shift nothing.
 
 Op suffix.  A ``paged_step`` program extends the launch's new tokens
-through the paged flash kernel, once per layer, then decodes the
-operation one token at a time through the paged decode kernel, once per
-layer and token.  Its op-suffix phase runs from the start of its first
-decode kernel (the ``L + 1``-th kernel of a launch with new tokens, the
-first of a decode-only launch, ``L`` the model's layers) to the
-program's end: the decode steps, the KV-window restore and the class
+through the paged flash kernel, once per layer, then the operation's
+tokens, as one chunk, through the same kernel, once per layer.  Its
+op-suffix phase runs from the start of the op chunk's first kernel (the
+``L + 1``-th kernel of a launch with new tokens, the first of a
+decode-only launch, ``L`` the model's paged flash kernels in one pass)
+to the program's end: the op chunk, the undo log's restore and the class
 head.  A program with no kernel past its ``L`` extend kernels (the
 operation extended with the document) has no op suffix.
 """
@@ -78,10 +78,10 @@ def op_suffix_seconds(programs: Sequence[Interval],
                       ) -> Tuple[float, float, int]:
     """(op-suffix seconds, program seconds, programs skipped) over the
     matched programs, each given as (position in ``programs``, the
-    model's layers, whether its launch had new tokens); ``kernels``
-    sorted by start.  A program with fewer kernels than the rule reads
-    (under ``L`` with new tokens, none decode-only) is skipped and
-    counted: the trace lost some of its kernels."""
+    model's paged flash kernels in one pass, whether its launch had new
+    tokens); ``kernels`` sorted by start.  A program with fewer kernels
+    than the rule reads (under ``L`` with new tokens, none decode-only)
+    is skipped and counted: the trace lost some of its kernels."""
     starts = [k[0] for k in kernels]
     suffix = total = 0.0
     skipped = 0
@@ -90,7 +90,7 @@ def op_suffix_seconds(programs: Sequence[Interval],
         inside = [k for k in kernels[bisect.bisect_left(starts, s):
                                      bisect.bisect_right(starts, e)]
                   if k[1] <= e]
-        first = layers if new_tokens else 0     # the first decode kernel
+        first = layers if new_tokens else 0     # the op chunk's first kernel
         if len(inside) < max(first, 1):
             skipped += 1
             continue

@@ -28,7 +28,8 @@ LIMIT = 0.02          # at this size: sound runs read ~1e-3, faults > 0.05
 
 
 def _model(name, layers):
-    return {"name": name, "source": "test", "hidden_size": 256,
+    return {"name": name, "source": "test", "model_type": "qwen3",
+            "hidden_size": 256,
             "intermediate_size": 512, "num_hidden_layers": layers,
             "num_attention_heads": 4, "num_key_value_heads": 2,
             "head_dim": 64, "vocab_size": 1024,
@@ -42,7 +43,8 @@ def root(tmp_path_factory):
     r = tmp_path_factory.mktemp("benchroot")
     (r / "bench" / "configs").mkdir(parents=True)
     (r / "bench" / "traffic").mkdir()
-    shutil.copytree(os.path.join(BENCH, "metrics"), r / "bench" / "metrics")
+    for d in ("arch", "metrics"):
+        shutil.copytree(os.path.join(BENCH, d), r / "bench" / d)
     cfg = {"name": "small", "reduced": [], "dtype": "bfloat16",
            "models": {"proxy": _model("p", 2), "oracle": _model("o", 3)},
            "serving": {"batch": 1, "inflight": 1, "attn_impl": "naive",
@@ -152,11 +154,13 @@ def test_launch_log_counts_the_documents_required_work(root, monkeypatch):
     mix = cell.mix
     stages = [tuple(s) for s in mix["stages"]] + [
         ("oracle", mix["oracle_op"], 1.0)]
+    models = {role: (m, cell.arch[role])
+              for role, m in cell.config["models"].items()}
     want = {}
     for s in run.in_window():
         for model, w in WK.document_work(
-                cell.config["models"], stages, range(s.exit_stage + 1),
-                s.doc.n_tokens, mix["operations"], 2).items():
+                models, stages, range(s.exit_stage + 1), s.doc.n_tokens,
+                mix["operations"], 2).items():
             want[model] = want.get(model, 0) + w["flops"]
     got = {}
     for launch in run.launched_in_window():

@@ -193,9 +193,11 @@ def _rec(index, model, dispatch, sync_end, decode_only=False):
 
 def _run(text, recs):
     from jax.profiler import ProfileData
+    qwen3 = harness.load_arch(os.path.dirname(BENCH), "qwen3")
     cell = SimpleNamespace(config={"models": {
         "proxy": {"num_hidden_layers": 2},
-        "oracle": {"num_hidden_layers": 3}}})
+        "oracle": {"num_hidden_layers": 3}}},
+        arch={"proxy": qwen3, "oracle": qwen3})
     return harness.Run(cell=cell, seconds=1.0, t_open=T0, t_close=T0 + 1,
                        setup_s=0.0, served=[], launches=recs,
                        trace=TRR.reduce_profile(

@@ -97,6 +97,7 @@ def test_harness_finds_new_cell_config_and_metric_by_name(tmp_path):
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "metrics").mkdir()
+    shutil.copytree(os.path.join(BENCH, "arch"), root / "bench" / "arch")
     shutil.copy(os.path.join(BENCH, "configs", "qwen3-0.6b_qwen3-1.7b.json"),
                 root / "bench" / "configs" / "new-pair.json")
     shutil.copy(os.path.join(BENCH, "traffic", "column.json"),
@@ -116,6 +117,8 @@ def test_harness_finds_new_cell_config_and_metric_by_name(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bm))
     cell = harness.load_cell("new-pair.new-mix", str(root))
     assert cell.config["models"]["oracle"]["name"] == "Qwen3-1.7B"
+    assert cell.arch["oracle"].__file__ == str(root / "bench" / "arch" /
+                                               "qwen3.py")
     assert cell.mix["loop"] == "closed"
     assert [m["name"] for m in cell.e2e] == ["setup_s"]
     assert [m["name"] for m in cell.per_layer] == ["new_counter.x"]
