@@ -23,9 +23,11 @@ ATTN_LOCAL = "attn_local"        # sliding-window self-attention
 MLSTM = "mlstm"                  # xLSTM matrix-memory block
 SLSTM = "slstm"                  # xLSTM scalar-memory block
 RGLRU = "rglru"                  # Griffin/RecurrentGemma RG-LRU block
+MAMBA2 = "mamba2"                # Mamba-2 (SSD) selective state-space block
 ENC_ATTN = "enc_attn"            # bidirectional encoder self-attention
 
-VALID_BLOCK_KINDS = {ATTN_FULL, ATTN_LOCAL, MLSTM, SLSTM, RGLRU, ENC_ATTN}
+VALID_BLOCK_KINDS = {ATTN_FULL, ATTN_LOCAL, MLSTM, SLSTM, RGLRU, MAMBA2,
+                     ENC_ATTN}
 
 # Families
 DENSE = "dense"
@@ -88,6 +90,21 @@ class ModelConfig:
     max_seq_len: int = 131_072
     dtype: str = "bfloat16"
     embed_scale: bool = False            # gemma-style sqrt(d) embed multiplier
+    # Granite-style multipliers; each default leaves the graph unchanged.
+    embedding_multiplier: float = 1.0    # embeddings x this
+    residual_multiplier: float = 1.0     # every residual branch x this
+    attention_multiplier: Optional[float] = None  # softmax scale (None:
+    #                                      1/sqrt(head_dim))
+    logits_scaling: float = 1.0          # logits / this
+    use_rope: bool = True                # False: no positions ("nope")
+    # Mamba-2 (MAMBA2 blocks): heads x head_dim = d_inner, one group of
+    # ``ssm_state`` B/C channels, a width-``ssm_conv`` causal conv over
+    # x|B|C, chunked SSD scan of ``ssm_chunk`` tokens
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
     # Pad KV heads up to the TP width so decode KV caches shard cleanly over
     # the model axis (DESIGN.md §5).  MQA archs (kv=1) set False and keep a
     # replicated KV with sequence-sharded flash-decode for long contexts.
@@ -238,5 +255,10 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         small["mrope_sections"] = (half - 2 * hw, hw, hw)
     if cfg.frontend_len:
         small["frontend_len"] = 8
+    if cfg.ssm_heads:
+        # d_inner 256 = expand 2 at d_model 128; chunk 16 so a few dozen
+        # tokens span several chunks
+        small.update(ssm_heads=8, ssm_head_dim=32, ssm_state=16,
+                     ssm_chunk=16)
     small.update(overrides)
     return dataclasses.replace(cfg, **small)

@@ -22,6 +22,7 @@ ARCHS: List[str] = [
     "whisper_base",
     "xlstm_350m",
     "recurrentgemma_2b",
+    "granite_4_0_h_micro",
 ]
 
 # public ids (dashes) -> module names
@@ -36,6 +37,7 @@ ALIASES: Dict[str, str] = {
     "whisper-base": "whisper_base",
     "xlstm-350m": "xlstm_350m",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

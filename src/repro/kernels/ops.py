@@ -29,6 +29,7 @@ from . import sanitize
 from .flash_attention import flash_attention_pallas, paged_flash_attention_pallas
 from .decode_attention import decode_attention_pallas, paged_decode_attention_pallas
 from .relevance_score import relevance_score_pallas
+from .ssd_scan import ssd_chunk_scan_pallas
 
 DEFAULT_IMPL = "xla"
 
@@ -518,3 +519,82 @@ def relevance_score(
             interpret=(impl == "pallas_interpret"),
         )
     raise ValueError(f"unknown relevance impl {impl!r}")
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 SSD chunk scan
+# ---------------------------------------------------------------------------
+
+def _ssd_xla(x, dt, A, Bm, Cm, h0, length, chunk: int):
+    """The chunked SSD form of ``ssd_scan`` in jnp, one chunk per
+    ``lax.scan`` step (float32 throughout; differentiable)."""
+    f32 = jnp.float32
+    B, S, H, P = x.shape
+    L = chunk
+    nc = S // L
+    pos = jnp.arange(S)[None, :, None]
+    dt = jnp.where(pos < length[:, None, None], dt.astype(f32), 0.0)
+
+    def chunks(a):
+        return jnp.moveaxis(a.astype(f32).reshape((B, nc, L) + a.shape[2:]),
+                            1, 0)
+
+    tri = jnp.tril(jnp.ones((L, L), bool))[None, :, :, None]   # [1,t,s,1]
+
+    def step(h, xs):
+        xc, dc, bc, cc = xs          # [B,L,H,P], [B,L,H], [B,L,N], [B,L,N]
+        cs = jnp.cumsum(dc * A.astype(f32), axis=1)            # [B,L,H]
+        g = jnp.einsum("btn,bsn->bts", cc, bc)
+        decay = jnp.exp(jnp.where(tri, cs[:, :, None] - cs[:, None], -jnp.inf))
+        m = g[..., None] * decay * dc[:, None]                 # [B,t,s,H]
+        y = jnp.einsum("btsh,bshp->bthp", m, xc)
+        y = y + jnp.exp(cs)[..., None] * jnp.einsum("btn,bhpn->bthp", cc, h)
+        w = jnp.exp(cs[:, -1:] - cs) * dc                      # [B,s,H]
+        h = (jnp.exp(cs[:, -1])[..., None, None] * h
+             + jnp.einsum("bsh,bshp,bsn->bhpn", w, xc, bc))
+        return h, y
+
+    h, ys = jax.lax.scan(step, h0.astype(f32),
+                         (chunks(x), chunks(dt), chunks(Bm), chunks(Cm)))
+    return jnp.moveaxis(ys, 0, 1).reshape(B, S, H, P).astype(x.dtype), h
+
+
+def ssd_scan(
+    x: jnp.ndarray,               # [B, S, H, P]
+    dt: jnp.ndarray,              # [B, S, H] float32, >= 0
+    A: jnp.ndarray,               # [H] float32, < 0
+    Bm: jnp.ndarray,              # [B, S, N]
+    Cm: jnp.ndarray,              # [B, S, N]
+    h0: jnp.ndarray,              # [B, H, P, N] float32
+    length: jnp.ndarray,          # [B] int32 true tokens of each row
+    *,
+    chunk: int = 256,
+    impl: str = DEFAULT_IMPL,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One Mamba-2 layer's selective scan over a chunk of ``S`` tokens,
+    resumed from ``h0``: -> (y [B, S, H, P] in x's dtype, final state
+    [B, H, P, N] float32).  Positions at or past ``length[b]`` take dt =
+    0, so the final state is the one row ``b``'s last true token left.
+
+    ``pallas``/``pallas_interpret`` run ``ssd_scan.ssd_chunk_scan_pallas``
+    (chunks of ``chunk`` tokens; on the chip a shorter ``S`` runs as one
+    chunk rounded up to 128 lanes), ``xla`` the same chunked form in
+    jnp, ``naive`` the sequential recurrence of ``ref.ssd_reference``.
+    A ragged ``S`` is padded up to whole chunks; padded positions lie
+    past every length."""
+    if impl == "naive":
+        y, h = ref.ssd_reference(x, dt, A, Bm, Cm, h0, length)
+        return y.astype(x.dtype), h
+    if impl not in ("xla", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown ssd impl {impl!r}")
+    S = x.shape[1]
+    L = min(chunk, S if impl != "pallas" else _round_up(S, 128))
+    Sp = _round_up(S, L)
+    x, dt, Bm, Cm = (_pad_axis(a, 1, Sp) for a in (x, dt, Bm, Cm))
+    if impl == "xla":
+        y, h = _ssd_xla(x, dt, A, Bm, Cm, h0, length, L)
+        return y[:, :S], h
+    y, h = ssd_chunk_scan_pallas(
+        jnp.swapaxes(x, 1, 2), jnp.swapaxes(dt, 1, 2), A, Bm, Cm, h0,
+        length, chunk=L, interpret=(impl == "pallas_interpret"))
+    return jnp.swapaxes(y, 1, 2)[:, :S], h

@@ -95,3 +95,33 @@ def relevance_reference(
     pooled = summed / denom
     logit = pooled @ w.astype(jnp.float32) + b.astype(jnp.float32)
     return jax.nn.sigmoid(logit)
+
+
+def ssd_reference(
+    x: jnp.ndarray,             # [B, S, H, P]
+    dt: jnp.ndarray,            # [B, S, H] (>= 0)
+    A: jnp.ndarray,             # [H] (< 0)
+    Bm: jnp.ndarray,            # [B, S, N]
+    Cm: jnp.ndarray,            # [B, S, N]
+    h0: jnp.ndarray,            # [B, H, P, N]
+    length: jnp.ndarray,        # [B] true tokens
+):
+    """The Mamba-2 selective scan one token at a time, in float32:
+    state = exp(dt A) state + dt x (outer) B, y = state . C, with dt = 0 at
+    and past each row's true length.  -> (y [B, S, H, P], state)."""
+    f32 = jnp.float32
+    S = x.shape[1]
+    dt = jnp.where(jnp.arange(S)[None, :, None] < length[:, None, None],
+                   dt.astype(f32), 0.0)
+
+    def step(h, xs):
+        xt, dtt, bt, ct = xs                 # [B,H,P], [B,H], [B,N], [B,N]
+        h = (jnp.exp(dtt * A.astype(f32))[..., None, None] * h
+             + (dtt[..., None] * xt)[..., None] * bt[:, None, None, :])
+        return h, jnp.einsum("bhpn,bn->bhp", h, ct)
+
+    xs = (jnp.moveaxis(x.astype(f32), 1, 0), jnp.moveaxis(dt, 1, 0),
+          jnp.moveaxis(Bm.astype(f32), 1, 0),
+          jnp.moveaxis(Cm.astype(f32), 1, 0))
+    h, ys = jax.lax.scan(step, h0.astype(f32), xs)
+    return jnp.moveaxis(ys, 0, 1), h

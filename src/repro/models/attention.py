@@ -143,12 +143,15 @@ def attention_apply(
     qk_norm: bool = False,
     theta: float = 10_000.0,
     norm_eps: float = 1e-6,
-    use_rope: bool = True,           # whisper uses absolute sinusoids instead
+    use_rope: bool = True,           # whisper uses absolute sinusoids
+                                     # instead; granite uses no positions
+    sm_scale: Optional[float] = None,  # None: 1/sqrt(head_dim)
     kv_ctx: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # cross-attn K,V
 ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     B, S, D = x.shape
     dh = p["wq"].shape[-1]
-    sm_scale = 1.0 / math.sqrt(dh)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(dh)
 
     if kv_ctx is not None:
         # cross attention (whisper decoder): kv precomputed from encoder

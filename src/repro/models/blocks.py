@@ -1,7 +1,8 @@
 """Block layer: sequence mixer (+ optional FFN) with pre-norms.
 
 A block is one transformer-ish layer of a given *kind* (config.py constants):
-attention (full / sliding-window / bidirectional), mLSTM, sLSTM, or RG-LRU.
+attention (full / sliding-window / bidirectional), mLSTM, sLSTM, RG-LRU or
+Mamba-2.
 Every block exposes the same functional surface —
 
     init_block / spec_block                   parameters
@@ -18,8 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..config import (ATTN_FULL, ATTN_LOCAL, ENC_ATTN, MLSTM, RGLRU, SLSTM,
-                      ResolvedConfig)
+from ..config import (ATTN_FULL, ATTN_LOCAL, ENC_ATTN, MAMBA2, MLSTM, RGLRU,
+                      SLSTM, ResolvedConfig)
 from . import ssm
 from .attention import (attention_apply, init_attention, init_kv_cache,
                         kv_cache_shape, spec_attention, spec_kv_cache)
@@ -37,6 +38,11 @@ def _has_ffn(rcfg: ResolvedConfig) -> bool:
 
 def _lru_width(rcfg: ResolvedConfig) -> int:
     return rcfg.base.d_model  # Griffin uses lru_width == d_model for 2b
+
+
+def _mamba2_sizes(rcfg: ResolvedConfig) -> Tuple[int, int, int, int]:
+    b = rcfg.base
+    return b.ssm_heads, b.ssm_head_dim, b.ssm_state, b.ssm_conv
 
 
 # ---------------------------------------------------------------------------
@@ -58,6 +64,8 @@ def init_block(rng, rcfg: ResolvedConfig, kind: str, dtype=jnp.bfloat16):
         p["slstm"] = ssm.init_slstm(k1, d, b.num_heads, dtype)
     elif kind == RGLRU:
         p["rglru"] = ssm.init_rglru(k1, d, _lru_width(rcfg), dtype)
+    elif kind == MAMBA2:
+        p["mamba"] = ssm.init_mamba2(k1, d, *_mamba2_sizes(rcfg), dtype)
     else:
         raise ValueError(kind)
     if _has_ffn(rcfg):
@@ -81,6 +89,8 @@ def spec_block(rcfg: ResolvedConfig, kind: str):
         s["slstm"] = ssm.spec_slstm()
     elif kind == RGLRU:
         s["rglru"] = ssm.spec_rglru()
+    elif kind == MAMBA2:
+        s["mamba"] = ssm.spec_mamba2()
     if _has_ffn(rcfg):
         s["norm2"] = spec_rmsnorm()
         if b.moe is not None:
@@ -116,6 +126,8 @@ def init_block_state(rcfg: ResolvedConfig, kind: str, batch: int,
         return ssm.init_slstm_state(batch, b.d_model)
     if kind == RGLRU:
         return ssm.init_rglru_state(batch, _lru_width(rcfg))
+    if kind == MAMBA2:
+        return ssm.init_mamba2_state(batch, *_mamba2_sizes(rcfg), dtype)
     raise ValueError(kind)
 
 
@@ -132,6 +144,8 @@ def block_state_shape(rcfg: ResolvedConfig, kind: str, batch: int,
         return ssm.slstm_state_shape(batch, b.d_model)
     if kind == RGLRU:
         return ssm.rglru_state_shape(batch, _lru_width(rcfg))
+    if kind == MAMBA2:
+        return ssm.mamba2_state_shape(batch, *_mamba2_sizes(rcfg), dtype)
     raise ValueError(kind)
 
 
@@ -155,6 +169,8 @@ def spec_block_state(rcfg: ResolvedConfig, kind: str, *, batch_sharded: bool,
         s = ssm.spec_slstm_state()
     elif kind == RGLRU:
         s = ssm.spec_rglru_state()
+    elif kind == MAMBA2:
+        s = ssm.spec_mamba2_state()
     else:
         raise ValueError(kind)
     if not batch_sharded:
@@ -187,11 +203,22 @@ def block_apply(
     positions: Optional[jnp.ndarray] = None,
     positions3: Optional[jnp.ndarray] = None,
     dp_spec=None,
+    commit_recurrent: bool = True,
 ) -> Tuple[jnp.ndarray, Optional[Any], jnp.ndarray]:
-    """Returns (y, new_state, moe_aux_loss)."""
+    """Returns (y, new_state, moe_aux_loss).
+
+    Recurrent kinds: an extend at offset 0 (no ``q_start``) starts a new
+    sequence, so it starts from the initial state whatever ``state``
+    holds (a recycled arena row's previous document).  Mamba-2 also
+    addresses arena rows by ``slots``, masks each row to its true length
+    and, with ``commit_recurrent`` False, leaves ``state`` as it came in
+    (the serving op suffix reads the document's state, never writes
+    it)."""
     b = rcfg.base
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm_apply(p["norm1"], x, b.norm_eps)
+    fresh = (kind not in _ATTN_KINDS and mode == "extend" and q_offset == 0
+             and q_start is None)
 
     if kind in _ATTN_KINDS:
         attn_mode = {"train": "full", "prefill": "full",
@@ -199,33 +226,62 @@ def block_apply(
         window = b.sliding_window if kind == ATTN_LOCAL else None
         assert slots is None or (kind == ATTN_FULL and window is None), \
             "paged serving (slots) supports full-attention blocks only"
-        mix, new_state = attention_apply(
-            p["attn"], h,
-            rt=rt,
-            mode=attn_mode,
-            causal=(kind != ENC_ATTN),
-            window=window,
-            positions=positions,
-            positions3=positions3,
-            mrope_sections=b.mrope_sections,
-            cache=state,
-            cache_len=cache_len,
-            q_offset=q_offset,
-            kv_len=kv_len,
-            q_start=q_start,
-            slots=slots,
-            block_tables=block_tables,
-            want_cache=(mode != "train"),
-            qk_norm=b.qk_norm,
-            theta=b.rope_theta,
-            norm_eps=b.norm_eps,
-        )
+        with jax.named_scope("attention"):
+            mix, new_state = attention_apply(
+                p["attn"], h,
+                rt=rt,
+                mode=attn_mode,
+                causal=(kind != ENC_ATTN),
+                window=window,
+                positions=positions,
+                positions3=positions3,
+                mrope_sections=b.mrope_sections,
+                cache=state,
+                cache_len=cache_len,
+                q_offset=q_offset,
+                kv_len=kv_len,
+                q_start=q_start,
+                slots=slots,
+                block_tables=block_tables,
+                want_cache=(mode != "train"),
+                qk_norm=b.qk_norm,
+                theta=b.rope_theta,
+                norm_eps=b.norm_eps,
+                sm_scale=b.attention_multiplier,
+                use_rope=b.use_rope,
+            )
+    elif kind == MAMBA2:
+        n_true = None
+        if mode == "extend" and kv_len is not None:
+            start = q_offset if q_start is None else q_start
+            n_true = jnp.clip(kv_len - start, 0, x.shape[1])
+        if fresh or state is None:
+            st = init_block_state(rcfg, kind, x.shape[0], 1, x.dtype)
+        elif slots is None:
+            st = state
+        else:
+            st = jax.tree.map(lambda a: a[slots], state)
+        impl = rt.attn_impl if rt.attn_impl in (
+            "pallas", "pallas_interpret", "naive") else "xla"
+        with jax.named_scope("mamba2"):
+            mix, rows = ssm.mamba2_apply(
+                p["mamba"], h, state=st,
+                mode=("step" if mode == "decode" else "full"),
+                n_true=n_true, chunk=b.ssm_chunk, impl=impl,
+                eps=b.norm_eps)
+        if not commit_recurrent:
+            new_state = state
+        elif slots is None:
+            new_state = rows
+        else:
+            new_state = jax.tree.map(
+                lambda a, r: a.at[slots].set(r.astype(a.dtype)), state, rows)
     elif kind == MLSTM:
         assert slots is None and q_start is None, \
             "paged serving (slots) and ragged-start extend (q_start) " \
             "support attention-state models only"
         mix, new_state = ssm.mlstm_apply(
-            p["mlstm"], h, state=state,
+            p["mlstm"], h, state=(None if fresh else state),
             mode=("step" if mode == "decode" else "full"),
             heads=b.num_heads)
     elif kind == SLSTM:
@@ -233,18 +289,19 @@ def block_apply(
             "paged serving (slots) and ragged-start extend (q_start) " \
             "support attention-state models only"
         mix, new_state = ssm.slstm_apply(
-            p["slstm"], h, state=state, heads=b.num_heads)
+            p["slstm"], h, state=(None if fresh else state), heads=b.num_heads)
     elif kind == RGLRU:
         assert slots is None and q_start is None, \
             "paged serving (slots) and ragged-start extend (q_start) " \
             "support attention-state models only"
         mix, new_state = ssm.rglru_apply(
-            p["rglru"], h, state=state,
+            p["rglru"], h, state=(None if fresh else state),
             mode=("step" if mode == "decode" else "full"))
     else:
         raise ValueError(kind)
 
-    x = x + mix
+    rm = b.residual_multiplier
+    x = x + (mix if rm == 1.0 else mix * jnp.asarray(rm, mix.dtype))
     if mode == "train":
         new_state = None
 
@@ -259,5 +316,5 @@ def block_apply(
                 mesh=rt.mesh, dp_spec=dp_spec)
         else:
             y = mlp_apply(p["mlp"], h2, b.act)
-        x = x + y
+        x = x + (y if rm == 1.0 else y * jnp.asarray(rm, y.dtype))
     return x, new_state, aux

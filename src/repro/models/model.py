@@ -23,6 +23,7 @@ vision frontend — which is prepended to the text token embeddings, and
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -30,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..config import ATTN_FULL, ATTN_LOCAL, ResolvedConfig
+from ..config import ATTN_FULL, ATTN_LOCAL, MAMBA2, ResolvedConfig
 from ..distributed.sharding import batch_pspec, constrain
 from . import blocks
 from .attention import chunk_positions
@@ -171,13 +172,41 @@ class LM:
 
     @property
     def supports_paged_kv(self) -> bool:
-        """True when every layer's serve-state is a full-attention KV
-        cache, i.e. the slot arena can be addressed IN PLACE by the paged
-        kernels (``slots=`` on ``extend``/``decode_step``) and the
-        KV-window helpers below are meaningful.  Sliding-window ring
-        caches and recurrent (xLSTM/RG-LRU) states still require the
+        """True when the slot arena can be addressed IN PLACE (``slots=``
+        on ``extend``/``decode_step``): every layer's serve-state is a
+        full-attention KV cache, read by the paged kernels, or a Mamba-2
+        state, taken and put by slot (O(1) a row).  Sliding-window ring
+        caches and the xLSTM/RG-LRU states still require the
         gather/scatter path."""
-        return all(k == ATTN_FULL for k in self.rcfg.base.layer_kinds())
+        return all(k in (ATTN_FULL, MAMBA2)
+                   for k in self.rcfg.base.layer_kinds())
+
+    def _entries(self, states):
+        """(kind, path, entry) of each layer entry of a state pytree:
+        ``("stages", i)`` entries carry the repetition axis first."""
+        for i, kind in enumerate(self.pattern):
+            yield kind, ("stages", i), states["stages"][i]
+        for i, kind in enumerate(self.tail_kinds):
+            yield kind, ("tail", i), states["tail"][i]
+
+    def _map_entries(self, states, fn):
+        """``states`` with each layer entry replaced by ``fn(kind, path,
+        entry)``."""
+        out = {"stages": list(states["stages"]), "tail": list(states["tail"])}
+        for kind, path, entry in self._entries(states):
+            out[path[0]][path[1]] = fn(kind, path, entry)
+        return {"stages": tuple(out["stages"]), "tail": tuple(out["tail"])}
+
+    def state_nbytes(self, states) -> Dict[str, int]:
+        """Bytes of a state pytree (arrays or shapes) by kind: ``kv``
+        (attention caches, which grow with the sequence) and
+        ``recurrent`` (fixed-size states)."""
+        out = {"kv": 0, "recurrent": 0}
+        for kind, _, entry in self._entries(states):
+            key = "kv" if kind in (ATTN_FULL, ATTN_LOCAL) else "recurrent"
+            out[key] += sum(int(math.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
+                            for l in jax.tree.leaves(entry))
+        return out
 
     def _kv_window_idx(self, slots: jnp.ndarray, start: jnp.ndarray,
                        length: int):
@@ -187,18 +216,21 @@ class LM:
                        start: jnp.ndarray, length: int):
         """Gather cache rows [start[b], start[b]+length) of every KV leaf
         at arena rows ``slots`` -> a tiny [B, length, KV, Dh]-per-leaf
-        pytree.  With ``put_kv_window`` this is the paged op-suffix UNDO
-        LOG: the serving engine snapshots the ``length`` cache positions
-        an operation suffix will dirty, runs it in place, then restores —
-        O(B * op_len) bytes instead of the full [B, S] row copy.  Only
-        valid for ``supports_paged_kv`` models (every leaf is a KV cache
-        whose sequence axis follows the batch axis)."""
+        pytree (None for a recurrent layer).  With ``put_kv_window`` this
+        is the paged op-suffix UNDO LOG: the serving engine snapshots the
+        ``length`` cache positions an operation suffix will dirty, runs it
+        in place, then restores — O(B * op_len) bytes instead of the full
+        [B, S] row copy.  Recurrent states need no entry: the op suffix
+        does not write them (``extend(commit_recurrent=False)``).  Only
+        valid for ``supports_paged_kv`` models."""
         si, win = self._kv_window_idx(slots, start, length)
-        flat, treedef = jax.tree_util.tree_flatten_with_path(states)
-        out = [leaf[si, win] if self._state_batch_axis(path) == 0
-               else leaf[:, si, win]
-               for path, leaf in flat]
-        return jax.tree_util.tree_unflatten(treedef, out)
+
+        def take(kind, path, entry):
+            if kind != ATTN_FULL:
+                return None
+            return jax.tree.map(lambda l: (l[:, si, win] if path[0] ==
+                                           "stages" else l[si, win]), entry)
+        return self._map_entries(states, take)
 
     def put_kv_window(self, states, slots: jnp.ndarray,
                       start: jnp.ndarray, length: int, window):
@@ -207,15 +239,15 @@ class LM:
         duplicate wins is unspecified — scratch contents are never read
         unmasked."""
         si, win = self._kv_window_idx(slots, start, length)
-        flat, treedef = jax.tree_util.tree_flatten_with_path(states)
-        subs = jax.tree.leaves(window)
-        out = []
-        for (path, leaf), sub in zip(flat, subs):
-            if self._state_batch_axis(path) == 0:
-                out.append(leaf.at[si, win].set(sub))
-            else:
-                out.append(leaf.at[:, si, win].set(sub))
-        return jax.tree_util.tree_unflatten(treedef, out)
+        saved = {path: entry for _, path, entry in self._entries(window)}
+
+        def put(kind, path, entry):
+            if kind != ATTN_FULL:
+                return entry
+            return jax.tree.map(
+                lambda l, w: (l.at[:, si, win].set(w) if path[0] == "stages"
+                              else l.at[si, win].set(w)), entry, saved[path])
+        return self._map_entries(states, put)
 
     def state_specs(self, *, batch_sharded: bool, seq_sharded: bool):
         def with_lead(tree):
@@ -259,14 +291,30 @@ class LM:
         if b.frontend_stub == "audio_frames" and "frame_emb" in batch:
             x = jnp.concatenate(
                 [batch["frame_emb"].astype(self.dtype), x], axis=1)
+        return self._scale_embed(x)
+
+    def _scale_embed(self, x):
+        b = self.rcfg.base
         if getattr(b, "embed_scale", False):
             x = x * jnp.asarray(b.d_model ** 0.5, self.dtype)
+        if b.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(b.embedding_multiplier, self.dtype)
         return x
+
+    def _head(self, params, x):
+        """Final norm and tied head: logits [..., V] (float32)."""
+        b = self.rcfg.base
+        x = rmsnorm_apply(params["final_norm"], x, b.norm_eps)
+        logits = lm_head_apply(params["embed"], x, b.logit_softcap)
+        if b.logits_scaling != 1.0:
+            logits = logits / b.logits_scaling
+        return logits
 
     # ------------------------------------------------------------------ core
     def _run_blocks(self, params, x, *, mode, states=None, cache_len=None,
                     q_offset=0, kv_len=None, q_start=None, slots=None,
-                    block_tables=None, positions=None, positions3=None):
+                    block_tables=None, positions=None, positions3=None,
+                    commit_recurrent=True):
         rcfg, rt = self.rcfg, self.rt
         dp_spec = self._dp_spec()
         pattern = self.pattern
@@ -284,7 +332,7 @@ class LM:
                     q_offset=q_offset, kv_len=kv_len, q_start=q_start,
                     slots=slots, block_tables=block_tables,
                     positions=positions, positions3=positions3,
-                    dp_spec=dp_spec)
+                    dp_spec=dp_spec, commit_recurrent=commit_recurrent)
                 x = self._constrain_act(x)
                 new_states.append(ns)
                 aux = aux + a
@@ -334,7 +382,8 @@ class LM:
                 mode=mode, state=st, cache_len=cache_len, q_offset=q_offset,
                 kv_len=kv_len, q_start=q_start, slots=slots,
                 block_tables=block_tables,
-                positions=positions, positions3=positions3, dp_spec=dp_spec)
+                positions=positions, positions3=positions3, dp_spec=dp_spec,
+                commit_recurrent=commit_recurrent)
             x = self._constrain_act(x)
             new_tail.append(ns)
             aux = aux + a
@@ -356,9 +405,7 @@ class LM:
         x, _, aux = self._run_blocks(
             params, x, mode="train", positions=positions,
             positions3=batch.get("positions3"))
-        x = rmsnorm_apply(params["final_norm"], x, self.rcfg.base.norm_eps)
-        logits = lm_head_apply(params["embed"], x, self.rcfg.base.logit_softcap)
-        return logits, aux
+        return self._head(params, x), aux
 
     def loss(self, params, batch: Dict[str, jnp.ndarray]):
         """Mean next-token xent (+ MoE aux).  ``labels`` [B, S_total]."""
@@ -390,17 +437,14 @@ class LM:
             x, new_states, _ = self._run_blocks(
                 params, x, mode="prefill", positions=positions,
                 positions3=batch.get("positions3"))
-        x = rmsnorm_apply(params["final_norm"], x[:, -1:],
-                          self.rcfg.base.norm_eps)
-        logits = lm_head_apply(params["embed"], x,
-                               self.rcfg.base.logit_softcap)[:, 0]
-        return logits, new_states
+        return self._head(params, x[:, -1:])[:, 0], new_states
 
     def extend(self, params, batch: Dict[str, jnp.ndarray], states,
                q_offset: int, kv_len: Optional[jnp.ndarray] = None,
                slots: Optional[jnp.ndarray] = None,
                block_tables: Optional[jnp.ndarray] = None,
-               q_start: Optional[jnp.ndarray] = None):
+               q_start: Optional[jnp.ndarray] = None,
+               commit_recurrent: bool = True):
         """Cascade fraction-extension: new tokens at [q_offset, q_offset+S).
 
         ``kv_len`` [B] is the TRUE (unpadded) sequence length including this
@@ -420,21 +464,26 @@ class LM:
         ``block_tables[b, j]`` instead of ``slots[b]`` — the prefix-sharing
         indirection.  Writes still land in row ``slots[b]``.
 
-        ``q_start`` [B] (traced; models whose layers are all full
-        attention) gives each row its own start: row ``b``'s chunk takes
-        positions [q_start[b], q_start[b]+S), its KV is written there and
-        ``kv_len`` defaults to ``q_start + S``.  ``q_offset`` then bounds
-        every start from above and fixes the keys attended, [0, q_offset +
-        S).  An unpadded chunk's last position is then every row's true
-        last token, so the returned logits are each row's own — the
-        serving engine runs a whole operation suffix this way in one pass.
+        ``q_start`` [B] (traced; ``supports_paged_kv`` models) gives each
+        row its own start: row ``b``'s chunk takes positions [q_start[b],
+        q_start[b]+S), its KV is written there and ``kv_len`` defaults to
+        ``q_start + S``.  ``q_offset`` then bounds every start from above
+        and fixes the keys attended, [0, q_offset + S).  An unpadded
+        chunk's last position is then every row's true last token, so the
+        returned logits are each row's own — the serving engine runs a
+        whole operation suffix this way in one pass.
+
+        Recurrent (Mamba-2) layers resume each row from the state
+        ``states`` holds for it, masked to ``kv_len``; an extend at
+        ``q_offset`` 0 (no ``q_start``) starts from the initial state.
+        ``commit_recurrent`` False returns their states as they came in.
         """
         x = self.embed_inputs(params, batch)
         B, S, _ = x.shape
         positions = q_offset + jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         if q_start is not None:
             assert self.supports_paged_kv, \
-                "ragged-start extend needs every layer to be full attention"
+                "ragged-start extend needs full-attention or Mamba-2 layers"
             positions = chunk_positions(q_start, S)
             if kv_len is None:
                 kv_len = q_start + S
@@ -443,12 +492,9 @@ class LM:
             kv_len=kv_len, q_start=q_start, slots=slots,
             block_tables=block_tables, positions=positions,
             positions3=batch.get("positions3"),
-            cache_len=jnp.full((B,), q_offset, jnp.int32))
-        x = rmsnorm_apply(params["final_norm"], x[:, -1:],
-                          self.rcfg.base.norm_eps)
-        logits = lm_head_apply(params["embed"], x,
-                               self.rcfg.base.logit_softcap)[:, 0]
-        return logits, new_states
+            cache_len=jnp.full((B,), q_offset, jnp.int32),
+            commit_recurrent=commit_recurrent)
+        return self._head(params, x[:, -1:])[:, 0], new_states
 
     def decode_step(self, params, tokens: jnp.ndarray, states,
                     pos: jnp.ndarray, slots: Optional[jnp.ndarray] = None,
@@ -462,9 +508,8 @@ class LM:
         ``take_kv_window``/``put_kv_window``).  ``block_tables``
         [B, nblocks] redirects cache READS per block (prefix sharing);
         the written token still lands in ``slots[b]``."""
-        x = embed_apply(params["embed"], tokens[:, None]).astype(self.dtype)
-        if getattr(self.rcfg.base, "embed_scale", False):
-            x = x * jnp.asarray(self.rcfg.base.d_model ** 0.5, self.dtype)
+        x = self._scale_embed(
+            embed_apply(params["embed"], tokens[:, None]).astype(self.dtype))
         positions = pos[:, None]
         positions3 = None
         if self.rcfg.base.mrope_sections is not None:
@@ -474,10 +519,7 @@ class LM:
             params, x, mode="decode", states=states, cache_len=pos,
             slots=slots, block_tables=block_tables, positions=positions,
             positions3=positions3)
-        x = rmsnorm_apply(params["final_norm"], x, self.rcfg.base.norm_eps)
-        logits = lm_head_apply(params["embed"], x,
-                               self.rcfg.base.logit_softcap)[:, 0]
-        return logits, new_states
+        return self._head(params, x)[:, 0], new_states
 
 
 def _dp_size(mesh) -> int:

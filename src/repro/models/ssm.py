@@ -397,3 +397,144 @@ def rglru_apply(p, x, *, state=None, mode: str = "full"):
 
     y = (hs * gate).astype(x.dtype) @ p["w_out"]
     return y, {"h": h, "conv": conv_state}
+
+
+# ===========================================================================
+# Mamba-2 (SSD)
+# ===========================================================================
+#
+# One layer (Dao & Gu 2024, arXiv:2405.21060; Hugging Face ``Mamba2Mixer``
+# with one group): in_proj -> z | xBC | dt; a width-K depthwise causal
+# conv with bias over xBC, SiLU; x, B, C split from it; dt = softplus(dt
+# + dt_bias), A = -exp(A_log); the selective scan (``kernels.ops.
+# ssd_scan``) plus D * x; y * SiLU(z) through an RMSNorm over d_inner;
+# out_proj.  The serve state is the conv's last K-1 inputs and the scan
+# state [H, P, N] in float32.
+
+def mamba2_sizes(p) -> Tuple[int, int, int, int]:
+    """(heads, head_dim, d_state, conv width) from a layer's params."""
+    H = p["A_log"].shape[-1]
+    d_inner = p["out_proj"].shape[-2]
+    conv_dim = p["conv_w"].shape[-1]
+    return H, d_inner // H, (conv_dim - d_inner) // 2, p["conv_w"].shape[-2]
+
+
+def init_mamba2(rng, d: int, heads: int, head_dim: int, d_state: int,
+                conv: int, dtype=jnp.bfloat16):
+    """Mamba-2's published initialisation: A in [1, 16], dt in [0.001,
+    0.1] (log-uniform, stored as softplus^-1), D = 1."""
+    ks = jax.random.split(rng, 5)
+    d_inner = heads * head_dim
+    conv_dim = d_inner + 2 * d_state
+    dt = jnp.exp(jax.random.uniform(ks[2], (heads,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    return {
+        "in_proj": _dense_init(ks[0], (d, d_inner + conv_dim + heads), d,
+                               dtype),
+        "conv_w": (jax.random.normal(ks[1], (conv, conv_dim), jnp.float32)
+                   * (1.0 / math.sqrt(conv))).astype(dtype),
+        "conv_b": jnp.zeros((conv_dim,), dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (heads,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((heads,), jnp.float32),
+        "norm": {"scale": jnp.ones((d_inner,), jnp.float32)},
+        "out_proj": _dense_init(ks[4], (d_inner, d), d_inner, dtype),
+    }
+
+
+def spec_mamba2():
+    return {"in_proj": (None, None), "conv_w": (None, None),
+            "conv_b": (None,), "dt_bias": (None,), "A_log": (None,),
+            "D": (None,), "norm": {"scale": (None,)},
+            "out_proj": (None, None)}
+
+
+def mamba2_state_shape(batch: int, heads: int, head_dim: int, d_state: int,
+                       conv: int, dtype=jnp.bfloat16):
+    return {
+        "conv": jax.ShapeDtypeStruct(
+            (batch, conv - 1, heads * head_dim + 2 * d_state), dtype),
+        "ssm": jax.ShapeDtypeStruct((batch, heads, head_dim, d_state),
+                                    jnp.float32),
+    }
+
+
+def init_mamba2_state(batch: int, heads: int, head_dim: int, d_state: int,
+                      conv: int, dtype=jnp.bfloat16):
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        mamba2_state_shape(batch, heads, head_dim, d_state,
+                                           conv, dtype))
+
+
+def spec_mamba2_state():
+    return {"conv": ("dp", None, None), "ssm": ("dp", None, None, None)}
+
+
+def mamba2_apply(p, x, *, state, mode: str = "full",
+                 n_true: Optional[jnp.ndarray] = None, chunk: int = 256,
+                 impl: str = "xla", eps: float = 1e-5):
+    """Mamba-2 mixer over ``x`` [B, T, D], resumed from ``state``.
+
+    mode "full": the chunked scan; ``n_true`` [B] (None: all T) is each
+    row's true tokens in this chunk: dt is 0 past it and the conv state
+    is taken from the last true inputs, so bucket PAD never enters the
+    state.  mode "step": T == 1, the one-token recurrence in jnp.
+    Returns (y [B, T, D], new state)."""
+    from ..kernels import ops          # deferred: keep the import DAG flat
+    B, T, _ = x.shape
+    H, P, N, K = mamba2_sizes(p)
+    d_inner = H * P
+    f32 = jnp.float32
+    zxbcdt = x @ p["in_proj"]
+    z = zxbcdt[..., :d_inner]
+    xbc = zxbcdt[..., d_inner:2 * d_inner + 2 * N]
+    dt = jax.nn.softplus(zxbcdt[..., 2 * d_inner + 2 * N:].astype(f32)
+                         + p["dt_bias"])                       # [B, T, H]
+    hist = jnp.concatenate([state["conv"].astype(f32), xbc.astype(f32)], 1)
+    w = p["conv_w"].astype(f32)
+    conv = p["conv_b"].astype(f32) + sum(hist[:, k:k + T] * w[k]
+                                         for k in range(K))
+    if n_true is None:
+        conv_state = hist[:, T:]
+    else:
+        idx = n_true.astype(jnp.int32)[:, None] + jnp.arange(K - 1)[None]
+        conv_state = jnp.take_along_axis(hist, idx[..., None], axis=1)
+    xbc = jax.nn.silu(conv).astype(x.dtype)
+    xs = xbc[..., :d_inner].reshape(B, T, H, P)
+    bm, cm = xbc[..., d_inner:d_inner + N], xbc[..., d_inner + N:]
+    A = -jnp.exp(p["A_log"])
+    h0 = state["ssm"]
+    if mode == "step":
+        dA = jnp.exp(dt[:, 0] * A)                             # [B, H]
+        h = (dA[..., None, None] * h0
+             + (dt[:, 0, :, None] * xs[:, 0].astype(f32))[..., None]
+             * bm[:, 0, None, None, :].astype(f32))
+        y = jnp.einsum("bhpn,bn->bhp", h, cm[:, 0].astype(f32))[:, None]
+    else:
+        length = (jnp.full((B,), T, jnp.int32) if n_true is None
+                  else n_true.astype(jnp.int32))
+        y, h = ops.ssd_scan(xs, dt, A, bm, cm, h0, length, chunk=chunk,
+                            impl=impl)
+    y = y.astype(f32) + p["D"][:, None] * xs.astype(f32)
+    y = y.reshape(B, T, d_inner) * jax.nn.silu(z.astype(f32))
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps) \
+        * p["norm"]["scale"]
+    out = y.astype(x.dtype) @ p["out_proj"]
+    return out, {"conv": conv_state.astype(state["conv"].dtype), "ssm": h}
+
+
+def mamba2_recurrent_ref(p, x, state, eps: float = 1e-5):
+    """The mixer one token at a time in float32 (parameters, input and
+    state cast up): the sequential recurrence the chunked scan is tested
+    against.  -> (y [B, T, D] float32, state)."""
+    p32 = jax.tree.map(lambda a: a.astype(jnp.float32), p)
+    st = jax.tree.map(lambda a: a.astype(jnp.float32), state)
+
+    def step(st, xt):
+        y, st = mamba2_apply(p32, xt[:, None], state=st, mode="step",
+                             eps=eps)
+        return st, y[:, 0]
+
+    st, ys = jax.lax.scan(step, st, jnp.moveaxis(x.astype(jnp.float32), 1, 0))
+    return jnp.moveaxis(ys, 0, 1), st

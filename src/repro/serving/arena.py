@@ -135,9 +135,13 @@ class BucketArena:
     def clear_slot(self, slot: int) -> None:
         """Reset metadata when a slot is re-issued to a new document.
 
-        Device state is NOT zeroed: the new document's prefill overwrites
-        [0, f_len) and every read is masked by per-slot valid lengths, so
-        stale KV past the new prefix is never visible.
+        Device state is NOT zeroed.  KV: the new document's prefill
+        overwrites [0, f_len) and every read is masked by per-slot valid
+        lengths, so stale KV past the new prefix is never visible.
+        Recurrent state has no mask: the new document's first launch is
+        an extend at offset 0, which starts every recurrent layer from
+        its initial state and never reads the row's old one
+        (``blocks.block_apply``).
         """
         if self.sanitizer is not None:
             self.sanitizer.note_clear(self.bucket, slot)

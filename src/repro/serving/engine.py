@@ -80,13 +80,15 @@ scalar-prefetch ``kv_len``), and the operation suffix runs as ONE causal
 extend of its ``op_len`` tokens starting at each document's own true
 length (the same kernel's scalar-prefetch ``q_start``): one model pass
 per launch, not one per op token.  Models with sliding-window or
-recurrent layers (no per-row positional write) keep a loop of masked
-decode steps, one per op token (``LMBackend.one_pass_op_suffix``).
+xLSTM/RG-LRU layers keep a loop of masked decode steps, one per op token
+(``LMBackend.one_pass_op_suffix``); Mamba-2 layers resume each row from
+its document's state and leave it unwritten.
 
 Paged data plane (default on Pallas runtimes, for models whose
-serve-state is all full-attention KV caches): the stage step never
-copies arena rows.  Per-sequence slot ids ride in scalar-prefetch SMEM
-beside ``kv_len`` and the paged kernels
+serve-state is full-attention KV caches and Mamba-2 states): the stage
+step never copies arena rows; a Mamba-2 state is taken and put by slot.
+Per-sequence slot ids ride in scalar-prefetch SMEM beside ``kv_len`` and
+the paged kernels
 (``ops.arena_decode_attention`` / ``ops.attention_paged``) DMA
 ``k_arena[slot]`` blocks directly, so an extend scatters only its
 chunk's KV and reads the arena in place — per-launch copy traffic drops
@@ -307,6 +309,7 @@ class GroupTicket:
     ts_dispatched: float             # dispatch_group returned control
     copy_bytes: int
     launch: int = -1
+    state_bytes: int = 0             # recurrent state read + written
     ts_sync: float = 0.0             # block_until_ready entered
     ts_ready: float = 0.0            # device results host-visible
 
@@ -331,7 +334,8 @@ class LMBackend:
     byte_budget: Optional[int] = None  # max device bytes across arenas
     retire_after: int = 64           # idle launches before bucket retirement
     # Paged data plane: None = auto (on for Pallas runtimes when the model
-    # is paged-capable — every serve-state leaf a full-attention KV cache).
+    # is paged-capable — every serve-state leaf a full-attention KV cache
+    # or a Mamba-2 state).
     # True forces it (XLA/naive impls fall back to a per-call gather inside
     # the kernels wrappers — reference semantics, not the fast path); False
     # forces the PR-1 gather/scatter stage step.
@@ -376,6 +380,8 @@ class LMBackend:
         default_factory=lambda: Telemetry(level=LEVEL_OFF), repr=False)
     last_timing: Optional[Dict[str, float]] = field(default=None, repr=False)
     last_copy_bytes: int = field(default=0, repr=False)
+    _row_bytes: Dict[int, Dict[str, int]] = field(default_factory=dict,
+                                                  repr=False)
     # Runtime arena sanitizer (analysis.sanitizer.ArenaSanitizer): per-row
     # ownership epochs + launch read/write-set brackets that turn silent
     # slot-aliasing races into a diagnostic ``ArenaRaceError``.  None =
@@ -731,18 +737,39 @@ class LMBackend:
                 and getattr(self.model, "supports_paged_kv", False))
         if self.paged:
             assert getattr(self.model, "supports_paged_kv", False), \
-                "paged=True requires a model whose serve-state is all " \
-                "full-attention KV caches (LM.supports_paged_kv)"
+                "paged=True requires a model whose serve-state is " \
+                "full-attention KV caches and Mamba-2 states " \
+                "(LM.supports_paged_kv)"
+        if self.prefix_sharing:
+            assert not self.row_nbytes(0)["recurrent"], \
+                "prefix sharing shares op-prefix KV rows; a recurrent " \
+                "state cannot be shared that way"
         return self.paged
 
     @property
     def one_pass_op_suffix(self) -> bool:
         """Whether a standard-layout launch runs its operation suffix as
-        ONE ragged-start extend: when every layer is full attention
-        (``LM.supports_paged_kv``), each row's op KV can be written at its
-        own true length.  Otherwise the suffix is one decode step per op
-        token."""
+        ONE ragged-start extend: when every layer is full attention or
+        Mamba-2 (``LM.supports_paged_kv``), each row's op KV can be
+        written at its own true length and each row's state resumed.
+        Otherwise the suffix is one decode step per op token."""
         return bool(getattr(self.model, "supports_paged_kv", False))
+
+    def row_nbytes(self, bucket: int) -> Dict[str, int]:
+        """One arena row's bytes by kind (``LM.state_nbytes``): ``kv``
+        grows with the bucket, ``recurrent`` does not."""
+        n = self._row_bytes.get(bucket)
+        if n is None:
+            by_kind = getattr(self.model, "state_nbytes", None)
+            if by_kind is None:          # a model with no recurrent kinds
+                n = {"kv": self.slot_nbytes(bucket), "recurrent": 0}
+            else:
+                kw = ({} if self.kv_dtype is None
+                      else {"kv_dtype": self._kv_jnp_dtype()})
+                n = by_kind(self.model.state_shapes(
+                    1, self._s_alloc_for(bucket), **kw))
+            self._row_bytes[bucket] = n
+        return n
 
     def _build_step(self):
         model = self.model
@@ -758,7 +785,8 @@ class LMBackend:
             if one_pass:
                 tok = jnp.broadcast_to(op_tok[None], (B, op_len))
                 return model.extend(params, {"tokens": tok}, states,
-                                    q_offset=ext, q_start=pos, slots=slots)
+                                    q_offset=ext, q_start=pos, slots=slots,
+                                    commit_recurrent=False)
             logits = None
             for t in range(op_len):
                 tok = jnp.broadcast_to(op_tok[t], (B,))
@@ -800,7 +828,8 @@ class LMBackend:
             # undershoot the padded cache) — so the window is snapshotted
             # first and restored after: an O(B * op_len) undo log instead
             # of an O(B * s_alloc) row copy, and the arena leaves the step
-            # bitwise identical to the gather path's.
+            # bitwise identical to the gather path's.  Recurrent states
+            # need no log: the op pass reads each row's and writes none.
             pos = kv_true.astype(jnp.int32)
             saved = model.take_kv_window(arena_states, slots, pos, op_len)
             logits, arena_states = op_suffix(
@@ -1124,7 +1153,7 @@ class LMBackend:
         Zero bytes scale with the cache/bucket size — the arena itself is
         read in place by the kernels."""
         s_alloc = self._s_alloc_for(bucket)
-        row = self.slot_nbytes(bucket)
+        row = self.row_nbytes(bucket)["kv"]
         return 2 * batch * op_len * (row // s_alloc)
 
     def class_confidences(self, logits: jnp.ndarray, n_classes: int
@@ -1257,6 +1286,9 @@ class LMBackend:
                     cached_d[i] = min(int(arena.true_len[slot]),
                                       self._true_len(toks, fraction))
                 kv_true[i] = self._true_len(toks, fraction)
+            if self.row_nbytes(bucket)["recurrent"]:
+                self._check_state_prefix(ids, slots, arena, n_new, kv_true,
+                                         ext_true)
         self.host_overhead_s += asm.seconds
 
         if self._step is None:
@@ -1287,6 +1319,7 @@ class LMBackend:
             arena.states = new_states
         self.host_overhead_s += dsp.seconds    # async dispatch
         self._note_launch_traffic(bucket, B, op_len)
+        state_bytes = self.state_bytes_per_launch(bucket, B, eff_c, n_new)
         if n_new > 0:
             for i, d in enumerate(ids):
                 slot = slots[i]
@@ -1298,7 +1331,33 @@ class LMBackend:
             cached_d=cached_d, op_len=op_len, san=san, san_ticket=ticket,
             timing={"host": asm.seconds, "dispatch": dsp.seconds},
             ts_enqueue=dsp.start, ts_dispatched=dsp.end,
-            copy_bytes=self.last_copy_bytes, launch=launch)
+            copy_bytes=self.last_copy_bytes, launch=launch,
+            state_bytes=state_bytes)
+
+    def _check_state_prefix(self, ids, slots, arena, n_new: int,
+                            kv_true: np.ndarray, ext_true: np.ndarray):
+        """A recurrent state holds exactly one prefix, the true tokens
+        cached so far; attention masks can read less, a state cannot.  So
+        a stage on a model with recurrent layers must read every true
+        token it caches (fraction 1, or a document that fills its
+        bucket)."""
+        for i, d in enumerate(ids):
+            cached = ext_true[i] if n_new > 0 else arena.true_len[slots[i]]
+            if int(cached) != int(kv_true[i]):
+                raise ValueError(
+                    f"{self.name}: document {d} reads {int(kv_true[i])} "
+                    f"true tokens but its recurrent state holds "
+                    f"{int(cached)}; a fraction that stops short of the "
+                    f"cached prefix needs a state snapshot there")
+
+    def state_bytes_per_launch(self, bucket: int, batch: int, c_len: int,
+                               n_new: int) -> int:
+        """Recurrent-state bytes a launch reads and writes through the
+        arena: the document's extend reads each row's state (unless it
+        starts the document) and writes it back; the op suffix reads it
+        once more and writes nothing."""
+        passes = 1 + ((2 if c_len > 0 else 1) if n_new > 0 else 0)
+        return batch * passes * self.row_nbytes(bucket)["recurrent"]
 
     def complete_group(self, ticket: GroupTicket):
         """Blocking half of ``run_group``: wait out the ticket's device
@@ -2126,6 +2185,7 @@ class CascadeServer:
             width=_pad_width(batch), sched_s=sched, host_s=host,
             dispatch_s=dispatch, sync_s=sync, wall_s=wall,
             copy_bytes=g.copy_bytes if (ok and g is not None) else 0,
+            state_bytes=g.state_bytes if (ok and g is not None) else 0,
             ok=ok, error=error,
             ts_enqueue=g.ts_enqueue if g is not None else 0.0,
             ts_ready=g.ts_ready if g is not None else 0.0,
@@ -2181,6 +2241,13 @@ class CascadeServer:
                                  for ar in getattr(b, "_arenas", {}
                                                    ).values()),
                              backend=name)
+                by_kind = {"kv": 0, "recurrent": 0}
+                for bucket, ar in getattr(b, "_arenas", {}).items():
+                    for k, v in b.row_nbytes(bucket).items():
+                        by_kind[k] += v * (ar.capacity + 1)
+                for k, v in by_kind.items():
+                    tm.set_gauge("serve_arena_kind_bytes", v, backend=name,
+                                 kind=k)
         self._arena_bytes_peak = max(self._arena_bytes_peak, nbytes)
         if tm.enabled:
             tm.set_gauge("serve_arena_bytes_peak", self._arena_bytes_peak)

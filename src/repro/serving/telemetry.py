@@ -434,6 +434,8 @@ class LaunchRecord:
     sync_s: float = 0.0
     wall_s: float = 0.0
     copy_bytes: int = 0            # gather copy / paged undo-log bytes
+    state_bytes: int = 0           # recurrent-state bytes read + written
+    #                                through the arena (apart from copy)
     ok: bool = True
     error: Optional[str] = None
     # per-ticket overlap stamps (0.0 when the launch never dispatched)
@@ -616,6 +618,9 @@ class Telemetry:
             self.observe("serve_launch_segment_seconds", v, segment=seg)
         for w in rec.queue_wait_s:
             self.observe("serve_queue_wait_seconds", w, backend=be)
+        if rec.state_bytes:
+            self.count("serve_state_bytes_total", rec.state_bytes,
+                       backend=be)
 
     def mean_launch_gap_s(self) -> float:
         """Mean device idle window between consecutive surviving launch
@@ -740,7 +745,8 @@ def chrome_trace(tm: Telemetry) -> Dict[str, Any]:
                 "cached_len": r.cached_len, "f_len": r.f_len,
                 "batch": r.batch, "width": r.width,
                 "occupancy": round(r.occupancy, 4),
-                "copy_bytes": r.copy_bytes, "ok": r.ok}
+                "copy_bytes": r.copy_bytes, "state_bytes": r.state_bytes,
+                "ok": r.ok}
         if r.error:
             args["error"] = r.error
         events.append({"ph": "X", "pid": pid, "tid": 0,

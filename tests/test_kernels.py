@@ -557,3 +557,60 @@ def test_paged_flash_q_start_bitwise_equals_dense(starts, ext):
                                          q_offset=starts[0], **kw)
         np.testing.assert_array_equal(np.asarray(out_paged),
                                       np.asarray(out_static))
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 SSD chunk scan
+# ---------------------------------------------------------------------------
+
+def _mk_ssd(key, B, S, H, P, N, dtype=jnp.float32):
+    k = jax.random.split(key, 6)
+    x = jax.random.normal(k[0], (B, S, H, P)).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, S, H)) - 2.0)
+    A = -jax.random.uniform(k[2], (H,), minval=1.0, maxval=16.0)
+    Bm = jax.random.normal(k[3], (B, S, N)).astype(dtype)
+    Cm = jax.random.normal(k[4], (B, S, N)).astype(dtype)
+    h0 = jax.random.normal(k[5], (B, H, P, N))
+    return x, dt, A, Bm, Cm, h0
+
+
+# chunk 16: S 45 is not a whole number of chunks; a 60-token chunk (the
+# served operation) in one; lengths cut rows short of S
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("S,lengths,chunk", [
+    (45, (45, 30), 16), (60, (60, 1), 64), (32, (0, 17), 16)])
+def test_ssd_scan_vs_sequential_reference(impl, S, lengths, chunk):
+    """The chunked scan resumed from a state, with ragged true lengths,
+    equals the float32 one-token recurrence: outputs at true positions
+    and the final state (the state a row's last true token left).
+    Tolerance 2e-5 abs on values of O(1)-O(10): float32 reassociation of
+    the chunk sums, nothing more."""
+    x, dt, A, Bm, Cm, h0 = _mk_ssd(jax.random.PRNGKey(3), 2, S, 8, 16, 16)
+    length = jnp.asarray(lengths, jnp.int32)
+    y_ref, h_ref = ref.ssd_reference(x, dt, A, Bm, Cm, h0, length)
+    y, h = ops.ssd_scan(x, dt, A, Bm, Cm, h0, length, chunk=chunk,
+                        impl=impl)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                               atol=2e-5, rtol=2e-5)
+    for b, n in enumerate(lengths):
+        np.testing.assert_allclose(np.asarray(y[b, :n]),
+                                   np.asarray(y_ref[b, :n]),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_ssd_scan_bf16_operands_within_rounding():
+    """bf16 activations (the chip's dtype): the kernel's matrix products
+    take bf16 operands with float32 accumulation and a float32 state, so
+    it stays within bf16 rounding of the float32 recurrence on the same
+    bf16 inputs (2e-2 relative to the largest output)."""
+    x, dt, A, Bm, Cm, h0 = _mk_ssd(jax.random.PRNGKey(4), 1, 64, 8, 16, 16,
+                                   jnp.bfloat16)
+    length = jnp.asarray([64], jnp.int32)
+    y_ref, h_ref = ref.ssd_reference(x, dt, A, Bm, Cm, h0, length)
+    y, h = ops.ssd_scan(x, dt, A, Bm, Cm, h0, length, chunk=32,
+                        impl="pallas_interpret")
+    scale = float(jnp.max(jnp.abs(y_ref)))
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - y_ref))) \
+        < 2e-2 * scale
+    assert float(jnp.max(jnp.abs(h - h_ref))) \
+        < 2e-2 * float(jnp.max(jnp.abs(h_ref)))

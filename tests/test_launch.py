@@ -43,7 +43,7 @@ def test_logical_param_counts_in_range(arch):
         "gemma3_27b": 27e9, "minitron_4b": 4e9, "qwen3_1_7b": 1.7e9,
         "llama3_2_1b": 1.2e9, "qwen2_vl_2b": 1.5e9, "phi3_5_moe": 42e9,
         "dbrx_132b": 132e9, "whisper_base": 72e6, "xlstm_350m": 350e6,
-        "recurrentgemma_2b": 2.7e9,
+        "recurrentgemma_2b": 2.7e9, "granite_4_0_h_micro": 3.2e9,
     }[arch]
     n = logical_param_counts(arch)["total"]
     assert 0.3 * advertised < n < 3.0 * advertised, (arch, n)

@@ -207,3 +207,76 @@ def test_one_pass_op_extend_matches_decode_loop(paged):
                                      st, kv_true + t, slots=slots)
     np.testing.assert_allclose(np.asarray(one), np.asarray(loop),
                                atol=2e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 (granite-4.0-h-micro's recurrent layers)
+# ---------------------------------------------------------------------------
+
+def _mamba2_layer(seed=0):
+    from repro.models import ssm
+    p = ssm.init_mamba2(jax.random.PRNGKey(seed), 64, 4, 16, 16, 4,
+                        jnp.float32)
+    st = ssm.init_mamba2_state(2, 4, 16, 16, 4, jnp.float32)
+    st = jax.tree.map(lambda a: 0.5 * jax.random.normal(
+        jax.random.PRNGKey(seed + 1), a.shape), st)     # resume mid-stream
+    x = jax.random.normal(jax.random.PRNGKey(seed + 2), (2, 40, 64))
+    return ssm, p, st, x
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_mamba2_mixer_matches_recurrence_on_true_tokens(impl):
+    """The chunked mixer (chunk 16 over 40 tokens, row 1 true for 23)
+    resumed from a state equals the float32 one-token recurrence over
+    each row's true tokens: outputs there, and the state (conv window and
+    scan state) its last true token left.  2e-5: float32 reassociation."""
+    ssm, p, st, x = _mamba2_layer()
+    n_true = jnp.asarray([40, 23], jnp.int32)
+    y, new = ssm.mamba2_apply(p, x, state=st, n_true=n_true, chunk=16,
+                              impl=impl)
+    for b, n in enumerate((40, 23)):
+        row = jax.tree.map(lambda a: a[b:b + 1], st)
+        y_ref, st_ref = ssm.mamba2_recurrent_ref(p, x[b:b + 1, :n], row)
+        np.testing.assert_allclose(np.asarray(y[b, :n]),
+                                   np.asarray(y_ref[0]), atol=2e-5,
+                                   rtol=2e-5)
+        for k in ("conv", "ssm"):
+            np.testing.assert_allclose(np.asarray(new[k][b]),
+                                       np.asarray(st_ref[k][0]),
+                                       atol=2e-5, rtol=2e-5)
+
+
+def test_mamba2_step_after_extend_matches_full_pass():
+    """Decode: the one-token step mode after a chunked extend continues
+    the full pass exactly (same tolerance and reason as above)."""
+    ssm, p, st, x = _mamba2_layer(5)
+    y_full, st_full = ssm.mamba2_apply(p, x, state=st, chunk=16)
+    _, s = ssm.mamba2_apply(p, x[:, :33], state=st, chunk=16)
+    for t in range(33, 40):
+        y_t, s = ssm.mamba2_apply(p, x[:, t:t + 1], state=s, mode="step")
+        np.testing.assert_allclose(np.asarray(y_t[:, 0]),
+                                   np.asarray(y_full[:, t]), atol=2e-5,
+                                   rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(s["ssm"]),
+                               np.asarray(st_full["ssm"]), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+def test_mamba2_state_across_extends(state_dtype):
+    """Two extends (25 + 15 tokens) with the state handed between them
+    as the arena holds it match the recurrence over all 40 at the
+    tolerance above with a float32 scan state, and fail it with a bf16
+    one: the comparison is tight enough to catch the next precision
+    down."""
+    ssm, p, st, x = _mamba2_layer(7)
+    y_ref, st_ref = ssm.mamba2_recurrent_ref(p, x, st)
+    y1, mid = ssm.mamba2_apply(p, x[:, :25], state=st, chunk=16)
+    mid = dict(mid, ssm=mid["ssm"].astype(state_dtype).astype(jnp.float32))
+    y2, end = ssm.mamba2_apply(p, x[:, 25:], state=mid, chunk=16)
+    y = np.concatenate([np.asarray(y1), np.asarray(y2)], axis=1)
+    close = (np.allclose(y, np.asarray(y_ref), atol=2e-5, rtol=2e-5)
+             and np.allclose(np.asarray(end["ssm"]),
+                             np.asarray(st_ref["ssm"]), atol=2e-5,
+                             rtol=2e-5))
+    assert close == (state_dtype == jnp.float32)

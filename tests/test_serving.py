@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.config import resolve
+from repro.config import ATTN_FULL, MAMBA2, resolve
 from repro.configs import get_reduced
 from repro.core.tasks import Cascade, Task, TaskConfig
 from repro.data.documents import generate_corpus
@@ -763,3 +763,152 @@ def test_paged_gather_bytes_accounting():
     s_alloc = be._s_alloc_for(64)
     assert p == 2 * 4 * 6 * (be.slot_nbytes(64) // s_alloc)
     assert p < g // 8                          # undo log is tiny vs rows
+
+
+# ---------------------------------------------------------------------------
+# Hybrid (Mamba-2 + attention) models on the paged plane
+# ---------------------------------------------------------------------------
+
+def _hybrid_backend(name="oracle", seed=2, paged=None, tokz=None, rt=None,
+                    arch="granite_4_0_h_micro", **over):
+    tokz = tokz or HashWordTokenizer(vocab_size=512)
+    if arch == "granite_4_0_h_micro":
+        # one Mamba-2 and one attention layer: both kinds of serve state
+        over = {"block_pattern": (MAMBA2, ATTN_FULL), "num_layers": 2,
+                **over}
+    cfg = get_reduced(arch, dtype="float32", vocab_size=512, **over)
+    m = LM(resolve(cfg, tp=1), rt or _PAGED_RT)
+    return LMBackend(name=name, model=m,
+                     params=m.init(jax.random.PRNGKey(seed)), tokenizer=tokz,
+                     rate_per_token=1.0 if name == "oracle" else 0.06,
+                     s_alloc=512, paged=paged)
+
+
+def _hybrid_engine(paged=None):
+    tokz = HashWordTokenizer(vocab_size=512)
+    return CascadeEngine(
+        {n: _hybrid_backend(n, s, paged, tokz)
+         for n, s in (("proxy", 1), ("oracle", 2))},
+        OPS, n_classes=2, batch_size=4)
+
+
+_WHOLE_DOCS = Cascade([
+    Task(TaskConfig("proxy", "sur_1", 1.0), {0: 2.0, 1: 2.0}),
+    Task(TaskConfig("oracle", "o_orig", 1.0), {0: 2.0, 1: 2.0}),
+])
+
+
+def test_hybrid_serves_on_the_paged_plane_in_one_op_pass(monkeypatch):
+    """A Mamba-2 hybrid takes the paged plane and the one-pass operation
+    suffix: no decode step runs, and a launch copies only the KV undo
+    log (the attention layer's KV at op_len positions), its recurrent
+    state counted apart as state traffic: written by a document's first
+    extend and read by the op pass, or only read by a decode-only
+    launch."""
+    eng = _hybrid_engine()
+    be = eng.backends["oracle"]
+    assert be.uses_paged_kv() and be.one_pass_op_suffix
+
+    def no_decode(*a, **k):
+        raise AssertionError("a decode step ran")
+    monkeypatch.setattr(LM, "decode_step", no_decode)
+    res = eng.run(_WHOLE_DOCS, _PAGED_DOCS)
+    assert sorted(res.pred) == sorted(_PAGED_DOCS)
+    row = be.row_nbytes(64)
+    assert row["kv"] and row["recurrent"]
+    assert row["kv"] + row["recurrent"] == be.slot_nbytes(64)
+    assert be.paged_copy_bytes_per_launch(64, 2, 6) \
+        == 2 * 2 * 6 * (row["kv"] // be._s_alloc_for(64))
+    recs = [r for r in eng.telemetry.launches.items() if r.model == "oracle"]
+    assert recs and all(
+        r.state_bytes == r.batch * (1 if r.decode_only else 2)
+        * be.row_nbytes(r.bucket)["recurrent"] for r in recs)
+    reg = eng.telemetry.registry               # the arena's bytes by kind
+    kinds = {k: reg.gauge("serve_arena_kind_bytes", backend="oracle",
+                          kind=k).value for k in ("kv", "recurrent")}
+    assert kinds["kv"] > 0 and kinds["recurrent"] > 0
+    assert kinds["kv"] + kinds["recurrent"] == be.arena_nbytes()
+
+
+def test_hybrid_paged_engine_bitwise_parity_with_gather():
+    """Paged (state taken and put by slot, KV in place) and gather (row
+    copies) planes serve a hybrid bitwise alike."""
+    runs = {p: _hybrid_engine(p).run(_WHOLE_DOCS, _PAGED_DOCS)
+            for p in (False, True)}
+    assert runs[False].pred == runs[True].pred
+    assert runs[False].conf == runs[True].conf
+    assert runs[False].doc_cost == runs[True].doc_cost
+
+
+def test_hybrid_op_pass_leaves_arena_bitwise_unchanged():
+    """The op pass writes its KV behind the undo log and never writes a
+    recurrent state: a decode-only launch leaves every arena leaf
+    bitwise as the document pass left it, so it answers again alike."""
+    be = _hybrid_backend()
+    toks = {d: np.asarray(be.tokenizer.encode(t), np.int32)
+            for d, t in _PAGED_DOCS.items()}
+    op = np.asarray(be.tokenizer.encode(OPS["o_orig"]), np.int32)
+    ids = [1, 3]                                   # both in bucket 64
+    _, c1, *_ = be.run_stage(ids, toks, 64, 1.0, op, 2)
+    before = [np.asarray(l).copy() for l in jax.tree.leaves(be._arenas[64]
+                                                            .states)]
+    _, c2, new_t, _ = be.run_stage(ids, toks, 64, 1.0, op, 2)
+    assert new_t == 2 * len(op)                    # decode-only
+    for b, a in zip(before, jax.tree.leaves(be._arenas[64].states)):
+        np.testing.assert_array_equal(b, np.asarray(a))
+    np.testing.assert_array_equal(c1, c2)
+
+
+def test_hybrid_fraction_extension_equals_one_shot():
+    """Fraction 0.5 then 1.0 on the same hybrid resumes the recurrent
+    state exactly: the answer equals one prefill of the whole document
+    (1e-5: float32 reassociation between one extend and two).  The
+    document fills its bucket, so each stage reads every true token it
+    caches."""
+    be = _hybrid_backend()
+    d = 9
+    toks = {d: np.asarray(be.tokenizer.encode(
+        " ".join(f"f{j}" for j in range(64))), np.int32)}
+    op = np.asarray(be.tokenizer.encode("test op"), np.int32)
+    be.run_stage([d], toks, 64, 0.5, op, 2)
+    _, c_ext, new_t, cached_t = be.run_stage([d], toks, 64, 1.0, op, 2)
+    assert cached_t == 32
+    be.reset()
+    _, c_one, *_ = be.run_stage([d], toks, 64, 1.0, op, 2)
+    np.testing.assert_allclose(c_ext, c_one, atol=1e-5)
+
+
+def test_hybrid_refuses_a_fraction_short_of_its_cached_prefix():
+    """A recurrent state holds one prefix: a stage that reads fewer true
+    tokens than it caches (ceil(50 * 0.5) = 25 of the 32 padded) is
+    refused, not answered from the wrong state."""
+    be = _hybrid_backend()
+    toks = {3: np.asarray(be.tokenizer.encode(_PAGED_DOCS[3]), np.int32)}
+    op = np.asarray(be.tokenizer.encode("op"), np.int32)
+    with pytest.raises(ValueError, match="recurrent state"):
+        be.run_stage([3], toks, 64, 0.5, op, 2)
+
+
+@pytest.mark.parametrize("arch,paged", [
+    ("granite_4_0_h_micro", True),      # Mamba-2, paged plane
+    ("xlstm_350m", False),              # mLSTM + sLSTM, gather plane
+    ("recurrentgemma_2b", False),       # RG-LRU (+ local attention)
+])
+def test_recycled_slot_starts_from_the_initial_state(arch, paged):
+    """A slot freed by one document and handed to the next starts
+    every recurrent layer from its initial state: the next document
+    answers as on a fresh arena, whatever the last one left in the
+    row."""
+    rt = _PAGED_RT if paged else CPU_TEST
+    be = _hybrid_backend(paged=paged, rt=rt, arch=arch)
+    toks = {d: np.asarray(be.tokenizer.encode(t), np.int32)
+            for d, t in _PAGED_DOCS.items()}
+    op = np.asarray(be.tokenizer.encode(OPS["o_orig"]), np.int32)
+    be.run_stage([1], toks, 64, 1.0, op, 2)
+    slot = be._doc_slot[1]
+    be.release(1)
+    _, recycled, *_ = be.run_stage([3], toks, 64, 1.0, op, 2)
+    assert be._doc_slot[3] == slot
+    be.reset()
+    _, fresh, *_ = be.run_stage([3], toks, 64, 1.0, op, 2)
+    np.testing.assert_allclose(recycled, fresh, atol=1e-6)

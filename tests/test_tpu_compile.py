@@ -6,13 +6,15 @@ whatever the chip's compiler would raise (block shapes Mosaic refuses,
 scoped-VMEM overflows).  Each test asserts the Mosaic kernel is in the
 compiled program (``tpu_custom_call``).  Widths come from the stage
 models the chip path serves: llama3.2-1b (32/8 heads, head_dim 64),
-qwen3-1.7b (16/8 heads, head_dim 128), and for the one-pass operation
-suffix the Qwen3-4B and Qwen3-0.6B widths, in bf16.
+qwen3-1.7b (16/8 heads, head_dim 128), for the one-pass operation
+suffix the Qwen3-4B and Qwen3-0.6B widths, and granite-4.0-h-micro's
+SSD chunk scan and hybrid paged step, in bf16.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every xdist
 worker imports this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -195,4 +197,47 @@ def test_paged_step_runs_op_suffix_as_one_flash_pass(one_chip, c_len, n_new):
         _sds(one_chip, (B,), i32), _sds(one_chip, (B,), i32),
         c_len=c_len, op_len=op_len).compile().as_text()
     assert "paged_flash_attention" in text
+    assert "paged_decode_attention" not in text
+
+
+# the oracle's Mamba-2 widths (granite-4.0-h-micro): 64 heads x 64,
+# d_state 128, chunk 256, at a 4,096-token document and the 60-token op
+@pytest.mark.parametrize("S", [4096, 60])
+def test_ssd_chunk_scan_compiles(one_chip, S):
+    B, H, P, N = 2, 64, 64, 128
+    args = (_sds(one_chip, (B, S, H, P)),
+            _sds(one_chip, (B, S, H), jnp.float32),
+            _sds(one_chip, (H,), jnp.float32),
+            _sds(one_chip, (B, S, N)), _sds(one_chip, (B, S, N)),
+            _sds(one_chip, (B, H, P, N), jnp.float32),
+            _sds(one_chip, (B,), jnp.int32))
+
+    def fn(*a):
+        return ops.ssd_scan(*a, chunk=256, impl="pallas")
+    assert "ssd_chunk_scan" in _compile_text(fn, *args)
+
+
+def test_hybrid_paged_step_compiles(one_chip):
+    """The served paged step of granite-4.0-h-micro at its published
+    widths (one period of its layer pattern: 9 Mamba-2, 1 attention) and
+    the benchmark's oracle launch width (2), for a prefill launch of the
+    4,096 bucket: the scan and paged flash kernels are in the program and
+    no decode kernel (one op pass)."""
+    cfg = get_config("granite_4_0_h_micro")
+    cfg = dataclasses.replace(cfg, num_layers=10, vocab_size=512)
+    model = LM(resolve(cfg, tp=1), Runtime(attn_impl="pallas", remat=False))
+    be = LMBackend(name="oracle", model=model, params=None,
+                   tokenizer=HashWordTokenizer(512), paged=True)
+    B, N, op_len = 2, 5, 60
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    arena = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         model.state_shapes(N, be._s_alloc_for(4096)))
+    i32 = jnp.int32
+    text = be._build_step().lower(
+        params, arena, _sds(one_chip, (B,), i32),
+        _sds(one_chip, (B, 4096), i32), _sds(one_chip, (op_len,), i32),
+        _sds(one_chip, (B,), i32), _sds(one_chip, (B,), i32),
+        c_len=0, op_len=op_len).compile().as_text()
+    assert "ssd_chunk_scan" in text and "paged_flash_attention" in text
     assert "paged_decode_attention" not in text
