@@ -26,7 +26,8 @@ served CONCURRENTLY over that shared substrate:
     server = CascadeServer(backends, operations, n_classes)
     handle = server.register(cascade, accuracy_target=0.9)   # QueryHandle
     fut    = handle.submit(doc_id, text)                     # DocFuture
-    server.step()                            dispatch ONE launch (any query)
+    server.step()                            complete ONE launch (any query),
+                                             refill the dispatch window
     handle.poll()                            this query's fresh resolutions
     handle.result() / server.stats(qid)      per-query results, stats, $
     server.drain()                           step until idle (all queries)
@@ -1580,6 +1581,7 @@ class _Flight:
     t_begin: float
     t_sched: float
     queue_wait_s: Tuple[float, ...] = ()
+    early: bool = False     # dispatched by a step's refill after completion
 
 
 @dataclass
@@ -1613,8 +1615,10 @@ class CascadeServer:
     idle_wait_cap: float = 0.25      # max seconds one _idle_wait sleeps
     # Overlapped ahead-of-time dispatch: keep up to ``inflight`` launches
     # enqueued on the device before blocking for the oldest one's routing
-    # confidences.  1 (default) is bitwise the pre-overlap behavior; K>1
-    # hides scheduler/host bookkeeping behind device compute.  Safe by
+    # confidences.  1 (default) keeps one launch on the device at a time,
+    # refilled by each step after its completion so the device works
+    # while the caller is between steps; K>1 also hides scheduler/host
+    # bookkeeping behind device compute.  Safe by
     # construction: in-flight documents are out of the ready queue (so
     # concurrent launches own disjoint arena rows), the scheduler vetoes
     # groups that would touch rows open tickets own, and every structural
@@ -1926,21 +1930,28 @@ class CascadeServer:
             st.retired_buckets += n
 
     def step(self) -> List[Tuple[int, int]]:
-        """Fill the dispatch window, then complete the oldest launch.
+        """Complete the oldest launch and refill the dispatch window.
 
         Ahead-of-time dispatch: up to ``inflight`` launches are enqueued
         non-blocking (``dispatch_group`` returns a ticket while the
-        device works), then exactly one — the oldest — is completed,
-        because the scheduler needs ITS confidences to route its
-        documents' next stages.  At ``inflight=1`` this is bitwise the
-        classic dispatch-then-block step.  Launches may mix documents
-        from several registered queries (same static signature).
-        Returns the ``(query_id, doc_id)`` pairs that reached a TERMINAL
-        state this step (may be empty).  No-op when idle.  A failed
-        launch never raises out of ``step``: its documents are
-        re-enqueued solo with backoff (or finished FAILED/TIMED_OUT past
-        their retry/deadline budgets) — see the module docstring's
-        failure model.
+        device works).  A step first fills the window from the ready
+        queue (normally it is already full), then completes exactly one
+        launch — the oldest — because the scheduler needs ITS
+        confidences to route its documents' next stages, and then fills
+        the window again from what is ready at that instant (the
+        documents it just escalated, and any the caller submitted
+        earlier), so the device runs a launch while the caller submits
+        and polls.  Those refilled launches are ``early``.  The refill
+        follows the completion's sync, so at ``inflight=1`` there is
+        never more than one launch on the device; a step that finds
+        nothing ready at completion returns with the device idle.
+        Launches may mix documents from several registered queries (same
+        static signature).  Returns the ``(query_id, doc_id)`` pairs
+        that reached a TERMINAL state this step (may be empty).  No-op
+        when idle.  A failed launch never raises out of ``step``: its
+        documents are re-enqueued solo with backoff (or finished
+        FAILED/TIMED_OUT past their retry/deadline budgets) — see the
+        module docstring's failure model.
 
         Telemetry: each launch's wall time decomposes into
         scheduler-pick / host / dispatch / sync segments (host is the
@@ -1949,16 +1960,32 @@ class CascadeServer:
         (``inflight_s``).  Each segment but host is a ``serve.*`` span on
         the profiler's clock — see ``serving/telemetry.py``.
         """
-        tm = self.telemetry
         terminal: List[Tuple[int, int]] = []
+        dispatched, failed = self._fill(terminal, early=False)
+        if failed:
+            self._note_progress(True)
+        elif self._flights:
+            self._complete_one(terminal)
+            self._fill(terminal, early=True)
+            self._note_progress(True)
+        else:
+            self._note_progress(bool(terminal) or dispatched)
+        return terminal
+
+    def _fill(self, terminal: List[Tuple[int, int]],
+              early: bool) -> Tuple[bool, bool]:
+        """Dispatch ready groups until ``inflight`` launches are open or
+        nothing is dispatchable; ``early`` marks a refill after a
+        completion.  The first pick also sweeps deadlines and reroutes
+        around open breakers, inside its sched segment.  Stops at the
+        first launch that fails to dispatch (its documents go back with
+        backoff).  Returns (dispatched any, a dispatch failed)."""
+        tm = self.telemetry
         k = max(int(self.inflight), 1)
         dispatched = False
         while len(self._flights) < k:
             with tm.span("serve.sched") as sched:
                 if not dispatched:
-                    # the first pick of a step also sweeps deadlines and
-                    # reroutes around open breakers, inside its sched
-                    # segment (inflight=1 parity)
                     now = sched.start
                     for req in self._queue.pop_expired(now):  # beats backoff
                         self._finish(req, TIMED_OUT, now,
@@ -1983,7 +2010,7 @@ class CascadeServer:
             self._attempts += 1
             fl = _Flight(launch=launch, be=be, group=None,
                          attempt=self._attempts - 1, t_begin=sched.start,
-                         t_sched=sched.end)
+                         t_sched=sched.end, early=early)
             ready = []
             for rid in launch.doc_ids:      # leaves the queue for a launch
                 req = self._requests[rid]
@@ -2002,8 +2029,7 @@ class CascadeServer:
                 self._on_launch_failure(launch, exc, time.perf_counter(),
                                         terminal)
                 self._record_flight(fl, ok=False, error=str(exc))
-                self._note_progress(True)
-                return terminal
+                return dispatched, True
             t_enqueue = fl.group.ts_enqueue    # 0.0: nothing was enqueued
             if t_enqueue > 0.0:
                 fl.queue_wait_s = tuple(max(t_enqueue - r, 0.0)
@@ -2012,12 +2038,7 @@ class CascadeServer:
             dispatched = True
             self._max_inflight_seen = max(self._max_inflight_seen,
                                           len(self._flights))
-        if self._flights:
-            self._complete_one(terminal)
-            self._note_progress(True)
-        else:
-            self._note_progress(bool(terminal) or dispatched)
-        return terminal
+        return dispatched, False
 
     def _inflight_blocked(self, key) -> bool:
         """Scheduler veto for overlapped dispatch: True if co-scheduling
@@ -2062,12 +2083,13 @@ class CascadeServer:
         """Complete the OLDEST in-flight launch and route its documents.
 
         FIFO completion keeps billing-ledger order a pure function of
-        dispatch order.  Dispatch order itself may legally differ from
-        ``inflight=1`` — the window fills with already-ready cohorts
-        before a completion re-queues escalated documents — but every
-        document still runs exactly its stage ladder, so per-document
-        preds/confs/$ (and the arena state they leave behind) are
-        bitwise schedule-independent."""
+        dispatch order.  Dispatch order itself legally depends on the
+        schedule — at ``inflight > 1`` the window fills with
+        already-ready cohorts before a completion re-queues escalated
+        documents, and a step's refill takes what is ready at completion
+        before the caller's next submits — but every document still runs
+        exactly its stage ladder, so per-document preds/confs/$ (and the
+        arena state they leave behind) are bitwise schedule-independent."""
         tm = self.telemetry
         fl = self._flights.pop(0)
         launch, be = fl.launch, fl.be
@@ -2164,7 +2186,9 @@ class CascadeServer:
         boundary stamp; the host segment is the residual, so the four
         sum to the record's wall clock exactly.  Overlapped records also
         carry the dispatch-return -> sync-begin window (``inflight_s``)
-        and their enqueue/ready stamps for the gap histogram."""
+        and their enqueue/ready stamps for the gap histogram; a refilled
+        (``early``) launch's window spans the caller's time between
+        steps."""
         tm = self.telemetry
         if not tm.enabled:
             return
@@ -2191,7 +2215,7 @@ class CascadeServer:
             ts_ready=g.ts_ready if g is not None else 0.0,
             inflight_s=(max(g.ts_sync - g.ts_dispatched, 0.0)
                         if g is not None and g.ts_sync > 0.0 else 0.0),
-            queue_wait_s=fl.queue_wait_s)
+            queue_wait_s=fl.queue_wait_s, early=fl.early)
         tm.record_launch(rec)
         tm.set_gauge("serve_queue_depth", len(self._queue))
 

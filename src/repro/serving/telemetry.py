@@ -64,7 +64,10 @@ disjoint segments that sum to the record's wall clock:
                 span ``serve.dispatch``)
 ``sync_s``      the completion-side ``jax.block_until_ready`` wait (span
                 ``serve.sync``): the host blocked until the device
-                finished, not the device's own time
+                finished, not the device's own time.  A launch a step
+                refilled after its completion (``early``) ran while the
+                caller was between steps, so its sync covers only what
+                was left of its device run when the next step blocked
 
 Spans on the profiler's clock
 -----------------------------
@@ -109,9 +112,12 @@ exactly).  The hidden window is the overlap win:
 waits), and ``timeline["mean_launch_gap_ms"]`` measures
 ``max(enqueue(next) - ready(prev), 0)`` over consecutive ok records —
 the device idle window between launches, which ahead-of-time dispatch
-drives toward zero.  At ``inflight=1`` every stamp reduces to the
-pre-overlap decomposition (``sync_s`` measured immediately after
-dispatch; ``inflight_s`` ~ 0).
+drives toward zero.  At ``inflight=1`` a launch dispatched at the start
+of a step keeps the pre-overlap decomposition (``sync_s`` measured
+immediately after dispatch; ``inflight_s`` ~ 0); an ``early`` launch,
+refilled after the step's completion, is in flight across the return to
+the caller, so its ``inflight_s`` and ``host_s`` hold the caller's time
+between steps (``serve_early_dispatch_total{backend}`` counts them).
 
 The old ``LMBackend.host_overhead_s`` scalar survives as a derived view:
 it accumulates ``host assembly + dispatch`` exactly as before, and
@@ -417,10 +423,15 @@ class LaunchRecord:
     """One dispatched launch: signature, occupancy, copy traffic, its
     documents' queue waits, and the scheduler/host/dispatch/sync
     wall-time decomposition (the four segments are disjoint and sum to
-    ``wall_s`` by construction)."""
+    ``wall_s`` by construction).  The wall runs from the launch's
+    scheduler pick to the end of its completion; for an ``early`` launch
+    (dispatched by a step's refill, after that step's completion) it
+    spans the return to the caller, so ``host_s`` holds the caller's
+    time between steps and ``sync_s`` only the rest of the device run
+    the next step waited out."""
 
     index: int                     # server launch index (attempt order)
-    ts_start: float                # perf_counter at step entry
+    ts_start: float                # perf_counter at its scheduler pick
     model: str = ""
     op_id: Optional[str] = None
     bucket: int = 0
@@ -442,9 +453,12 @@ class LaunchRecord:
     ts_enqueue: float = 0.0        # perf_counter entering the jit call
     ts_ready: float = 0.0          # perf_counter after block_until_ready
     inflight_s: float = 0.0        # dispatched->sync window hidden behind
-    #                                other launches' sched/host work; NOT
-    #                                a wall-clock segment (see docstring)
+    #                                other launches' sched/host work or the
+    #                                caller's; NOT a wall-clock segment
+    #                                (see docstring)
     queue_wait_s: Tuple[float, ...] = ()   # ready -> serve.dispatch entry
+    early: bool = False            # dispatched after a completion, inside
+    #                                the same CascadeServer.step()
 
     @property
     def occupancy(self) -> float:
@@ -621,6 +635,8 @@ class Telemetry:
         if rec.state_bytes:
             self.count("serve_state_bytes_total", rec.state_bytes,
                        backend=be)
+        if rec.early:
+            self.count("serve_early_dispatch_total", 1, backend=be)
 
     def mean_launch_gap_s(self) -> float:
         """Mean device idle window between consecutive surviving launch

@@ -128,9 +128,12 @@ def test_failed_launch_retries_solo_and_resolves(backends, docs):
     futs = [h.submit(d, docs[d], arrival=float(i))
             for i, d in enumerate(sorted(docs)[:3])]
     assert srv.step() == []                 # packed launch fails
-    assert inj.counts["launch_failures"] == 1
+    # the step's refill already dispatched the first solo retry, drawn
+    # while the backend still fails; it surfaces at the next completion
+    assert inj.counts["launch_failures"] == 2
     assert h.stats.retries == 3             # every member re-enqueued
     assert all(not f.done for f in futs)    # ... but nobody failed
+    assert [len(f.launch.doc_ids) for f in srv._flights] == [1]
     inj.plan = FaultPlan(seed=3)            # heal the backend
     # poisoned-cohort isolation: survivors retry in SINGLETON groups
     launch = srv._queue.next_launch(srv._stage_of, srv.batch_size)
@@ -198,11 +201,12 @@ def test_persistent_nan_escalates_then_fails(backends, docs):
     inj.install(srv)
     d0 = sorted(docs)[0]
     fut = h.submit(d0, docs[d0])
-    srv.step()                              # quarantine 1: solo retry
+    srv.step()                              # quarantine 1: solo retry,
+    #                                         refilled while still NaN
+    inj.plan = FaultPlan(seed=2)            # oracle now healthy
     srv.step()                              # quarantine 2: escalate to final
     final = len(h.stages) - 1
     assert srv._requests[srv._ids[(h.query_id, d0)]].stage == final
-    inj.plan = FaultPlan(seed=2)            # oracle now healthy
     h.drain()
     assert fut.status == RESOLVED and fut.exit_stage == final
     # and with the oracle ALSO emitting NaN, the document fails cleanly
@@ -330,6 +334,15 @@ def test_finite_backoff_is_not_a_stall(backends, docs):
 # ------------------------------------------------- eviction under retry
 
 def test_eviction_during_backoff_rebills_prefill_once():
+    """A's third proxy stage fails, B evicts A during the retry, and A's
+    re-prefill of that stage is billed once.  Each step refills the
+    window after its completion, so the launch a plan poisons is the one
+    refilled in the step after the plan is set."""
+    ladder = Cascade([
+        Task(TaskConfig("proxy", "o_orig", 0.25), IMPOSSIBLE),
+        Task(TaskConfig("proxy", "o_orig", 0.5), IMPOSSIBLE),
+        Task(TaskConfig("proxy", "o_orig", 1.0), IMPOSSIBLE),
+    ])
     tokz = HashWordTokenizer(vocab_size=512)
     bks = {"proxy": _mk_backend("proxy", 1, tokz, slot_budget=1),
            "oracle": _mk_backend("oracle", 2, tokz)}
@@ -338,31 +351,33 @@ def test_eviction_during_backoff_rebills_prefill_once():
     corpus = {d.doc_id: d.text
               for d in generate_corpus(2, avg_lines=10, seed=11)}
     da, db = sorted(corpus)
-    ha = srv.register(LADDER)
-    hb = srv.register(LADDER)
+    ha = srv.register(ladder)
+    hb = srv.register(ladder)
     fa = ha.submit(da, corpus[da], arrival=0.0)
     srv.step()                              # A runs stage 0, caches f=0.25
     rid = srv._ids[(ha.query_id, da)]
     assert srv._requests[rid].cached["proxy"] > 0
     inj = FaultInjector(FaultPlan(seed=8, launch_failure_p=1.0))
     inj.install(srv)
-    srv.step()                              # A's stage-1 launch fails
-    assert srv._requests[rid].retries == 1
+    srv.step()                              # A's stage 1; stage 2 poisoned
+    assert srv._requests[rid].retries == 0
     inj.plan = FaultPlan(seed=8)            # heal
-    # B arrives with priority and evicts A (slot_budget=1) mid-retry
+    # B arrives with priority; A's stage-2 launch fails at completion and
+    # the refill launches B, which evicts A (slot_budget=1) mid-retry
     fb = hb.submit(db, corpus[db], arrival=-1.0)
     srv.step()
+    assert srv._requests[rid].retries == 1
     assert srv._requests[rid].evictions == 1
     assert srv._requests[rid].cached["proxy"] == 0
     assert srv._requests[rid].retries == 1  # eviction preserves retry count
     srv.drain()
     assert fa.status == RESOLVED and fb.status == RESOLVED
-    # A's stage-1 re-prefill was billed exactly once: the full document
+    # A's stage-2 re-prefill was billed exactly once: the full document
     # (cache lost) plus the op suffix, as NEW tokens
     toks_a = len(tokz.encode(corpus[da]))
     op_len = len(tokz.encode(OPS["o_orig"]))
-    assert ha.stats.stage_new_tokens[1] == toks_a + op_len
-    assert ha.stats.stage_cached_tokens[1] == 0
+    assert ha.stats.stage_new_tokens[2] == toks_a + op_len
+    assert ha.stats.stage_cached_tokens[2] == 0
     assert ha.stats.retries == 1 and ha.stats.evictions == 1
     _assert_ledger_exact(srv)
 

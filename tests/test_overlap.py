@@ -8,8 +8,11 @@ the contract: bitwise parity with ``inflight=1`` on the fault-free plane
 (preds/confs/per-doc $ and arena device state), a seeded chaos drain
 with K>1 under the sanitizer (all docs terminal, ledger exact, zero
 violations), the sanitizer still catching two open tickets on one row,
-faults surfacing at completion rather than dispatch, and per-ticket
-timing that never forces synchronization.
+faults surfacing at completion rather than dispatch, per-ticket timing
+that never forces synchronization, and the step's refill after its
+completion: one launch open at ``inflight=1`` while the caller is between
+steps, a client loop bitwise equal to ``drain()``, no open ticket once
+the queue empties, and ``early`` marking exactly the refilled launches.
 """
 import jax
 import jax.numpy as jnp
@@ -122,9 +125,8 @@ def _capture_releases(backends):
     return store
 
 
-def _replay(tokz, docs, cascade, inflight, sanitize=None, plan=None):
-    """Fresh backends + server at the given window depth; drain the
-    whole corpus (logical arrivals) and return (server, result,
+def _build(tokz, inflight, sanitize=None):
+    """Fresh backends + server at the given window depth: (server,
     backends, release-time row fingerprints)."""
     backends = {"proxy": _mk_backend("proxy", 1, tokz, sanitize=sanitize),
                 "oracle": _mk_backend("oracle", 2, tokz,
@@ -133,11 +135,22 @@ def _replay(tokz, docs, cascade, inflight, sanitize=None, plan=None):
     srv = CascadeServer(dict(backends), OPS, n_classes=2, batch_size=4,
                         retry=RetryPolicy(max_retries=2, backoff_base=0.0),
                         inflight=inflight)
+    return srv, backends, rows
+
+
+def _submit_all(h, docs):
+    for i, d in enumerate(sorted(docs)):
+        h.submit(d, docs[d], arrival=float(i))
+
+
+def _replay(tokz, docs, cascade, inflight, sanitize=None, plan=None):
+    """Drain the whole corpus (logical arrivals) on a fresh server and
+    return (server, result, backends, release-time row fingerprints)."""
+    srv, backends, rows = _build(tokz, inflight, sanitize)
     if plan is not None:
         FaultInjector(plan).install(srv)
     h = srv.register(cascade)
-    for i, d in enumerate(sorted(docs)):
-        h.submit(d, docs[d], arrival=float(i))
+    _submit_all(h, docs)
     res = h.drain()
     return srv, res, backends, rows
 
@@ -276,3 +289,103 @@ def test_per_ticket_timing_without_sync(tokz, docs):
     assert ticket.ts_ready >= ticket.ts_sync >= ticket.ts_dispatched
     assert len(pred) == len(conf) == 1
     assert int(new_d[0]) > 0
+
+
+# ------------------------------------------ refill after the completion
+def test_step_refills_one_launch_at_inflight_1(tokz, docs):
+    """A step that completes a launch while another group is ready
+    returns with exactly that one launch open: the device works while
+    the caller is between steps, and never holds two launches."""
+    srv, _, _ = _build(tokz, inflight=1)
+    h = srv.register(LADDER)
+    _submit_all(h, docs)                    # 8 documents, launches of 4
+    srv.step()
+    assert len(srv._flights) == 1 and srv._flights[0].early
+    assert srv._max_inflight_seen == 1
+    h.drain()
+    assert srv._max_inflight_seen == 1
+
+
+def test_client_loop_matches_drain_bitwise(tokz, docs):
+    """The benchmark client's closed loop (keep three documents
+    admitted, step, poll) refills between its submits, so it launches
+    other groups than ``drain()``; preds, confs, per-document $ and the
+    arena rows every document leaves behind are bitwise the same."""
+    srv0, ref, _, rows0 = _replay(tokz, docs, LADDER, inflight=1)
+    srv, _, rows = _build(tokz, inflight=1)
+    h = srv.register(LADDER)
+    order, submitted, polled = sorted(docs), 0, {}
+    while len(polled) < len(order):
+        while srv.pending() < 3 and submitted < len(order):
+            d = order[submitted]
+            h.submit(d, docs[d], arrival=float(submitted))
+            submitted += 1
+        srv.step()
+        polled.update(h.poll())
+    res = h.result()
+    assert any(r.early for r in srv.telemetry.launches.items())
+    assert srv._max_inflight_seen == 1
+    assert polled == {d: (ref.pred[d], ref.conf[d], ref.exit_stage[d])
+                      for d in order}
+    assert res.pred == ref.pred
+    assert res.conf == ref.conf             # float equality, not approx
+    assert res.doc_cost == ref.doc_cost
+    assert sorted((q, r, c) for _, q, r, c in srv.ledger()) \
+        == sorted((q, r, c) for _, q, r, c in srv0.ledger())
+    _ledger_exact(srv)
+    assert set(rows) == set(rows0)
+    for key in rows0:                       # (backend, bucket, doc)
+        assert rows[key] == rows0[key], key
+
+
+def test_last_step_leaves_no_open_ticket(tokz, docs):
+    """Stepping until nothing is pending: every step but the last
+    returns with the refilled launch open; the last finds the queue
+    empty at its completion and leaves the device idle."""
+    srv, _, _ = _build(tokz, inflight=1)
+    h = srv.register(LADDER)
+    _submit_all(h, docs)
+    open_after = []
+    while srv.pending():
+        srv.step()
+        open_after.append(len(srv._flights))
+    assert open_after == [1] * (len(open_after) - 1) + [0]
+    assert srv.pending() == 0 and h.pending() == 0
+    assert len(open_after) == srv.telemetry.launch_total
+
+
+@pytest.mark.parametrize("inflight", [1, 3])
+def test_early_counts_only_refilled_launches(tokz, docs, inflight):
+    """``LaunchRecord.early`` and ``serve_early_dispatch_total`` count
+    exactly the launches dispatched after a completion inside the same
+    step, as the backends saw them (dispatch and completion wrapped)."""
+    srv, backends, _ = _build(tokz, inflight=inflight)
+    seen = {"completed": False, "after": set()}
+    for be in backends.values():
+        dispatch, complete = be.dispatch_group, be.complete_group
+
+        def dispatch_group(*a, _real=dispatch, **kw):
+            if seen["completed"]:
+                seen["after"].add(kw["launch"])
+            return _real(*a, **kw)
+
+        def complete_group(ticket, _real=complete):
+            seen["completed"] = True
+            return _real(ticket)
+
+        be.dispatch_group, be.complete_group = dispatch_group, complete_group
+    h = srv.register(LADDER)
+    _submit_all(h, docs)
+    while srv.pending():
+        seen["completed"] = False
+        srv.step()
+    recs = srv.telemetry.launches.items()
+    assert {r.index for r in recs if r.early} == seen["after"]
+    assert 0 < len(seen["after"]) < len(recs)
+    if inflight == 1:                       # only the first launch waits
+        assert [r.early for r in recs] == [False] + [True] * (len(recs) - 1)
+    reg = srv.telemetry.registry
+    for name in backends:
+        assert reg.counter("serve_early_dispatch_total",
+                           backend=name).value \
+            == sum(r.early for r in recs if r.model == name)

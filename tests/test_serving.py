@@ -279,12 +279,15 @@ def test_streaming_admission_mid_cascade(engine, docs):
 def test_eviction_requeues_and_resolves(docs):
     """Under a tiny slot budget the newest-arrival slot is preempted; the
     evicted document re-resolves correctly with its re-prefill counted as
-    new tokens."""
+    new tokens.  Each step refills the window after its completion, so
+    the eviction lands on the step after ``a``'s stage 1, and a third
+    proxy stage is what ``a`` must re-prefill."""
     ids = sorted(docs)[:2]
     sub = {d: docs[d] for d in ids}
     thr = {0: 2.0, 1: 2.0}                            # nothing exits early
     ladder = Cascade([
         Task(TaskConfig("proxy", "o_orig", 0.25), thr),
+        Task(TaskConfig("proxy", "o_orig", 0.5), thr),
         Task(TaskConfig("proxy", "o_orig", 1.0), thr),
     ])
     ref_eng = _mk_engine(batch_size=1)
@@ -294,10 +297,10 @@ def test_eviction_requeues_and_resolves(docs):
     a, b = ids
     eng.start(ladder)
     eng.submit(a, sub[a], arrival=0.0)
-    eng.step()                                        # a cached at stage 0
+    eng.step()                            # a cached at stage 0; stage 1 out
     assert eng.backends["proxy"].cached_len(a) > 0
     eng.submit(b, sub[b], arrival=-1.0)               # older -> higher prio
-    eng.step()                                        # launches b, evicts a
+    eng.step()                            # a's stage 1; refill b, evicts a
     assert eng._stats.evictions >= 1
     assert eng.backends["proxy"].cached_len(a) == 0   # cache gone
     res = eng.drain()
@@ -313,12 +316,14 @@ def test_eviction_requeues_and_resolves(docs):
 def test_byte_budget_evicts_and_resolves(docs):
     """A byte-denominated budget preempts slots when the pending launch
     would GROW an arena past it; the evicted document re-resolves and the
-    arenas never exceed the budget."""
+    arenas never exceed the budget.  As above, the eviction lands on the
+    refill after ``a``'s stage 1, before its third proxy stage."""
     ids = _same_bucket_ids(docs, 2)
     sub = {d: docs[d] for d in ids}
     thr = {0: 2.0, 1: 2.0}
     ladder = Cascade([
         Task(TaskConfig("proxy", "o_orig", 0.25), thr),
+        Task(TaskConfig("proxy", "o_orig", 0.5), thr),
         Task(TaskConfig("proxy", "o_orig", 1.0), thr),
     ])
     ref = _mk_engine(batch_size=1).run(ladder, sub)   # unbudgeted baseline
@@ -333,10 +338,10 @@ def test_byte_budget_evicts_and_resolves(docs):
     a, b = ids
     eng.start(ladder)
     eng.submit(a, sub[a], arrival=0.0)
-    eng.step()                                        # a cached at stage 0
+    eng.step()                            # a cached at stage 0; stage 1 out
     assert eng.backends["proxy"].cached_len(a) > 0
     eng.submit(b, sub[b], arrival=-1.0)               # older -> higher prio
-    eng.step()                                        # b launches, evicts a
+    eng.step()                            # a's stage 1; refill b, evicts a
     assert eng._stats.evictions >= 1
     be = eng.backends["proxy"]
     assert be.cached_len(a) == 0                      # cache gone
